@@ -45,7 +45,7 @@ import numpy as np
 
 from repro.configs.base import ArchConfig, ShapeSpec
 from repro.launch.batcher import ContinuousBatcher, synthetic_traffic
-from repro.launch.mesh import compat_mesh
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import make_pool_setup, make_serve_setup
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -222,7 +222,7 @@ def run(out_path: str = DEFAULT_OUT, smoke: bool = False,
         # Skewed: 3/4 of requests want 9 tokens, 1/4 want 129 — the
         # long-tail shape that makes lockstep waves idle short rows.
         gen_lens = [9, 9, 9, 129]
-    mesh = compat_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rows = []
     with mesh:
         for r, impl in cells:

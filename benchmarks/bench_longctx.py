@@ -192,7 +192,7 @@ def overhead_cell(repeats: int, smoke: bool, verbose: bool) -> dict:
     through the real ContinuousBatcher, min-of-repeats wall clock."""
     from repro.configs.base import ArchConfig
     from repro.launch.batcher import ContinuousBatcher, synthetic_traffic
-    from repro.launch.mesh import compat_mesh
+    from repro.launch.mesh import make_mesh
     from repro.launch.steps import make_pool_setup
     from repro.models import build_model
 
@@ -209,7 +209,7 @@ def overhead_cell(repeats: int, smoke: bool, verbose: bool) -> dict:
     params = model.init(jax.random.PRNGKey(0))
     reqs = synthetic_traffic(n_req, cfg.vocab, [plen], gen_lens, seed=3)
     useful = sum(rq.gen_len for rq in reqs)
-    mesh = compat_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with mesh:
         engines = {}
         for mode, tele in (("telemetry_off", False), ("telemetry_on", True)):

@@ -35,7 +35,7 @@ import jax
 from repro.configs.base import ArchConfig
 from repro.core.health import HealthConfig
 from repro.launch.batcher import ContinuousBatcher, synthetic_traffic
-from repro.launch.mesh import compat_mesh
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import make_pool_setup
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -120,7 +120,7 @@ def run(out_path: str = DEFAULT_OUT, smoke: bool = False,
         impls = ["lln_diag", "softmax"]
         slots, n_requests, prompt_len, segment, blk = 4, 12, 16, 8, 16
         gen_lens = [9, 9, 33]
-    mesh = compat_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rows = []
     with mesh:
         for impl in impls:

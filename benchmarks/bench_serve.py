@@ -39,7 +39,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, ShapeSpec
-from repro.launch.mesh import compat_mesh
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import make_serve_setup
 from repro.models import build_model, synthetic_batch
 
@@ -201,7 +201,7 @@ def run(out_path: str = DEFAULT_OUT, smoke: bool = False,
     else:
         cells = [(r, impl) for r in (1, 4) for impl in IMPLS]
         batch, prompt, gen, blk, chunk_t = 2, 128, 17, 32, 8
-    mesh = compat_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rows = []
     with mesh:
         for r, impl in cells:

@@ -47,7 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig, ShapeSpec
-from repro.launch.mesh import compat_mesh
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import (flatten_spec_tokens, make_serve_setup,
                                 make_spec_setup)
 from repro.models import build_model, synthetic_batch
@@ -74,7 +74,7 @@ def _cell(impl: str, r: int, k: int, draft_layers: int, *, batch: int,
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     max_len = prompt + steps + k + 2
-    mesh = compat_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shape = ShapeSpec("spec", max_len, batch, "decode")
     batch_in = synthetic_batch(cfg, batch, max_len, text_seq=prompt)
     with mesh:

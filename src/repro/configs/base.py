@@ -30,7 +30,11 @@ class ArchConfig:
                                      # multi-scale LLN state)
     diag_block: int = 256
     lln_chunk: int = 256
-    use_kernel: bool = False         # Pallas kernels (TPU); jnp path on CPU
+    use_kernel: bool = False         # training forward on the CPU backend:
+                                     # True runs the Pallas kernels (in
+                                     # interpret mode), False the jnp
+                                     # reference.  Accelerators always run
+                                     # the kernels.
     use_serve_kernel: bool = True    # legacy escape: False maps to
                                      # attn_backend="ref" (the seed jnp
                                      # serving path), kept for benchmarking

@@ -30,6 +30,19 @@ from .moment_matching import (constants_for_dim, length_gain,
 
 NEG_INF = -1e30
 
+#: Logical axes of the arrays attention hands a kernel, by name, for
+#: ``distributed/sharding.py:per_device``: q/k/v/out (B, N, H|G, D) and the
+#: calibrations alpha/beta, heads last ((H|G,) or per-row (B, H|G)).  Query
+#: and kv heads share the name "heads", so they split together or not at all.
+ATTN_AXES = {
+    "q": ("act_batch", None, "heads", None),
+    "k": ("act_batch", None, "heads", None),
+    "v": ("act_batch", None, "heads", None),
+    "out": ("act_batch", None, "heads", None),
+    "alpha": ("act_batch", "heads"),
+    "beta": ("act_batch", "heads"),
+}
+
 
 @dataclasses.dataclass(frozen=True)
 class AttnConfig:
@@ -313,6 +326,10 @@ def multi_head_attention(
     if cfg.use_kernel:
         # Kernels handle GQA via BlockSpec index maps — no KV repeat; the
         # backend registry owns the pallas/scan/ref dispatch.
+        if mask is not None:
+            raise ValueError("the attention kernels take no padding mask; "
+                             "use the jnp path (use_kernel=False, or "
+                             "backend 'ref') for masked attention")
         from repro.kernels import registry as kreg
         spec = kreg.AttnSpec(impl=cfg.impl, causal=cfg.causal, r=h // g,
                              backend=cfg.backend or "auto",
@@ -322,7 +339,11 @@ def multi_head_attention(
                              fixed_ab=cfg.fixed_ab,
                              num_scales=cfg.num_scales,
                              scale_decay=cfg.scale_decay)
-        return kreg.attention(spec, q, k, v, alpha, beta)
+        from repro.distributed.sharding import per_device
+        return per_device(
+            lambda **a: {"out": kreg.attention(spec, **a)},
+            ATTN_AXES.__getitem__, q=q, k=k, v=v, alpha=alpha,
+            beta=beta)["out"]
 
     kv_k = _repeat_kv(k, h)
     kv_v = _repeat_kv(v, h)

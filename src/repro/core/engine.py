@@ -35,6 +35,7 @@ from . import moment_matching as mm
 from .attention import KVCache, LLNDecodeState, batch_alpha_beta
 from .lln import LLNState, commit_lengths
 from .loglinear import LogLinState
+from repro.distributed import sharding as shd
 from repro.kernels import registry as kreg
 from repro.kernels.registry import AttnSpec
 
@@ -100,6 +101,25 @@ class AttentionState:
 
 
 _STATE_FIELDS = tuple(f.name for f in dataclasses.fields(AttentionState))
+
+#: Logical axes of the LLN and log_linear ``AttentionState`` leaves and of
+#: the other arrays the engine hands ``distributed/sharding.py:per_device``
+#: (q/k/v/out, per-row masks), by name.  A leaf missing here fails loudly
+#: there.  Softmax states never go there: XLA partitions that path.
+STATE_AXES = {
+    **ca.ATTN_AXES,
+    "pos": ("act_batch",),
+    "row_mask": ("act_batch",), "commit_len": ("act_batch",),
+    "s": ("act_batch", "heads", None, None),
+    "z": ("act_batch", "heads", None),
+    "c_k": ("act_batch", None, "heads", None),
+    "tail_k": ("act_batch", None, "heads", None),
+    "tail_v": ("act_batch", None, "heads", None),
+    "log_scale": ("act_batch", "heads"),
+    "sl": ("act_batch", None, "heads", None, None),
+    "zl": ("act_batch", None, "heads", None),
+    "cl": ("act_batch", None, "heads"),
+}
 
 
 def _state_flatten_with_keys(st: AttentionState):
@@ -277,7 +297,27 @@ class AttentionEngine:
         O(d^2) state from one pass (``kernels/ops.py:lln_prefill`` under
         ``spec.backend``) plus the diag tail at the G kv heads.
         ``alpha``/``beta`` override the moment-matching calibration.
+        Under a multi-device mesh each device runs its own rows and heads
+        (``distributed/sharding.py:per_device``); the batch-pooled
+        calibration is measured before the split, over every row.
         """
+        if self.spec.impl == "softmax":     # no kernel: XLA partitions it
+            return self._prefill(q, k, v, max_len=max_len,
+                                 prefix_len=prefix_len, alpha=alpha,
+                                 beta=beta)
+        if alpha is None or beta is None:
+            alpha, beta = self.calibrate(q, k, n=q.shape[1])
+
+        def run(q, k, v, alpha, beta):
+            out, st = self._prefill(q, k, v, max_len=max_len,
+                                    prefix_len=prefix_len, alpha=alpha,
+                                    beta=beta)
+            return {"out": out, "state": st}
+        res = shd.per_device(run, STATE_AXES.__getitem__, q=q, k=k, v=v,
+                             alpha=alpha, beta=beta)
+        return res["out"], res["state"]
+
+    def _prefill(self, q, k, v, *, max_len, prefix_len, alpha, beta):
         b, n, h, _ = q.shape
         g = k.shape[2]
         spec = self.spec
@@ -294,8 +334,6 @@ class AttentionEngine:
                 k=jnp.pad(k.astype(self.state_dtype), pad),
                 v=jnp.pad(v.astype(self.state_dtype), pad),
                 len=jnp.full((b,), n, jnp.int32))
-        if alpha is None or beta is None:
-            alpha, beta = self.calibrate(q, k, n=n)
         # beta(n) schedule: the prefill forward runs at the prompt-length
         # temperature, but the state stores the BASE calibration — decode
         # re-derives each row's effective temperature from its own pos, so
@@ -354,7 +392,22 @@ class AttentionEngine:
         contract).  ``commit_len`` (B,) int32 in [0, T]: the speculative
         partial-commit contract — all T positions are scored, but only
         the accepted prefix folds into the state (see :meth:`verify`).
+        Under a multi-device mesh each device runs its own rows and heads.
         """
+        if self.spec.impl == "softmax":
+            return self._decode(state, q, k, v, row_mask=row_mask,
+                                commit_len=commit_len)
+
+        def run(state, q, k, v, row_mask, commit_len):
+            out, st = self._decode(state, q, k, v, row_mask=row_mask,
+                                   commit_len=commit_len)
+            return {"out": out, "state": st}
+        res = shd.per_device(run, STATE_AXES.__getitem__, state=state, q=q,
+                             k=k, v=v, row_mask=row_mask,
+                             commit_len=commit_len)
+        return res["out"], res["state"]
+
+    def _decode(self, state, q, k, v, *, row_mask, commit_len):
         spec = self.spec
         if spec.impl == "softmax":
             out, kv2 = ca.decode_softmax(

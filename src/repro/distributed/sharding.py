@@ -48,6 +48,66 @@ def current_mesh() -> Optional[Mesh]:
     return _MESH
 
 
+def per_device(fn, axes, **args):
+    """Call ``fn(**args)`` once per device of the active mesh, each device
+    on its own block of every leaf.
+
+    XLA cannot partition a Pallas TPU kernel, so under a mesh of more than
+    one device the code that calls one runs inside ``shard_map``.  The
+    caller names the layout: ``axes(name)`` gives the logical axes (as
+    :func:`constrain` takes them) of the leaf whose path ends in ``name``,
+    in ``args`` and in the dict ``fn`` returns, aligned to the leaf's
+    trailing dims (so ``("act_batch", "heads")`` also fits an (H,) leaf).
+    A logical axis splits over its rule's mesh axes only where they divide
+    every dim that axis names in every leaf, so all blocks agree; else it
+    is replicated.  ``fn`` must compute each block of a split axis on its
+    own (rows and heads of attention do).  Outside a mesh, or on one
+    device, ``fn`` runs as is.
+    """
+    mesh = _MESH
+    if mesh is None or mesh.size == 1:
+        return fn(**args)
+
+    def local(a):
+        with logical_rules(None, None):      # constraints name global axes
+            return fn(**a)
+
+    out = jax.eval_shape(local, args)
+    flat, tree = jax.tree_util.tree_flatten_with_path((args, out))
+    names = []
+    for path, leaf in flat:
+        key = path[-1]
+        name = getattr(key, "key", getattr(key, "name", None))
+        ndim = len(jnp.shape(leaf))
+        want = tuple(axes(name))
+        if ndim > len(want):
+            raise ValueError(f"leaf {name!r} has rank {ndim} but only "
+                             f"{len(want)} logical axes")
+        names.append(want[len(want) - ndim:])
+    dims: dict = {}
+    for (_, leaf), ns in zip(flat, names):
+        for n, d in zip(ns, jnp.shape(leaf)):
+            if n is not None:
+                dims.setdefault(n, []).append(d)
+    split, used = {}, set()
+    for n, sizes in dims.items():
+        rule = (_ACTIVE or {}).get(n)
+        kept = []
+        for a in (rule if isinstance(rule, tuple) else (rule,)):
+            if a is None or a in used or a not in mesh.axis_names:
+                continue
+            size = _axis_size(mesh, tuple(kept) + (a,))
+            if all(d % size == 0 for d in sizes):
+                kept.append(a)
+        used.update(kept)
+        split[n] = tuple(kept) if len(kept) > 1 else (kept[0] if kept
+                                                      else None)
+    specs = tree.unflatten(
+        [P(*(split.get(n) for n in ns)) for ns in names])
+    return jax.shard_map(local, mesh=mesh, in_specs=(specs[0],),
+                         out_specs=specs[1], check_vma=False)(args)
+
+
 def _axis_size(mesh: Mesh, axes) -> int:
     if axes is None:
         return 1
@@ -84,8 +144,11 @@ def fit_spec(spec: P, shape, mesh: Mesh) -> P:
 
 
 def constrain(x: jnp.ndarray, *logical_axes) -> jnp.ndarray:
-    """Annotate activation sharding by logical axis names (no-op w/o rules)."""
-    if _ACTIVE is None or _MESH is None:
+    """Annotate activation sharding by logical axis names (no-op w/o rules,
+    and inside :func:`per_device`, where a custom VJP's backward may be
+    traced after that context has closed)."""
+    if _ACTIVE is None or _MESH is None \
+            or jax.sharding.get_abstract_mesh().manual_axes:
         return x
     axes = tuple(_ACTIVE.get(a) if isinstance(a, str) else a
                  for a in logical_axes)

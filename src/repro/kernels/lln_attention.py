@@ -18,8 +18,9 @@ Training residuals
 ------------------
 Every forward entry point accepts ``return_res=True`` to additionally emit
 the per-row normalizer ``den_i = Phi(q_i) . (z_prefix + sum_block Phi(k))``
-(fp32, shape (BH, N)) — and, for the bidirectional variant, the reduced
-``(S, z)`` summary state.  ops.py saves these (together with the already
+(fp32, shape (BH, 1, N): the TPU compiler tiles the last two block dims
+by (8, 128), so a per-row vector travels as a (1, blk) lane row) — and,
+for the bidirectional variant, the reduced ``(S, z)`` summary state.  ops.py saves these (together with the already
 pre-scaled ``qs``/``ks``) as custom_vjp residuals so the backward kernels in
 ``lln_backward.py`` never recompute the stabilization constants or the
 forward normalizers: the quotient rule through ``out = num / den`` is applied
@@ -79,7 +80,7 @@ def _lln_causal_kernel(qs_ref, ks_ref, v_ref, o_ref, *rest, blk, with_res,
     den = intra_z + inter_z + EPS
     o_ref[0] = ((intra + inter) / den[:, None]).astype(o_ref.dtype)
     if with_res:
-        den_ref[0] = den
+        den_ref[0] = den[None, :]
 
     s_acc[...] += jax.lax.dot_general(fk, vv, (((0,), (0,)), ((), ())),
                                       preferred_element_type=jnp.float32)
@@ -96,7 +97,7 @@ def lln_causal_pallas(qs: jnp.ndarray, ks: jnp.ndarray, v: jnp.ndarray, *,
                       return_res: bool = False, return_state: bool = False):
     """qs: (BH, N, D) pre-scaled; ks/v: (BG, N, D[v]); N % blk == 0.
 
-    With ``return_res`` also emits the fp32 normalizer ``den`` (BH, N) used
+    With ``return_res`` also emits the fp32 normalizer ``den`` (BH, 1, N) used
     by the custom backward (see module docstring).  With ``return_state``
     also emits the final running state ``s`` (BH, D, DV) and ``z`` (BH, 1, D)
     — the O(d^2) decode state, produced by the same pass that computes the
@@ -109,8 +110,8 @@ def lln_causal_pallas(qs: jnp.ndarray, ks: jnp.ndarray, v: jnp.ndarray, *,
     out_specs = [pl.BlockSpec((1, blk, dv), lambda h, j: (h, j, 0))]
     out_shape = [jax.ShapeDtypeStruct((bh, n, dv), v.dtype)]
     if return_res:
-        out_specs.append(pl.BlockSpec((1, blk), lambda h, j: (h, j)))
-        out_shape.append(jax.ShapeDtypeStruct((bh, n), jnp.float32))
+        out_specs.append(pl.BlockSpec((1, 1, blk), lambda h, j: (h, 0, j)))
+        out_shape.append(jax.ShapeDtypeStruct((bh, 1, n), jnp.float32))
     if return_state:
         out_specs.append(pl.BlockSpec((1, d, dv), lambda h, j: (h, 0, 0)))
         out_shape.append(jax.ShapeDtypeStruct((bh, d, dv), jnp.float32))
@@ -160,7 +161,7 @@ def _lln_apply_kernel(qs_ref, s_ref, z_ref, o_ref, *rest, with_res):
                   preferred_element_type=jnp.float32)[:, 0] + EPS
     o_ref[0] = (num / den[:, None]).astype(o_ref.dtype)
     if with_res:
-        rest[0][0] = den
+        rest[0][0] = den[None, :]
 
 
 def lln_bidir_pallas(qs: jnp.ndarray, ks: jnp.ndarray, v: jnp.ndarray, *,
@@ -169,7 +170,7 @@ def lln_bidir_pallas(qs: jnp.ndarray, ks: jnp.ndarray, v: jnp.ndarray, *,
     """qs: (BH, N, D); ks/v: (BG, N, D[v]); N % blk == 0.
 
     With ``return_res`` returns ``(out, s, z, den)``: the reduced summary
-    state (BG, D, DV)/(BG, 1, D) and the fp32 normalizer (BH, N), reused by
+    state (BG, D, DV)/(BG, 1, D) and the fp32 normalizer (BH, 1, N), reused by
     the backward pass.
     """
     bh, n, d = qs.shape
@@ -194,8 +195,8 @@ def lln_bidir_pallas(qs: jnp.ndarray, ks: jnp.ndarray, v: jnp.ndarray, *,
     out_specs = [pl.BlockSpec((1, blk, dv), lambda h, j: (h, j, 0))]
     out_shape = [jax.ShapeDtypeStruct((bh, n, dv), v.dtype)]
     if return_res:
-        out_specs.append(pl.BlockSpec((1, blk), lambda h, j: (h, j)))
-        out_shape.append(jax.ShapeDtypeStruct((bh, n), jnp.float32))
+        out_specs.append(pl.BlockSpec((1, 1, blk), lambda h, j: (h, 0, j)))
+        out_shape.append(jax.ShapeDtypeStruct((bh, 1, n), jnp.float32))
     res = pl.pallas_call(
         functools.partial(_lln_apply_kernel, with_res=return_res),
         grid=(bh, nb),
@@ -251,7 +252,7 @@ def _lln_diag_fused_kernel(qs_ref, ks_ref, q_ref, k_ref, v_ref, o_ref,
     den = intra_z + inter_z + EPS
     lln_out = (intra + inter) / den[:, None]
     if with_res:
-        den_ref[0] = den
+        den_ref[0] = den[None, :]
     s_acc[...] += jax.lax.dot_general(fk, vv, (((0,), (0,)), ((), ())),
                                       preferred_element_type=jnp.float32)
     z_acc[...] += jnp.sum(fk, axis=0, keepdims=True)
@@ -278,7 +279,7 @@ def lln_diag_fused_pallas(qs, ks, q, k, v, *, r: int = 1, blk: int = 256,
 
     Causal only: the bidirectional LLN needs the full-sequence state, which
     the single-pass fusion cannot provide (use lln_bidir_pallas + block_diag).
-    With ``return_res`` also emits the LLN normalizer ``den`` (BH, N, fp32);
+    With ``return_res`` also emits the LLN normalizer ``den`` (BH, 1, N, fp32);
     the diag softmax needs no residual — its backward recomputes the block
     probabilities from the shared q/k loads.
     """
@@ -291,8 +292,8 @@ def lln_diag_fused_pallas(qs, ks, q, k, v, *, r: int = 1, blk: int = 256,
     out_specs = [pl.BlockSpec((1, blk, dv), lambda h, j: (h, j, 0))]
     out_shape = [jax.ShapeDtypeStruct((bh, n, dv), v.dtype)]
     if return_res:
-        out_specs.append(pl.BlockSpec((1, blk), lambda h, j: (h, j)))
-        out_shape.append(jax.ShapeDtypeStruct((bh, n), jnp.float32))
+        out_specs.append(pl.BlockSpec((1, 1, blk), lambda h, j: (h, 0, j)))
+        out_shape.append(jax.ShapeDtypeStruct((bh, 1, n), jnp.float32))
     res = pl.pallas_call(
         functools.partial(_lln_diag_fused_kernel, blk=blk, scale=scale,
                           causal=causal, with_res=return_res),

@@ -77,7 +77,7 @@ def _load_uw(g_ref, o_ref, den_ref):
     """Cotangents u = g/den (blk, Dv) and w = (g.o)/den (blk,)."""
     gg = g_ref[0].astype(jnp.float32)
     oo = o_ref[0].astype(jnp.float32)
-    den = den_ref[0].astype(jnp.float32)
+    den = den_ref[0][0]                                  # (blk,)
     return gg / den[:, None], jnp.sum(gg * oo, axis=-1) / den
 
 
@@ -161,9 +161,10 @@ def lln_causal_bwd_pallas(qs, ks, v, g, o, den, *, r: int = 1,
                           blk: int = 256, interpret: bool = False):
     """Backward of the causal LLN kernel.
 
-    qs/g/o/den: (BH, N, .) query-side tensors; ks/v: (BG, N, .) with
-    r = H // G.  Returns fp32 (dqs, dks, dv) in kernel layout, with dks/dv
-    already segment-summed over the repeated query heads.
+    qs/g/o: (BH, N, .) query-side tensors, den (BH, 1, N) the forward's
+    fp32 normalizer; ks/v: (BG, N, .) with r = H // G.  Returns fp32
+    (dqs, dks, dv) in kernel layout, with dks/dv already segment-summed
+    over the repeated query heads.
     """
     bh, n, d = qs.shape
     bg = ks.shape[0]
@@ -178,7 +179,7 @@ def lln_causal_bwd_pallas(qs, ks, v, g, o, den, *, r: int = 1,
             pl.BlockSpec((1, blk, dv), lambda h, j, r=r: (h // r, j, 0)),
             pl.BlockSpec((1, blk, dv), lambda h, j: (h, j, 0)),
             pl.BlockSpec((1, blk, dv), lambda h, j: (h, j, 0)),
-            pl.BlockSpec((1, blk), lambda h, j: (h, j)),
+            pl.BlockSpec((1, 1, blk), lambda h, j: (h, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, blk, d), lambda h, j: (h, j, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, n, d), jnp.float32),
@@ -206,9 +207,9 @@ def lln_causal_bwd_pallas(qs, ks, v, g, o, den, *, r: int = 1,
             pl.BlockSpec((1, blk, dv),
                          lambda gi, j, rr, r=r, nb=nb:
                          (gi * r + rr, nb - 1 - j, 0)),
-            pl.BlockSpec((1, blk),
+            pl.BlockSpec((1, 1, blk),
                          lambda gi, j, rr, r=r, nb=nb:
-                         (gi * r + rr, nb - 1 - j)),
+                         (gi * r + rr, 0, nb - 1 - j)),
         ],
         out_specs=[
             pl.BlockSpec((1, blk, d),
@@ -276,7 +277,7 @@ def lln_bidir_bwd_pallas(qs, ks, v, g, o, den, s, z, *, r: int = 1,
             pl.BlockSpec((1, blk, d), lambda h, j: (h, j, 0)),
             pl.BlockSpec((1, blk, dv), lambda h, j: (h, j, 0)),
             pl.BlockSpec((1, blk, dv), lambda h, j: (h, j, 0)),
-            pl.BlockSpec((1, blk), lambda h, j: (h, j)),
+            pl.BlockSpec((1, 1, blk), lambda h, j: (h, 0, j)),
             pl.BlockSpec((1, d, dv), lambda h, j, r=r: (h // r, 0, 0)),
             pl.BlockSpec((1, 1, d), lambda h, j, r=r: (h // r, 0, 0)),
         ],
@@ -298,8 +299,8 @@ def lln_bidir_bwd_pallas(qs, ks, v, g, o, den, s, z, *, r: int = 1,
                          lambda gi, rr, j, r=r: (gi * r + rr, j, 0)),
             pl.BlockSpec((1, blk, dv),
                          lambda gi, rr, j, r=r: (gi * r + rr, j, 0)),
-            pl.BlockSpec((1, blk),
-                         lambda gi, rr, j, r=r: (gi * r + rr, j)),
+            pl.BlockSpec((1, 1, blk),
+                         lambda gi, rr, j, r=r: (gi * r + rr, 0, j)),
         ],
         out_specs=[
             pl.BlockSpec((1, d, dv), lambda gi, rr, j: (gi, 0, 0)),
@@ -352,7 +353,7 @@ def _fused_uw(g_ref, o_ref, den_ref, diag_out):
     reconstructed as 2*out - diag_out, and the 0.5 averaging weight is
     folded into u/w via g/2."""
     gh = 0.5 * g_ref[0].astype(jnp.float32)
-    den = den_ref[0].astype(jnp.float32)
+    den = den_ref[0][0]                                  # (blk,)
     lln_out = 2.0 * o_ref[0].astype(jnp.float32) - diag_out
     u = gh / den[:, None]
     w = jnp.sum(gh * lln_out, axis=-1) / den
@@ -471,7 +472,7 @@ def lln_diag_fused_bwd_pallas(qs, ks, q, k, v, g, o, den, *, r: int = 1,
             kv_spec((1, blk, dvd)),
             q_spec((1, blk, dvd)),
             q_spec((1, blk, dvd)),
-            pl.BlockSpec((1, blk), lambda h, j: (h, j)),
+            pl.BlockSpec((1, 1, blk), lambda h, j: (h, 0, j)),
         ],
         out_specs=[q_spec((1, blk, d)), q_spec((1, blk, d))],
         out_shape=[jax.ShapeDtypeStruct((bh, n, d), jnp.float32),
@@ -501,9 +502,9 @@ def lln_diag_fused_bwd_pallas(qs, ks, q, k, v, g, o, den, *, r: int = 1,
             kvr_spec((1, blk, dvd)),
             qr_spec((1, blk, dvd)),
             qr_spec((1, blk, dvd)),
-            pl.BlockSpec((1, blk),
+            pl.BlockSpec((1, 1, blk),
                          lambda gi, j, rr, r=r, nb=nb:
-                         (gi * r + rr, nb - 1 - j)),
+                         (gi * r + rr, 0, nb - 1 - j)),
         ],
         out_specs=[kvr_spec((1, blk, d)), kvr_spec((1, blk, d)),
                    kvr_spec((1, blk, dvd))],
@@ -522,6 +523,7 @@ def lln_diag_fused_bwd_pallas(qs, ks, q, k, v, g, o, den, *, r: int = 1,
 # ---------------------------------------------------------------------------
 
 def _uw_full(g, o, den):
+    den = den[:, 0]                                      # (BH, 1, N) -> (BH, N)
     gf = g.astype(jnp.float32)
     u = gf / den[..., None]
     w = jnp.sum(gf * o.astype(jnp.float32), axis=-1) / den

@@ -1231,7 +1231,7 @@ def _ssd_fwd_impl(xbar, b_in, c_in, log_a, chunk, interpret):
     xk = _to_kernel(xbar)
     bk = _to_kernel(b_in)
     ck = _to_kernel(c_in)
-    lk = log_a.transpose(0, 2, 1).reshape(b * h, l)
+    lk = log_a.transpose(0, 2, 1).reshape(b * h, 1, l)
     out = ssd_pallas(lk, xk, bk, ck, r=h // g, blk=chunk,
                      interpret=_interpret(interpret))
     return _from_kernel(out, b)

@@ -91,25 +91,12 @@ def _segsum_kv(t: jnp.ndarray, r: int) -> jnp.ndarray:
     return t.reshape(bh // r, r, *t.shape[1:]).sum(axis=1)
 
 
-def lln_fwd_res_ref(qs: jnp.ndarray, ks: jnp.ndarray, v: jnp.ndarray,
-                    causal: bool, r: int = 1):
-    """Forward oracle that also returns the fp32 (out, den) residual pair."""
-    fq = jnp.exp(qs.astype(jnp.float32))
-    fk = jnp.exp(_expand_kv(ks, r).astype(jnp.float32))
-    vf = _expand_kv(v, r).astype(jnp.float32)
-    scores = jnp.einsum("hid,hjd->hij", fq, fk)
-    if causal:
-        scores = scores * jnp.tril(jnp.ones(scores.shape[1:], jnp.float32))
-    den = jnp.sum(scores, axis=-1) + EPS
-    out = jnp.einsum("hij,hjv->hiv", scores, vf) / den[..., None]
-    return out, den
-
-
 def lln_bwd_ref(qs: jnp.ndarray, ks: jnp.ndarray, v: jnp.ndarray,
                 g: jnp.ndarray, o: jnp.ndarray, den: jnp.ndarray,
                 causal: bool, r: int = 1):
     """Analytic LLN backward oracle (quadratic form), kernel layout.
 
+    ``den`` is the forward's fp32 normalizer in kernel layout (BH, 1, N).
     Mirrors the normalizer-aware decomposition used by the Pallas backward:
     u = g/den, w = (g.o)/den, G_ij = (u_i.v_j - w_i) * mask, then
     dqs = fq * (G @ fk), dks = fk * (G^T @ fq), dv = scores^T @ u, with
@@ -120,6 +107,7 @@ def lln_bwd_ref(qs: jnp.ndarray, ks: jnp.ndarray, v: jnp.ndarray,
     vf = _expand_kv(v, r).astype(jnp.float32)
     gf = g.astype(jnp.float32)
     of = o.astype(jnp.float32)
+    den = den[:, 0]
     u = gf / den[..., None]
     w = jnp.sum(gf * of, axis=-1) / den
     mask = jnp.tril(jnp.ones((qs.shape[1], qs.shape[1]), jnp.float32)) \

@@ -13,22 +13,19 @@ from __future__ import annotations
 import jax
 
 
-def compat_mesh(shape, axes):
-    """``jax.make_mesh`` across jax versions: ``axis_types`` (explicit-Auto)
-    only exists on newer releases; older ones are Auto-only anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: shardings propagate from
+    the arguments and the ``with_sharding_constraint`` hints."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_smoke_mesh(data: int = 1, model: int = 1):
     """Tiny mesh over whatever devices exist (tests)."""
-    return compat_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))

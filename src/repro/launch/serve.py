@@ -56,10 +56,11 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.configs.base import ShapeSpec
-from repro.launch.mesh import compat_mesh
-from repro.launch.steps import (flatten_spec_tokens, make_pool_setup,
-                                make_serve_setup, make_spec_setup,
-                                sample_token)
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
+from repro.launch.steps import (flatten_spec_tokens, init_params,
+                                make_pool_setup, make_serve_setup,
+                                make_spec_setup, sample_token)
 from repro.models import build_model, synthetic_batch
 
 
@@ -124,6 +125,7 @@ def main(argv=None):
                     help="[--continuous] resume from the latest snapshot "
                          "in --snapshot-dir before serving new requests")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     overrides = {}
     if args.attn_impl:
@@ -136,7 +138,7 @@ def main(argv=None):
     model = build_model(cfg)
 
     data, model_ax = (int(x) for x in args.mesh.split(","))
-    mesh = compat_mesh((data, model_ax), ("data", "model"))
+    mesh = make_mesh((data, model_ax), ("data", "model"))
     if args.continuous:
         return _run_continuous(cfg, model, mesh, args)
     if args.speculative:
@@ -146,8 +148,7 @@ def main(argv=None):
 
     with mesh:
         setup = make_serve_setup(cfg, shape, mesh, multi_pod=False)
-        params = jax.device_put(model.init(jax.random.PRNGKey(args.seed)),
-                                setup.params_shardings)
+        params = init_params(model, mesh, args.seed)
         batch = synthetic_batch(cfg, args.batch, max_len,
                                 text_seq=args.prompt_len)
         batch = {k: v for k, v in batch.items()}
@@ -229,7 +230,7 @@ def _run_speculative(cfg, model, mesh, args):
     with mesh:
         setup = make_spec_setup(cfg, shape, mesh, spec_k=args.spec_k,
                                 draft_layers=draft_layers)
-        params = jax.device_put(model.init(jax.random.PRNGKey(args.seed)))
+        params = init_params(model, mesh, args.seed)
         batch = synthetic_batch(cfg, args.batch, max_len,
                                 text_seq=args.prompt_len)
 
@@ -303,7 +304,7 @@ def _run_continuous(cfg, model, mesh, args):
                                 health=HealthConfig(
                                     check_drift=bool(args.drift))
                                 if args.health else None)
-        params = jax.device_put(model.init(jax.random.PRNGKey(args.seed)))
+        params = init_params(model, mesh, args.seed)
         eng = ContinuousBatcher(setup, params, queue_cap=args.queue_cap,
                                 snapshot_mgr=mgr,
                                 snapshot_every=(args.snapshot_every

@@ -118,9 +118,22 @@ def cache_shardings(cache_tree, cfg, mesh, rules):
 # Step builders.
 # ---------------------------------------------------------------------------
 
+def init_params(model: Model, mesh, seed: int):
+    """Build a model's parameters where their shardings
+    (``distributed/sharding.py:param_shardings``) place them: one jitted
+    init with those ``out_shardings``, so no device ever holds the whole
+    tree unless the mesh says so."""
+    key = jax.random.PRNGKey(seed)
+    shardings = shd.param_shardings(jax.eval_shape(model.init, key), mesh)
+    return jax.jit(model.init, out_shardings=shardings)(key)
+
+
 @dataclasses.dataclass
 class TrainSetup:
+    """``init_fn(key) -> state`` builds ``{"params", "opt"}`` already laid
+    out by ``state_shardings``."""
     step_fn: Any
+    init_fn: Any
     state_struct: Any
     state_shardings: Any
     batch: dict
@@ -194,7 +207,9 @@ def make_train_setup(cfg: ArchConfig, shape: ShapeSpec, mesh, *,
                       in_shardings=(state_shardings, None),
                       out_shardings=(state_shardings, None),
                       donate_argnums=(0,))
-    return TrainSetup(step_fn=step_fn, state_struct=state_struct,
+    init_fn = jax.jit(init_state, out_shardings=state_shardings)
+    return TrainSetup(step_fn=step_fn, init_fn=init_fn,
+                      state_struct=state_struct,
                       state_shardings=state_shardings, batch=batch,
                       rules=rules)
 

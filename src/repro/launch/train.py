@@ -27,8 +27,10 @@ from repro.data.pipeline import HostShardedSource, Prefetcher, device_placer
 from repro.data.synthetic import lm_batches, mlm_batches
 from repro.distributed import sharding as shd
 from repro.distributed.straggler import StepWatchdog
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import make_train_setup
-from repro.models import build_model, synthetic_batch
+from repro.models import synthetic_batch
 
 
 def main(argv=None):
@@ -50,6 +52,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--metrics-out", default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     overrides = {}
     if args.attn_impl:
@@ -57,8 +60,7 @@ def main(argv=None):
     cfg = get_config(args.arch, smoke=args.smoke, **overrides)
 
     data, model_ax = (int(x) for x in args.mesh.split(","))
-    from repro.launch.mesh import compat_mesh
-    mesh = compat_mesh((data, model_ax), ("data", "model"))
+    mesh = make_mesh((data, model_ax), ("data", "model"))
     shape = ShapeSpec("cli", args.seq, args.batch, "train")
 
     with mesh:
@@ -66,12 +68,7 @@ def main(argv=None):
                                  peak_lr=args.lr, total_steps=args.steps)
 
         def init_state():
-            m = build_model(cfg)
-            params = m.init(jax.random.PRNGKey(args.seed))
-            from repro.optim import adamw_init
-            return jax.device_put(
-                {"params": params, "opt": adamw_init(params)},
-                setup.state_shardings)
+            return setup.init_fn(jax.random.PRNGKey(args.seed))
 
         start_step = 0
         mgr = None

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from repro.core import attention as ca
 from repro.core.attention import AttnConfig
 from repro.core.engine import AttentionEngine
-from repro.kernels.registry import deprecated_shim
+from repro.kernels.registry import deprecated_shim, on_cpu
 from repro.distributed.sharding import constrain
 from .layers import dense, dense_init, rms_head_norm, rope
 
@@ -31,7 +31,9 @@ def attn_cfg_of(cfg, causal: bool = True) -> AttnConfig:
     return AttnConfig(impl=cfg.attn_impl, causal=causal,
                       diag_block=cfg.diag_block, lln_chunk=cfg.lln_chunk,
                       softmax_chunk=cfg.softmax_chunk,
-                      use_kernel=cfg.use_kernel,
+                      use_kernel=cfg.use_kernel or not on_cpu(),
+                      backend=(None if cfg.attn_backend == "auto"
+                               else cfg.attn_backend),
                       fixed_ab=cfg.lln_fixed_ab,
                       num_scales=getattr(cfg, "lln_num_scales", 4),
                       scale_decay=getattr(cfg, "lln_scale_decay", 0.5))

@@ -188,15 +188,8 @@ def moe_apply(p, x, cfg) -> tuple[jnp.ndarray, jnp.ndarray]:
         in_specs += [P(fsdp, "model"), P(fsdp, "model"), P("model", fsdp)]
     out_spec = (P(batch_axes, "model", None) if scatter
                 else P(batch_axes, None))
-    if hasattr(jax, "shard_map"):
-        smap = jax.shard_map(
-            shard_fn, mesh=mesh, in_specs=tuple(in_specs),
-            out_specs=(out_spec, P()), check_vma=False)
-    else:                               # older jax: experimental API
-        from jax.experimental.shard_map import shard_map as _shard_map
-        smap = _shard_map(
-            shard_fn, mesh=mesh, in_specs=tuple(in_specs),
-            out_specs=(out_spec, P()), check_rep=False)
+    smap = jax.shard_map(shard_fn, mesh=mesh, in_specs=tuple(in_specs),
+                         out_specs=(out_spec, P()), check_vma=False)
     out, aux = smap(*args)
     return out.reshape(b, n, d), aux
 

@@ -21,7 +21,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.numerics import einsum_f32
-from repro.distributed.sharding import constrain
+from repro.distributed.sharding import constrain, per_device
+from repro.kernels.registry import on_cpu
 from .layers import apply_norm, dense, dense_init, norm_init, trunc_normal
 
 
@@ -162,12 +163,24 @@ def ssm_apply(p, x, cfg, *, state0=None, return_state: bool = False,
     xh = constrain(xh, "act_batch", None, "heads", None)
     xbar = xh.astype(jnp.float32) * dt[..., None]
     rep = h // g
-    if cfg.use_kernel and state0 is None and not return_state \
+    if (cfg.use_kernel or not on_cpu()) and state0 is None \
+            and not return_state \
             and l % cfg.ssm_chunk == 0:
-        # Pallas SSD kernel (training fwd; groups via index maps, no repeat).
+        # Pallas SSD kernel (training fwd; groups via index maps, no repeat),
+        # per device over rows and heads under a mesh.  One group serves
+        # every head, so it is replicated; more groups split with the heads.
         from repro.kernels import ssd_scan
-        y = ssd_scan(xbar, b_proj.reshape(bsz, l, g, s),
-                     c_proj.reshape(bsz, l, g, s), log_a, cfg.ssm_chunk)
+        grp = "heads" if g > 1 else None
+        axes = {"xbar": ("act_batch", None, "heads", None),
+                "y": ("act_batch", None, "heads", None),
+                "b": ("act_batch", None, grp, None),
+                "c": ("act_batch", None, grp, None),
+                "log_a": ("act_batch", None, "heads")}
+        y = per_device(
+            lambda xbar, b, c, log_a: {"y": ssd_scan(xbar, b, c, log_a,
+                                                     cfg.ssm_chunk)},
+            axes.__getitem__, xbar=xbar, b=b_proj.reshape(bsz, l, g, s),
+            c=c_proj.reshape(bsz, l, g, s), log_a=log_a)["y"]
         state = None
     else:
         b_in = jnp.repeat(b_proj.reshape(bsz, l, g, s), rep, axis=2)

@@ -22,7 +22,7 @@ from repro.core import lln as core_lln
 from repro.kernels import ops as kops
 from repro.launch.batcher import (ContinuousBatcher, Request,
                                   synthetic_traffic)
-from repro.launch.mesh import compat_mesh
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import make_pool_setup, make_serve_setup
 from repro.models import build_model
 
@@ -82,7 +82,7 @@ class TestPoolParity:
         # the per-length compile path.
         reqs = synthetic_traffic(4, cfg.vocab, prompt_lens=[8, 8, 11],
                                  gen_lens=[2, 7, 4], seed=r)
-        mesh = compat_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         with mesh:
             setup = make_pool_setup(cfg, mesh, slots=2, max_len=max_len,
                                     segment=3)
@@ -111,7 +111,7 @@ class TestPoolParity:
         max_len = 32
         reqs = synthetic_traffic(3, cfg.vocab, prompt_lens=[8],
                                  gen_lens=[3, 6], seed=7)
-        mesh = compat_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         with mesh:
             setup = make_pool_setup(cfg, mesh, slots=2, max_len=max_len,
                                     segment=3)
@@ -232,7 +232,7 @@ class TestMaskedLogits:
         cfg = _tiny_cfg(impl, 2)
         model = build_model(cfg)
         params = model.init(jax.random.PRNGKey(4))
-        mesh = compat_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         with mesh:
             setup = make_pool_setup(cfg, mesh, slots=2, max_len=32,
                                     segment=4, temperature=0.7)
@@ -278,7 +278,7 @@ class TestEvictCalibration:
         cfg = _tiny_cfg("lln_diag", 2, fixed_ab=False)
         model = build_model(cfg)
         params = model.init(jax.random.PRNGKey(7))
-        mesh = compat_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         with mesh:
             setup = make_pool_setup(cfg, mesh, slots=2, max_len=32,
                                     segment=2)
@@ -316,7 +316,7 @@ class TestEvictCalibration:
         # Different prompt lengths => different lengths AND statistics.
         reqs = synthetic_traffic(2, cfg.vocab, prompt_lens=[8, 11],
                                  gen_lens=[3, 5], seed=11)
-        mesh = compat_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         with mesh:
             setup = make_pool_setup(cfg, mesh, slots=1, max_len=max_len,
                                     segment=2)
@@ -374,7 +374,7 @@ class TestAdmit:
         cfg = _tiny_cfg("lln_diag", 2)
         model = build_model(cfg)
         params = model.init(jax.random.PRNGKey(6))
-        mesh = compat_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         with mesh:
             setup = make_pool_setup(cfg, mesh, slots=3, max_len=32,
                                     segment=2)
@@ -447,7 +447,7 @@ class TestSpeculativePool:
         max_len = 48
         reqs = synthetic_traffic(4, cfg.vocab, prompt_lens=[8, 8, 11],
                                  gen_lens=[2, 7, 4], seed=r)
-        mesh = compat_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         with mesh:
             setup = make_pool_setup(cfg, mesh, slots=2, max_len=max_len,
                                     segment=3, spec_k=self.SPEC_K,
@@ -479,7 +479,7 @@ class TestSpeculativePool:
         reqs = synthetic_traffic(2, cfg.vocab, prompt_lens=[8],
                                  gen_lens=[9], seed=5)
         plan = FaultPlan(events=[{"kind": "nan", "segment": 1, "row": 0}])
-        mesh = compat_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         with mesh:
             setup = make_pool_setup(cfg, mesh, slots=2, max_len=max_len,
                                     segment=2, spec_k=self.SPEC_K,
@@ -505,7 +505,7 @@ class TestSpeculativePool:
         model = build_model(cfg)
         params = model.init(jax.random.PRNGKey(0))
         max_len = 48
-        mesh = compat_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         # gen_len chosen NOT ≡ 1 (mod spec_k+1) so expiry can land
         # mid-iteration; max_tokens on rid 1 exercises the min() budget.
         reqs = [Request(rid=0, prompt=np.arange(2, 10, dtype=np.int32),
@@ -533,7 +533,7 @@ class TestSpeculativePool:
         cfg = _tiny_cfg("lln_diag", 2)
         model = build_model(cfg)
         params = model.init(jax.random.PRNGKey(0))
-        mesh = compat_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         with mesh:
             setup = make_pool_setup(cfg, mesh, slots=2, max_len=24,
                                     segment=2, spec_k=self.SPEC_K,

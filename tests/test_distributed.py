@@ -102,12 +102,12 @@ class TestElastic:
             from repro.checkpoint.checkpointer import save, restore
             from repro.distributed.sharding import param_shardings
             d = tempfile.mkdtemp()
-            from repro.launch.mesh import compat_mesh
-            mesh1 = compat_mesh((2, 4), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh1 = make_mesh((2, 4), ("data", "model"))
             tree = {"layers": {"q_w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}}
             tree = jax.device_put(tree, param_shardings(tree, mesh1))
             save(d, 1, tree)
-            mesh2 = compat_mesh((4, 2), ("data", "model"))
+            mesh2 = make_mesh((4, 2), ("data", "model"))
             template = jax.tree_util.tree_map(
                 lambda a: jnp.zeros(a.shape, a.dtype), tree)
             out = restore(d, 1, template, param_shardings(template, mesh2))
@@ -131,7 +131,7 @@ class TestElastic:
             from repro.configs.base import ArchConfig
             from repro.distributed.elastic import (make_degraded_mesh,
                                                    reshard_state)
-            from repro.launch.mesh import compat_mesh
+            from repro.launch.mesh import make_mesh
             from repro.launch.steps import make_pool_setup
             from repro.models import build_model
 
@@ -152,7 +152,7 @@ class TestElastic:
             active = jnp.asarray([True, False])
             key = jax.random.PRNGKey(2)
 
-            mesh1 = compat_mesh((2, 4), ("data", "model"))
+            mesh1 = make_mesh((2, 4), ("data", "model"))
             with mesh1:
                 setup1 = make_pool_setup(cfg, mesh1, slots=2, max_len=32,
                                          segment=4)
@@ -208,8 +208,8 @@ class TestMultiDeviceTraining:
             from repro.optim import adamw_init
 
             cfg = get_config("yi-9b", smoke=True, attn_impl="lln_diag")
-            from repro.launch.mesh import compat_mesh
-            mesh = compat_mesh((2, 4), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((2, 4), ("data", "model"))
             shape = ShapeSpec("t", 32, 4, "train")
             with mesh:
                 setup = make_train_setup(cfg, shape, mesh, multi_pod=False)
@@ -240,8 +240,8 @@ class TestMultiDeviceTraining:
             from repro.models import build_model, synthetic_batch
 
             cfg = get_config("yi-9b", smoke=True)
-            from repro.launch.mesh import compat_mesh
-            mesh = compat_mesh((2, 4), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((2, 4), ("data", "model"))
             shape = ShapeSpec("s", 48, 4, "decode")
             with mesh:
                 setup = make_serve_setup(cfg, shape, mesh, multi_pod=False)
@@ -261,3 +261,129 @@ class TestMultiDeviceTraining:
                 print("SERVE_OK")
         """)
         assert "SERVE_OK" in out
+
+
+class TestHeadParallelKernels:
+    def test_pool_and_train_match_one_device_subprocess(self):
+        """Pallas kernels (interpreted) under a model=4 mesh run per device
+        over their heads: pool greedy tokens and prefill logits, and the
+        kernel-path training loss, match the one-device mesh; parameters
+        are built already split over the model axis."""
+        out = _run_subprocess("""
+            import jax, jax.numpy as jnp, numpy as np
+            from repro.configs.base import ArchConfig
+            from repro.distributed.sharding import logical_rules, make_rules
+            from repro.launch.batcher import ContinuousBatcher, synthetic_traffic
+            from repro.launch.mesh import make_mesh
+            from repro.launch.steps import init_params, make_pool_setup
+            from repro.models import build_model, synthetic_batch
+
+            cfg = ArchConfig(
+                name="hp", family="dense", n_layers=2, d_model=64,
+                n_heads=8, n_kv_heads=4, head_dim=16, d_ff=128, vocab=128,
+                attn_impl="lln_diag", diag_block=8, lln_chunk=8,
+                softmax_chunk=16, compute_dtype="float32", remat="none",
+                attn_backend="pallas", use_kernel=True)
+            reqs = synthetic_traffic(4, cfg.vocab, (8, 16), (3, 6))
+
+            def serve(mesh):
+                with mesh:
+                    setup = make_pool_setup(cfg, mesh, slots=2, max_len=24,
+                                            segment=4)
+                    params = init_params(setup.model, mesh, 0)
+                    logits, _ = setup.prefill_fn(8)(
+                        params, jnp.asarray(reqs[0].prompt[None]))
+                    stats = ContinuousBatcher(setup, params).run(reqs)
+
+                    def loss_fn(p, b):
+                        with logical_rules(mesh, make_rules(
+                                cfg, multi_pod=False)):
+                            return build_model(cfg).loss(p, b)
+                    loss = jax.jit(loss_fn)(params,
+                                            synthetic_batch(cfg, 2, 16))
+                return params, np.asarray(logits), stats.outputs, float(loss)
+
+            _, l1, o1, loss1 = serve(make_mesh((1, 1), ("data", "model")))
+            p4, l4, o4, loss4 = serve(make_mesh((1, 4), ("data", "model")))
+            assert len(p4["layers"]["attn"]["q_w"].sharding.device_set) == 4
+            np.testing.assert_allclose(l4, l1, rtol=1e-4, atol=1e-4)
+            assert all(np.array_equal(o1[r], o4[r]) for r in o1), (o1, o4)
+            assert abs(loss1 - loss4) < 1e-4, (loss1, loss4)
+            print("HEAD_PARALLEL_OK")
+        """, devices=4)
+        assert "HEAD_PARALLEL_OK" in out
+
+    def test_rows_and_heads_split_grads_match_subprocess(self):
+        """On a data=2, model=4 mesh each device's kernel call gets half
+        the rows, and a quarter of the heads where query and kv heads both
+        divide (kv heads that do not divide keep the heads whole but still
+        split the rows); the Mamba2 SSD kernel splits the same way.  Loss
+        and every gradient leaf of the kernel path match one device."""
+        out = _run_subprocess("""
+            import jax, jax.numpy as jnp, numpy as np
+            import repro.kernels as kernels
+            from repro.configs import get_config
+            from repro.configs.base import ArchConfig
+            from repro.distributed.sharding import logical_rules, make_rules
+            from repro.kernels import registry
+            from repro.launch.mesh import make_mesh
+            from repro.launch.steps import init_params
+            from repro.models import build_model, synthetic_batch
+
+            blocks = []
+            real_attn, real_ssd = registry.attention, kernels.ssd_scan
+
+            def attn_spy(spec, q, *a, **k):
+                blocks.append(q.shape)
+                return real_attn(spec, q, *a, **k)
+
+            def ssd_spy(xbar, *a, **k):
+                blocks.append(xbar.shape)
+                return real_ssd(xbar, *a, **k)
+
+            registry.attention, kernels.ssd_scan = attn_spy, ssd_spy
+
+            def grads(cfg, mesh, batch):
+                model = build_model(cfg)
+                rules = make_rules(cfg, multi_pod=False)
+                with mesh:
+                    params = init_params(model, mesh, 0)
+
+                    def f(p, b):
+                        with logical_rules(mesh, rules):
+                            return jax.value_and_grad(model.loss)(p, b)
+                    blocks.clear()
+                    loss, g = jax.jit(f)(params, batch)
+                return float(loss), g, set(blocks)
+
+            dense = dict(name="rh", family="dense", n_layers=2, d_model=64,
+                         n_heads=8, head_dim=16, d_ff=128, vocab=128,
+                         attn_impl="lln_diag", diag_block=8, lln_chunk=8,
+                         softmax_chunk=16, compute_dtype="float32",
+                         remat="none", attn_backend="pallas", use_kernel=True)
+            mamba = get_config("mamba2-130m", smoke=True).replace(
+                use_kernel=True, compute_dtype="float32")
+            cases = [   # (config, batch, seq, per-device kernel block)
+                (ArchConfig(n_kv_heads=4, **dense), 4, 16, {(2, 16, 2, 16)}),
+                (ArchConfig(n_kv_heads=2, **dense), 4, 16, {(2, 16, 8, 16)}),
+                # replicate: rows over data x model; tp_heads: heads too
+                (mamba, 8, 32, {(1, 32, 4, 32)}),
+                (mamba.replace(attn_shard="tp_heads"), 4, 32,
+                 {(2, 32, 1, 32)}),
+            ]
+            for cfg, rows, seq, want in cases:
+                batch = synthetic_batch(cfg, rows, seq)
+                l1, g1, whole = grads(cfg, make_mesh((1, 1),
+                                                     ("data", "model")), batch)
+                l8, g8, seen = grads(cfg, make_mesh((2, 4), ("data", "model")),
+                                     batch)
+                # Tracing the output shapes also sees the whole block once.
+                assert seen - whole == want, (cfg.name, seen, want)
+                assert abs(l1 - l8) < 1e-5, (cfg.name, l1, l8)
+                for a, b in zip(jax.tree_util.tree_leaves(g1),
+                                jax.tree_util.tree_leaves(g8)):
+                    np.testing.assert_allclose(np.asarray(b), np.asarray(a),
+                                               rtol=2e-4, atol=2e-6)
+            print("ROWS_HEADS_OK")
+        """, devices=8)
+        assert "ROWS_HEADS_OK" in out

@@ -32,7 +32,7 @@ from repro.core.metrics import (spectral_gap, spectral_gap_power,
                                 streaming_concentration)
 from repro.kernels import ops as kops
 from repro.launch.batcher import ContinuousBatcher, synthetic_traffic
-from repro.launch.mesh import compat_mesh
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import make_pool_setup
 from repro.models import build_model
 
@@ -167,7 +167,7 @@ class TestLengthSchedule:
         for cfg in (cfg0, cfg1):
             model = build_model(cfg)
             params = model.init(jax.random.PRNGKey(0))
-            mesh = compat_mesh((1, 1), ("data", "model"))
+            mesh = make_mesh((1, 1), ("data", "model"))
             with mesh:
                 setup = make_pool_setup(cfg, mesh, slots=2, max_len=32,
                                         segment=3)
@@ -201,7 +201,7 @@ class TestPoolRobustness:
         max_len = 40
         reqs = synthetic_traffic(4, cfg.vocab, prompt_lens=[8, 8, 14],
                                  gen_lens=[3, 9, 5], seed=11)
-        mesh = compat_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         with mesh:
             setup = make_pool_setup(cfg, mesh, slots=2, max_len=max_len,
                                     segment=3)
@@ -223,7 +223,7 @@ class TestPoolRobustness:
         cfg = _robust_cfg("robust-drift")
         model = build_model(cfg)
         params = model.init(jax.random.PRNGKey(0))
-        mesh = compat_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         with mesh:
             setup = make_pool_setup(
                 cfg, mesh, slots=2, max_len=32, segment=3,
@@ -241,7 +241,7 @@ class TestPoolRobustness:
         cfg = _robust_cfg("robust-tele")
         model = build_model(cfg)
         params = model.init(jax.random.PRNGKey(0))
-        mesh = compat_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         with mesh:
             setup = make_pool_setup(cfg, mesh, slots=2, max_len=32,
                                     segment=3)

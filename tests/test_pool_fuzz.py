@@ -38,7 +38,7 @@ except ImportError:                                    # container has no
 from repro.configs.base import ArchConfig, ShapeSpec
 from repro.launch.batcher import ContinuousBatcher, Request
 from repro.launch.faults import FaultPlan
-from repro.launch.mesh import compat_mesh
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import (flatten_spec_tokens, make_pool_setup,
                                 make_serve_setup, make_spec_setup)
 from repro.models import build_model
@@ -76,7 +76,7 @@ def _pool(spec: bool, impl: str = "lln_diag"):
         cfg = _cfg(impl)
         model = build_model(cfg)
         params = model.init(jax.random.PRNGKey(0))
-        mesh = compat_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         with mesh:
             setup = make_pool_setup(
                 cfg, mesh, slots=SLOTS, max_len=MAX_LEN, segment=SEGMENT,

@@ -34,7 +34,7 @@ from repro.launch.batcher import (AdmissionError, ContinuousBatcher,
                                   QueueFullError, Request, synthetic_traffic)
 from repro.launch.faults import (FaultEvent, FaultPlan, SimulatedCrash,
                                  poison_rows)
-from repro.launch.mesh import compat_mesh
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import make_pool_setup
 from repro.models import build_model
 
@@ -67,7 +67,7 @@ def pool():
     cfg = _tiny_cfg()
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
-    mesh = compat_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with mesh:
         setup = make_pool_setup(cfg, mesh, slots=2, max_len=48, segment=3)
         yield _Pool(cfg=cfg, model=model, params=params, mesh=mesh,

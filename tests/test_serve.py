@@ -304,12 +304,12 @@ class TestEndToEnd:
     def test_scanned_generate_matches_loop(self):
         """ServeSetup.make_generate (one lax.scan dispatch) produces the
         same greedy tokens as the per-token decode_fn loop."""
-        from repro.launch.mesh import compat_mesh
+        from repro.launch.mesh import make_mesh
         from repro.launch.steps import make_serve_setup
         cfg = _tiny_cfg("lln_diag", 2)
         model = build_model(cfg)
         n_prompt, steps = 16, 6
-        mesh = compat_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         shape = ShapeSpec("t", n_prompt + steps + 1, 2, "decode")
         with mesh:
             setup = make_serve_setup(cfg, shape, mesh, multi_pod=False)

@@ -26,7 +26,7 @@ from repro.core import speculative as spec
 from repro.core.engine import AttentionEngine
 from repro.kernels import ops as kops
 from repro.kernels.registry import AttnSpec
-from repro.launch.mesh import compat_mesh
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import (flatten_spec_tokens, make_serve_setup,
                                 make_spec_setup)
 from repro.models import build_model, draft_config, draft_params, \
@@ -322,7 +322,7 @@ def _run_pair(cfg, draft_layers, spec_k, steps, bsz=2, plen=12, seed=0):
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(seed))
     max_len = plen + steps + spec_k + 2
-    mesh = compat_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shape = ShapeSpec("spec", max_len, bsz, "decode")
     batch = synthetic_batch(cfg, bsz, max_len, text_seq=plen)
     with mesh:
@@ -400,7 +400,7 @@ class TestSpecParity:
         params = model.init(jax.random.PRNGKey(0))
         bsz, plen, steps, k = 2, 12, 6, 2
         max_len = plen + steps + k + 2
-        mesh = compat_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         shape = ShapeSpec("spec", max_len, bsz, "decode")
         batch = synthetic_batch(cfg, bsz, max_len, text_seq=plen)
         with mesh:
