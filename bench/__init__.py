@@ -1,0 +1,7 @@
+"""On-chip benchmark of the LLN serving and training stack.
+
+``python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>``
+runs one cell of ``BENCHMARK.json`` once and prints one JSON result line.
+Configurations, traffic mixes, correctness limits and metric readers are
+files under this directory, found by the names ``BENCHMARK.json`` gives.
+"""
