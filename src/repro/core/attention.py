@@ -499,6 +499,28 @@ def decode_softmax(cache: KVCache, q: jnp.ndarray, k_new: jnp.ndarray,
     return out, KVCache(k=ret_k, v=ret_v, length=new_len)
 
 
+def _roll_tail(state: LLNDecodeState, k_t, v_t, posb, cl, idx):
+    """The rolling diag-tail update over a chunk of T tokens: for each
+    slot i the last *committed* chunk token writing it is
+    j_i = j0 + block*((c-1-j0)//block), j0 = (i-pos)%blk, c the per-row
+    committed length ``cl`` (= T for a plain decode).  Returns the new
+    ``(tail_k, tail_v)``."""
+    block = state.tail_k.shape[1]
+    t = k_t.shape[1]
+    j0 = jnp.mod(idx[None, :] - posb[:, None], block)             # (B, BLK)
+    j_last = jnp.clip(j0 + block * ((cl[:, None] - 1 - j0) // block),
+                      0, t - 1)
+    wrote = (j0 < cl[:, None])[:, :, None, None]
+    gather = j_last[:, :, None, None]
+    tail_k = jnp.where(wrote, jnp.take_along_axis(k_t, gather, axis=1
+                                                  ).astype(state.tail_k.dtype),
+                       state.tail_k)
+    tail_v = jnp.where(wrote, jnp.take_along_axis(v_t, gather, axis=1
+                                                  ).astype(state.tail_v.dtype),
+                       state.tail_v)
+    return tail_k, tail_v
+
+
 def decode_lln_chunk(state: LLNDecodeState, q: jnp.ndarray,
                      k_new: jnp.ndarray, v_new: jnp.ndarray,
                      alpha: jnp.ndarray, beta: jnp.ndarray,
@@ -560,10 +582,7 @@ def decode_lln_chunk(state: LLNDecodeState, q: jnp.ndarray,
             alpha, beta_h, row_mask=row_mask, commit_len=commit_len,
             renorm=renorm)
 
-    # --- rolling tail update, vectorized: for each slot i the last
-    # *committed* chunk token writing it is j_i = j0 + block*((c-1-j0)//block),
-    # j0 = (i-pos)%blk, c the per-row committed length (= t for a plain
-    # decode).
+    # --- rolling tail update, vectorized (``_roll_tail``).
     block = state.tail_k.shape[1]
     gt = state.tail_k.shape[2]          # tail head count (G, or H for seed)
     k_t = _repeat_kv(k_new, gt) if k_new.shape[2] != gt else k_new
@@ -577,17 +596,8 @@ def decode_lln_chunk(state: LLNDecodeState, q: jnp.ndarray,
     else:
         cl = jnp.full((b,), t, jnp.int32)
     idx = jnp.arange(block)
-    j0 = jnp.mod(idx[None, :] - posb[:, None], block)             # (B, BLK)
-    j_last = jnp.clip(j0 + block * ((cl[:, None] - 1 - j0) // block),
-                      0, t - 1)
-    wrote = (j0 < cl[:, None])[:, :, None, None]
-    gather = j_last[:, :, None, None]
-    tail_k = jnp.where(wrote, jnp.take_along_axis(k_t, gather, axis=1
-                                                  ).astype(state.tail_k.dtype),
-                       state.tail_k)
-    tail_v = jnp.where(wrote, jnp.take_along_axis(v_t, gather, axis=1
-                                                  ).astype(state.tail_v.dtype),
-                       state.tail_v)
+    with jax.named_scope("diag_tail"):
+        tail_k, tail_v = _roll_tail(state, k_t, v_t, posb, cl, idx)
     if commit_len is not None:
         new_pos = posb + cl         # always per-row under partial commit
     elif row_mask is not None:
@@ -702,18 +712,9 @@ def commit_lln_chunk(state: LLNDecodeState, k_new: jnp.ndarray,
     v_t = _repeat_kv(v_new, gt) if v_new.shape[2] != gt else v_new
     posb = jnp.broadcast_to(jnp.asarray(state.pos, jnp.int32), (b,))
     cl = lln_mod.commit_lengths(commit_len, row_mask, t)
-    idx = jnp.arange(block)
-    j0 = jnp.mod(idx[None, :] - posb[:, None], block)             # (B, BLK)
-    j_last = jnp.clip(j0 + block * ((cl[:, None] - 1 - j0) // block),
-                      0, t - 1)
-    wrote = (j0 < cl[:, None])[:, :, None, None]
-    gather = j_last[:, :, None, None]
-    tail_k = jnp.where(wrote, jnp.take_along_axis(k_t, gather, axis=1
-                                                  ).astype(state.tail_k.dtype),
-                       state.tail_k)
-    tail_v = jnp.where(wrote, jnp.take_along_axis(v_t, gather, axis=1
-                                                  ).astype(state.tail_v.dtype),
-                       state.tail_v)
+    with jax.named_scope("diag_tail"):
+        tail_k, tail_v = _roll_tail(state, k_t, v_t, posb, cl,
+                                    jnp.arange(block))
     return LLNDecodeState(lln=lln_state, tail_k=tail_k, tail_v=tail_v,
                           pos=posb + cl)
 

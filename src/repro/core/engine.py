@@ -140,6 +140,7 @@ jax.tree_util.register_pytree_with_keys(
     _state_flatten)
 
 
+@jax.named_scope("diag_tail")
 def _tail_of(t: jnp.ndarray, n: int, blk: int) -> jnp.ndarray:
     """Contents of the (partially filled) last ``blk``-sized block."""
     nb = -(-n // blk)

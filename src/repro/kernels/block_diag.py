@@ -84,6 +84,7 @@ def block_diag_bwd_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     return pl.pallas_call(
         functools.partial(_block_diag_bwd_kernel, blk=blk, scale=scale,
                           causal=causal),
+        name="block_diag_bwd",
         grid=(bg, nb, r),
         in_specs=[
             pl.BlockSpec((1, blk, d),
@@ -118,6 +119,7 @@ def block_diag_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     return pl.pallas_call(
         functools.partial(_block_diag_kernel, blk=blk, scale=scale,
                           causal=causal),
+        name="block_diag",
         grid=(bh, nb),
         in_specs=[
             pl.BlockSpec((1, blk, d), lambda h, j: (h, j, 0)),

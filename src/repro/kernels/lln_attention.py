@@ -120,6 +120,7 @@ def lln_causal_pallas(qs: jnp.ndarray, ks: jnp.ndarray, v: jnp.ndarray, *,
     res = pl.pallas_call(
         functools.partial(_lln_causal_kernel, blk=blk, with_res=return_res,
                           with_state=return_state),
+        name="lln_causal",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, blk, d), lambda h, j: (h, j, 0)),
@@ -179,6 +180,7 @@ def lln_bidir_pallas(qs: jnp.ndarray, ks: jnp.ndarray, v: jnp.ndarray, *,
     nb = n // blk
     s, z = pl.pallas_call(
         _lln_reduce_kernel,
+        name="lln_reduce",
         grid=(bg, nb),
         in_specs=[
             pl.BlockSpec((1, blk, d), lambda g, j: (g, j, 0)),
@@ -199,6 +201,7 @@ def lln_bidir_pallas(qs: jnp.ndarray, ks: jnp.ndarray, v: jnp.ndarray, *,
         out_shape.append(jax.ShapeDtypeStruct((bh, 1, n), jnp.float32))
     res = pl.pallas_call(
         functools.partial(_lln_apply_kernel, with_res=return_res),
+        name="lln_apply",
         grid=(bh, nb),
         in_specs=[
             pl.BlockSpec((1, blk, d), lambda h, j: (h, j, 0)),
@@ -297,6 +300,7 @@ def lln_diag_fused_pallas(qs, ks, q, k, v, *, r: int = 1, blk: int = 256,
     res = pl.pallas_call(
         functools.partial(_lln_diag_fused_kernel, blk=blk, scale=scale,
                           causal=causal, with_res=return_res),
+        name="lln_diag_fused",
         grid=(bh, nb),
         in_specs=[
             pl.BlockSpec((1, blk, d), lambda h, j: (h, j, 0)),
@@ -360,6 +364,7 @@ def lln_decode_pallas(qs: jnp.ndarray, ks: jnp.ndarray, v: jnp.ndarray,
     dv = v.shape[-1]
     return pl.pallas_call(
         functools.partial(_lln_decode_kernel, t=t),
+        name="lln_decode",
         grid=(bh,),
         in_specs=[
             pl.BlockSpec((1, t, d), lambda h: (h, 0, 0)),

@@ -172,6 +172,7 @@ def lln_causal_bwd_pallas(qs, ks, v, g, o, den, *, r: int = 1,
     nb = n // blk
     dqs = pl.pallas_call(
         functools.partial(_causal_dq_kernel, blk=blk),
+        name="lln_causal_dq",
         grid=(bh, nb),
         in_specs=[
             pl.BlockSpec((1, blk, d), lambda h, j: (h, j, 0)),
@@ -192,6 +193,7 @@ def lln_causal_bwd_pallas(qs, ks, v, g, o, den, *, r: int = 1,
     # innermost r axis accumulates the GQA segment-sum in the output block.
     dks, dvv = pl.pallas_call(
         functools.partial(_causal_dkv_kernel, blk=blk, r=r),
+        name="lln_causal_dkv",
         grid=(bg, nb, r),
         in_specs=[
             pl.BlockSpec((1, blk, d),
@@ -272,6 +274,7 @@ def lln_bidir_bwd_pallas(qs, ks, v, g, o, den, s, z, *, r: int = 1,
     nb = n // blk
     dqs = pl.pallas_call(
         _bidir_dq_kernel,
+        name="lln_bidir_dq",
         grid=(bh, nb),
         in_specs=[
             pl.BlockSpec((1, blk, d), lambda h, j: (h, j, 0)),
@@ -291,6 +294,7 @@ def lln_bidir_bwd_pallas(qs, ks, v, g, o, den, s, z, *, r: int = 1,
     # block, so the accumulation stays in VMEM until the head changes.
     dsg, dzg = pl.pallas_call(
         _bidir_reduce_kernel,
+        name="lln_bidir_reduce",
         grid=(bg, r, nb),
         in_specs=[
             pl.BlockSpec((1, blk, d),
@@ -313,6 +317,7 @@ def lln_bidir_bwd_pallas(qs, ks, v, g, o, den, s, z, *, r: int = 1,
 
     dks, dvv = pl.pallas_call(
         _bidir_dkv_kernel,
+        name="lln_bidir_dkv",
         grid=(bg, nb),
         in_specs=[
             pl.BlockSpec((1, blk, d), lambda gi, j: (gi, j, 0)),
@@ -463,6 +468,7 @@ def lln_diag_fused_bwd_pallas(qs, ks, q, k, v, g, o, den, *, r: int = 1,
 
     dqs, dqd = pl.pallas_call(
         functools.partial(_fused_dq_kernel, blk=blk, scale=scale),
+        name="lln_diag_fused_dq",
         grid=(bh, nb),
         in_specs=[
             q_spec((1, blk, d)),
@@ -493,6 +499,7 @@ def lln_diag_fused_bwd_pallas(qs, ks, q, k, v, g, o, den, *, r: int = 1,
 
     dks, dkd, dvv = pl.pallas_call(
         functools.partial(_fused_dkv_kernel, blk=blk, scale=scale, r=r),
+        name="lln_diag_fused_dkv",
         grid=(bg, nb, r),
         in_specs=[
             qr_spec((1, blk, d)),

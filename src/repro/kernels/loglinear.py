@@ -139,6 +139,7 @@ def loglin_causal_pallas(qs: jnp.ndarray, ks: jnp.ndarray, v: jnp.ndarray, *,
         functools.partial(_loglin_causal_kernel, blk=blk,
                           num_scales=ls, weights=weights,
                           with_state=return_state),
+        name="loglin_causal",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, blk, d), lambda h, j: (h, j, 0)),

@@ -19,6 +19,10 @@ Responsibilities:
   ragged sequence lengths (n % chunk != 0, same static dispatch as the
   forward) and (b) an explicit ``pallas_bwd=False`` escape used by
   ``benchmarks/bench_train_step.py`` to measure the speedup.
+* device scopes: every kernel call runs inside a ``jax.named_scope`` that
+  names it on every backend (``lln_prefill``, ``lln_decode``, ``lln_bidir``,
+  ``lln_bidir_bwd``, ``block_diag``), so a profiler trace or the compiled
+  module's ``op_name`` metadata finds it after a refactor.
 
 alpha/beta are calibration constants (moment matching) — non-differentiable
 by construction; gradients w.r.t. them are zero.
@@ -183,8 +187,13 @@ def _lln_fwd_impl(q, k, v, alpha, beta, causal, chunk, interpret,
         return _lln_quad_ref(q, k, v, alpha, beta, causal)
     qs, ks, _, _ = _scaled_stabilized(q, k, alpha, beta)
     vk = _to_kernel(v)
-    fn = lln_causal_pallas if causal else lln_bidir_pallas
-    out = fn(qs, ks, vk, r=h // g, blk=chunk, interpret=ip)
+    if causal:
+        out = lln_causal_pallas(qs, ks, vk, r=h // g, blk=chunk,
+                                interpret=ip)
+    else:
+        with jax.named_scope("lln_bidir"):
+            out = lln_bidir_pallas(qs, ks, vk, r=h // g, blk=chunk,
+                                   interpret=ip)
     return _from_kernel(out, b)
 
 
@@ -235,8 +244,10 @@ def _lln_vjp_fwd(q, k, v, alpha, beta, causal, chunk, interpret, pallas_bwd,
                                        interpret=ip, return_res=True)
         s = z = None
     else:
-        out_k, s, z, den = lln_bidir_pallas(qs, ks, vk, r=h // g, blk=chunk,
-                                            interpret=ip, return_res=True)
+        with jax.named_scope("lln_bidir"):
+            out_k, s, z, den = lln_bidir_pallas(qs, ks, vk, r=h // g,
+                                                blk=chunk, interpret=ip,
+                                                return_res=True)
     res = {"pallas": (qs, ks, vk, out_k, den, s, z, alpha_b, beta_b,
                       _dtype_tag(q), _dtype_tag(k), _dtype_tag(v),
                       jnp.asarray(alpha, jnp.float32),
@@ -267,13 +278,14 @@ def _lln_vjp_bwd(causal, chunk, interpret, pallas_bwd, backend, res, g_out):
             dqs, dks, dvk = lln_causal_bwd_scan(qs, ks, vk, gk, out_k, den,
                                                 r=r, blk=chunk)
     else:
-        if _kernel_bwd(interpret):
-            dqs, dks, dvk = lln_bidir_bwd_pallas(qs, ks, vk, gk, out_k, den,
-                                                 s, z, r=r, blk=chunk,
-                                                 interpret=ip)
-        else:
-            dqs, dks, dvk = lln_bidir_bwd_scan(qs, ks, vk, gk, out_k, den,
-                                               s, z, r=r, blk=chunk)
+        with jax.named_scope("lln_bidir_bwd"):
+            if _kernel_bwd(interpret):
+                dqs, dks, dvk = lln_bidir_bwd_pallas(
+                    qs, ks, vk, gk, out_k, den, s, z, r=r, blk=chunk,
+                    interpret=ip)
+            else:
+                dqs, dks, dvk = lln_bidir_bwd_scan(qs, ks, vk, gk, out_k,
+                                                   den, s, z, r=r, blk=chunk)
     # Chain rule through qs = alpha*q - stop_grad(c_q) (and same for k);
     # _row_head_bcast handles per-head (H,) and per-row (B, H) calibration.
     dq = (_from_kernel(dqs, b) * _row_head_bcast(alpha_b)).astype(tq.dtype)
@@ -292,6 +304,7 @@ lln_attention.defvjp(_lln_vjp_fwd, _lln_vjp_bwd)
 # interpret mode (CPU container), jnp reference for ragged lengths.
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("lln_prefill")
 def lln_prefill(q, k, v, alpha, beta, chunk: int = 256,
                 interpret: Optional[bool] = None, backend: str = "auto"):
     """Causal LLN prefill emitting outputs AND the decode state in one pass.
@@ -373,6 +386,7 @@ def _lln_prefill_scan(qs, ks, vk, *, r: int, blk: int):
     return out, s, z[:, None, :]
 
 
+@jax.named_scope("block_diag")
 def block_diag_fwd(q, k, v, block: int = 256, causal: bool = True,
                    interpret: Optional[bool] = None, backend: str = "auto"):
     """Inference-only block-diagonal softmax with the serving dispatch:
@@ -415,6 +429,7 @@ def _block_diag_twin(q, k, v, block, causal):
     return out.reshape(b, n, h, dv).astype(v.dtype)
 
 
+@jax.named_scope("lln_decode")
 def lln_decode_chunk(state, q, k, v, alpha, beta,
                      interpret: Optional[bool] = None,
                      row_mask: Optional[jnp.ndarray] = None,
@@ -1015,9 +1030,10 @@ def _diag_fwd_impl(q, k, v, block, causal, interpret, backend="auto"):
         return _diag_ref(q, k, v, block, causal)
     if kind == "scan":
         return _block_diag_twin(q, k, v, block, causal)
-    out = block_diag_pallas(_to_kernel(q), _to_kernel(k), _to_kernel(v),
-                            r=h // g, blk=block, causal=causal,
-                            interpret=ip)
+    with jax.named_scope("block_diag"):
+        out = block_diag_pallas(_to_kernel(q), _to_kernel(k), _to_kernel(v),
+                                r=h // g, blk=block, causal=causal,
+                                interpret=ip)
     return _from_kernel(out, b)
 
 
@@ -1036,9 +1052,10 @@ def _diag_vjp_fwd(q, k, v, block, causal, interpret, pallas_bwd,
         return (_diag_fwd_impl(q, k, v, block, causal, interpret, backend),
                 {"ref": (q, k, v)})
     qk, kk, vk = _to_kernel(q), _to_kernel(k), _to_kernel(v)
-    out = block_diag_pallas(qk, kk, vk, r=q.shape[2] // k.shape[2],
-                            blk=block, causal=causal,
-                            interpret=_interpret(interpret))
+    with jax.named_scope("block_diag"):
+        out = block_diag_pallas(qk, kk, vk, r=q.shape[2] // k.shape[2],
+                                blk=block, causal=causal,
+                                interpret=_interpret(interpret))
     res = {"pallas": (qk, kk, vk, _dtype_tag(q), _dtype_tag(k),
                       _dtype_tag(v))}
     return _from_kernel(out, q.shape[0]), res
@@ -1053,13 +1070,14 @@ def _diag_vjp_bwd(block, causal, interpret, pallas_bwd, backend, res, g_out):
     qk, kk, vk, tq, tk, tv = res["pallas"]
     b = g_out.shape[0]
     r = (qk.shape[0] // b) // (kk.shape[0] // b)
-    if _kernel_bwd(interpret):
-        dq, dk, dv = block_diag_bwd_pallas(qk, kk, vk, _to_kernel(g_out),
-                                           r=r, blk=block, causal=causal,
-                                           interpret=_interpret(interpret))
-    else:
-        dq, dk, dv = block_diag_bwd_scan(qk, kk, vk, _to_kernel(g_out),
-                                         r=r, blk=block, causal=causal)
+    with jax.named_scope("block_diag"):
+        if _kernel_bwd(interpret):
+            dq, dk, dv = block_diag_bwd_pallas(
+                qk, kk, vk, _to_kernel(g_out), r=r, blk=block,
+                causal=causal, interpret=_interpret(interpret))
+        else:
+            dq, dk, dv = block_diag_bwd_scan(qk, kk, vk, _to_kernel(g_out),
+                                             r=r, blk=block, causal=causal)
     return (_from_kernel(dq, b).astype(tq.dtype),
             _from_kernel(dk, b).astype(tk.dtype),
             _from_kernel(dv, b).astype(tv.dtype))
@@ -1114,9 +1132,12 @@ def _lln_diag_fwd_impl(q, k, v, alpha, beta, causal, block, interpret,
                                     vk, r=h // g, blk=block, causal=True,
                                     interpret=ip)
         return _from_kernel(out, b)
-    lln = lln_bidir_pallas(qs, ks, vk, r=h // g, blk=block, interpret=ip)
-    diag = block_diag_pallas(_to_kernel(q), _to_kernel(k), vk, r=h // g,
-                             blk=block, causal=False, interpret=ip)
+    with jax.named_scope("lln_bidir"):
+        lln = lln_bidir_pallas(qs, ks, vk, r=h // g, blk=block,
+                               interpret=ip)
+    with jax.named_scope("block_diag"):
+        diag = block_diag_pallas(_to_kernel(q), _to_kernel(k), vk, r=h // g,
+                                 blk=block, causal=False, interpret=ip)
     out = 0.5 * (lln.astype(jnp.float32) + diag.astype(jnp.float32))
     return _from_kernel(out, b).astype(v.dtype)
 
@@ -1148,10 +1169,12 @@ def _lln_diag_vjp_fwd(q, k, v, alpha, beta, causal, block, interpret,
         res = {"pallas_fused": (qs, ks, qk, kk, vk, out_k, den,
                                 alpha_b, beta_b) + tags}
         return _from_kernel(out_k, b), res
-    lln_k, s, z, den = lln_bidir_pallas(qs, ks, vk, r=h // g, blk=block,
-                                        interpret=ip, return_res=True)
-    diag_k = block_diag_pallas(qk, kk, vk, r=h // g, blk=block, causal=False,
-                               interpret=ip)
+    with jax.named_scope("lln_bidir"):
+        lln_k, s, z, den = lln_bidir_pallas(qs, ks, vk, r=h // g, blk=block,
+                                            interpret=ip, return_res=True)
+    with jax.named_scope("block_diag"):
+        diag_k = block_diag_pallas(qk, kk, vk, r=h // g, blk=block,
+                                   causal=False, interpret=ip)
     out = 0.5 * (lln_k.astype(jnp.float32) + diag_k.astype(jnp.float32))
     res = {"pallas_bidir": (qs, ks, qk, kk, vk, lln_k, den, s, z,
                             alpha_b, beta_b) + tags}
@@ -1186,18 +1209,23 @@ def _lln_diag_vjp_bwd(causal, block, interpret, pallas_bwd, backend, res,
          tq, tk, tv, alpha0, beta0) = res["pallas_bidir"]
         r = (qs.shape[0] // b) // (ks.shape[0] // b)
         gh = 0.5 * gk.astype(jnp.float32)
-        if _kernel_bwd(interpret):
-            dqs, dks, dvl = lln_bidir_bwd_pallas(qs, ks, vk, gh, lln_k, den,
-                                                 s, z, r=r, blk=block,
-                                                 interpret=ip)
-            dqd, dkd, dvd = block_diag_bwd_pallas(qk, kk, vk, gh, r=r,
-                                                  blk=block, causal=False,
-                                                  interpret=ip)
-        else:
-            dqs, dks, dvl = lln_bidir_bwd_scan(qs, ks, vk, gh, lln_k, den,
-                                               s, z, r=r, blk=block)
-            dqd, dkd, dvd = block_diag_bwd_scan(qk, kk, vk, gh, r=r,
-                                                blk=block, causal=False)
+        kernel = _kernel_bwd(interpret)
+        with jax.named_scope("lln_bidir_bwd"):
+            if kernel:
+                dqs, dks, dvl = lln_bidir_bwd_pallas(
+                    qs, ks, vk, gh, lln_k, den, s, z, r=r, blk=block,
+                    interpret=ip)
+            else:
+                dqs, dks, dvl = lln_bidir_bwd_scan(qs, ks, vk, gh, lln_k,
+                                                   den, s, z, r=r, blk=block)
+        with jax.named_scope("block_diag"):
+            if kernel:
+                dqd, dkd, dvd = block_diag_bwd_pallas(
+                    qk, kk, vk, gh, r=r, blk=block, causal=False,
+                    interpret=ip)
+            else:
+                dqd, dkd, dvd = block_diag_bwd_scan(qk, kk, vk, gh, r=r,
+                                                    blk=block, causal=False)
         dvk = dvl + dvd
     dq = (_from_kernel(dqs, b) * _row_head_bcast(alpha_b)
           + _from_kernel(dqd, b)).astype(tq.dtype)

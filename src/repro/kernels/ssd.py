@@ -71,6 +71,7 @@ def ssd_pallas(log_a: jnp.ndarray, xbar: jnp.ndarray, b_in: jnp.ndarray,
     nb = n // blk
     return pl.pallas_call(
         functools.partial(_ssd_kernel, blk=blk),
+        name="ssd_scan",
         grid=(bh, nb),
         in_specs=[
             pl.BlockSpec((1, 1, blk), lambda h, j: (h, 0, j)),

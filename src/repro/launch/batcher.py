@@ -73,6 +73,30 @@ diagram; ``tests/test_robustness.py`` proves each path end to end):
 * **straggler watchdog** — each segment's wall clock feeds a
   ``distributed/straggler.py:StepWatchdog`` EWMA; anomalies surface as
   ``StragglerReport`` entries in the final stats.
+
+Tracing: the loop opens ``jax.profiler.TraceAnnotation`` host spans at
+segment and admission boundaries only (never per row or per token), so a
+profiler trace puts each host gap between device programs on the device's
+clock.  A span costs about a microsecond when no profiler runs.
+
+* ``batcher.admit`` — :meth:`ContinuousBatcher._admit_all`; inside it
+  ``batcher.prefill`` (args ``prompt_len``, ``rows``: the prefill
+  dispatch), ``batcher.first_token`` (the wait for the first tokens),
+  ``batcher.scatter`` (``admit_fn`` and the ``tok``/``pos``/``remaining``/
+  ``active`` updates) and ``batcher.resume`` (a quarantined row rebuilt);
+* ``batcher.segment`` (args ``segment``, ``active_rows``): the key split
+  and the ``segment_fn`` dispatch;
+* ``batcher.sync``: the host reads of the segment's tokens and flags;
+* ``batcher.harvest`` (args ``emitted``: row-steps emitted, ``slot_steps``:
+  slots x steps): outputs, quarantine and finishes, eviction excluded;
+* ``batcher.evict``: :meth:`ContinuousBatcher._free_rows`;
+* ``batcher.deadlines``: the deadline sweep;
+* ``batcher.snapshot``: a pool snapshot.
+
+``BatchingStats.times`` stamps each accepted request on
+``time.perf_counter()``: ``enqueued``, ``admitted`` (its prefill
+dispatched), ``first_token`` (its first token on the host) and
+``finished`` (its terminal status set).
 """
 from __future__ import annotations
 
@@ -85,6 +109,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.checkpoint.checkpointer import restore as _restore_tree
 from repro.distributed.straggler import StepWatchdog
@@ -144,7 +169,9 @@ class BatchingStats:
     LAST segment's streaming-concentration summary over live rows
     (``conc_drift_max``/``log_mass_mean``/``log_mass_var_mean``/
     ``tau_hat_mean``) — empty for softmax pools or ``telemetry=False``
-    setups."""
+    setups.  ``times`` maps each accepted rid to its ``time.perf_counter()``
+    stamps: ``enqueued``, ``admitted``, ``first_token``, ``finished`` (a
+    stamp is absent where the request never reached that point)."""
     outputs: dict
     completed_tokens: int
     decode_steps: int
@@ -164,6 +191,7 @@ class BatchingStats:
     snapshots: int = 0
     restored_step: Optional[int] = None
     telemetry: dict = dataclasses.field(default_factory=dict)
+    times: dict = dataclasses.field(default_factory=dict)
     # Speculative pools (``PoolSetup.spec_k >= 1``): acceptance-aware
     # goodput.  ``verify_iters`` counts draft+verify iterations that
     # emitted anything; ``drafted_tokens`` = spec_k * verify_iters;
@@ -260,6 +288,7 @@ class ContinuousBatcher:
         self.max_retries = max_retries
         self.snapshot_mgr = snapshot_mgr
         self.snapshot_every = snapshot_every
+        self._times: dict = {}          # BatchingStats.times of the run
         # Grouped admission (one batched prefill for several same-length
         # queued prompts) is exact whenever prefill is per-row
         # independent: softmax has no calibration, fixed alpha/beta skips
@@ -346,57 +375,72 @@ class ContinuousBatcher:
         st.tracked[req.rid] = tr
         st.outputs[req.rid] = []
         st.queue.append(tr)
+        self._stamp(req.rid, "enqueued")
         return True
+
+    def _stamp(self, rid: int, event: str) -> None:
+        """Stamp ``event`` of request ``rid`` once: a row rebuilt after
+        quarantine keeps its first admission and first token."""
+        self._times.setdefault(rid, {}).setdefault(event,
+                                                    time.perf_counter())
 
     # ------------------------------------------------------------------
     # Admission (fresh groups + quarantine-recovery resumes).
     # ------------------------------------------------------------------
 
     def _admit_all(self, st: _RunState) -> None:
-        s = self.setup
-        free = list(np.nonzero(st.slot_rid < 0)[0])
-        while free:
-            idx = next((i for i, tr in enumerate(st.queue)
-                        if tr.eligible_seg <= st.segments), None)
-            if idx is None:
-                break
-            tr = st.queue[idx]
-            del st.queue[idx]
-            if st.outputs[tr.req.rid]:
-                # Re-queued by quarantine recovery: the request already
-                # holds committed tokens — rebuild its row mid-stream.
-                self._admit_resume(st, tr, int(free.pop(0)))
-                continue
-            group = [tr]
-            plen = tr.req.prompt.shape[0]
-            # Group only CONSECUTIVE eligible fresh same-length prompts
-            # (keeps admission order close to FCFS).
-            while (self.group_admits and idx < len(st.queue)
-                   and len(group) < len(free)):
-                nxt = st.queue[idx]
-                if (nxt.eligible_seg > st.segments
-                        or st.outputs[nxt.req.rid]
-                        or nxt.req.prompt.shape[0] != plen):
+        with TraceAnnotation("batcher.admit"):
+            free = list(np.nonzero(st.slot_rid < 0)[0])
+            while free:
+                idx = next((i for i, tr in enumerate(st.queue)
+                            if tr.eligible_seg <= st.segments), None)
+                if idx is None:
                     break
-                group.append(nxt)
+                tr = st.queue[idx]
                 del st.queue[idx]
-            self._admit_group(st, group, free)
+                if st.outputs[tr.req.rid]:
+                    # Re-queued by quarantine recovery: the request already
+                    # holds committed tokens — rebuild its row mid-stream.
+                    with TraceAnnotation("batcher.resume"):
+                        self._admit_resume(st, tr, int(free.pop(0)))
+                    continue
+                group = [tr]
+                plen = tr.req.prompt.shape[0]
+                # Group only CONSECUTIVE eligible fresh same-length prompts
+                # (keeps admission order close to FCFS).
+                while (self.group_admits and idx < len(st.queue)
+                       and len(group) < len(free)):
+                    nxt = st.queue[idx]
+                    if (nxt.eligible_seg > st.segments
+                            or st.outputs[nxt.req.rid]
+                            or nxt.req.prompt.shape[0] != plen):
+                        break
+                    group.append(nxt)
+                    del st.queue[idx]
+                self._admit_group(st, group, free)
 
     def _admit_group(self, st: _RunState, group: list, free: list) -> None:
         s = self.setup
         plen = group[0].req.prompt.shape[0]
-        pf = s.prefill_fn(plen, len(group))
-        prompts = jnp.asarray(np.stack([t.req.prompt for t in group]))
-        logits, slot_caches = pf(self.params, prompts)
-        last = logits[:, -1] if logits.ndim == 3 else logits
-        tok0 = np.asarray(jnp.argmax(last, -1).astype(jnp.int32))
+        for tr in group:
+            self._stamp(tr.req.rid, "admitted")
+        with TraceAnnotation("batcher.prefill", prompt_len=int(plen),
+                             rows=len(group)):
+            pf = s.prefill_fn(plen, len(group))
+            prompts = jnp.asarray(np.stack([t.req.prompt for t in group]))
+            logits, slot_caches = pf(self.params, prompts)
+        with TraceAnnotation("batcher.first_token"):
+            last = logits[:, -1] if logits.ndim == 3 else logits
+            tok0 = np.asarray(jnp.argmax(last, -1).astype(jnp.int32))
         live, live_slots, live_rem = [], [], []
         for j, tr in enumerate(group):
             rid = tr.req.rid
             st.outputs[rid].append(int(tok0[j]))
             st.admitted += 1
+            self._stamp(rid, "first_token")
             if tr.req.budget <= 1:          # done at prefill; slot free
                 st.statuses[rid] = "done"
+                self._stamp(rid, "finished")
                 del st.tracked[rid]
                 continue
             slot = int(free.pop(0))
@@ -406,22 +450,23 @@ class ContinuousBatcher:
             st.slot_rid[slot] = rid
         if not live:
             return
-        if len(live) != len(group):          # drop prefill-only rows
-            sel = jnp.asarray(live)
-            # Leaves whose rank matches the pooled leaf carry the
-            # admit-group axis at position 1; lower-rank leaves
-            # (len/pos/alpha/beta) are shared across the group.
-            slot_caches = jax.tree_util.tree_map(
-                lambda sl, pl: sl[:, sel] if sl.ndim == pl.ndim
-                else sl, slot_caches, st.caches)
-        slots_dev = jnp.asarray(live_slots, jnp.int32)
-        st.caches = s.admit_fn(st.caches, slot_caches, slots_dev)
-        st.tok = st.tok.at[slots_dev].set(jnp.asarray(tok0[live]))
-        st.pos = st.pos.at[slots_dev].set(
-            jnp.full((len(live),), plen, jnp.int32))
-        st.remaining = st.remaining.at[slots_dev].set(
-            jnp.asarray(live_rem, jnp.int32))
-        st.active = st.active.at[slots_dev].set(True)
+        with TraceAnnotation("batcher.scatter"):
+            if len(live) != len(group):          # drop prefill-only rows
+                sel = jnp.asarray(live)
+                # Leaves whose rank matches the pooled leaf carry the
+                # admit-group axis at position 1; lower-rank leaves
+                # (len/pos/alpha/beta) are shared across the group.
+                slot_caches = jax.tree_util.tree_map(
+                    lambda sl, pl: sl[:, sel] if sl.ndim == pl.ndim
+                    else sl, slot_caches, st.caches)
+            slots_dev = jnp.asarray(live_slots, jnp.int32)
+            st.caches = s.admit_fn(st.caches, slot_caches, slots_dev)
+            st.tok = st.tok.at[slots_dev].set(jnp.asarray(tok0[live]))
+            st.pos = st.pos.at[slots_dev].set(
+                jnp.full((len(live),), plen, jnp.int32))
+            st.remaining = st.remaining.at[slots_dev].set(
+                jnp.asarray(live_rem, jnp.int32))
+            st.active = st.active.at[slots_dev].set(True)
 
     def _admit_resume(self, st: _RunState, tr: _Tracked, slot: int) -> None:
         """Rebuild a quarantined request's row from its committed tokens:
@@ -469,13 +514,14 @@ class ContinuousBatcher:
         s = self.setup
         if not rows:
             return
-        sel = jnp.asarray(rows, jnp.int32)
-        st.active = st.active.at[sel].set(False)
-        st.remaining = st.remaining.at[sel].set(0)
-        if s.evict_fn is not None:
-            mask = np.zeros((s.slots,), np.bool_)
-            mask[rows] = True
-            st.caches = s.evict_fn(st.caches, jnp.asarray(mask))
+        with TraceAnnotation("batcher.evict"):
+            sel = jnp.asarray(rows, jnp.int32)
+            st.active = st.active.at[sel].set(False)
+            st.remaining = st.remaining.at[sel].set(0)
+            if s.evict_fn is not None:
+                mask = np.zeros((s.slots,), np.bool_)
+                mask[rows] = True
+                st.caches = s.evict_fn(st.caches, jnp.asarray(mask))
 
     def _quarantine(self, st: _RunState, idx: int) -> None:
         """Sentinel fired on row ``idx``: discard the segment's tokens
@@ -492,6 +538,7 @@ class ContinuousBatcher:
         tr.retries += 1
         if tr.retries > self.max_retries:
             st.statuses[rid] = "failed"
+            self._stamp(rid, "finished")
             st.reject_reasons[rid] = (
                 f"unhealthy state; {self.max_retries} retries exhausted")
             del st.tracked[rid]
@@ -510,28 +557,34 @@ class ContinuousBatcher:
         ``check_request`` reserved) but never surface in ``outputs``."""
         s = self.setup
         freed: list = []
-        for idx in range(s.slots):
-            if unhealthy_h[idx]:
-                self._quarantine(st, idx)
-                freed.append(idx)
-                continue
-            rid = int(st.slot_rid[idx])
-            if rid < 0:
-                continue
-            tr = st.tracked[rid]
-            out = st.outputs[rid]
-            room = tr.req.budget - len(out)   # hard buffer bound
-            for step in np.nonzero(emitted_h[:, idx])[0]:
-                if room <= 0:
-                    break
-                take = toks_h[step, idx, :int(emitted_h[step, idx])][:room]
-                out.extend(int(t) for t in take)
-                room -= len(take)
-            if not active_h[idx]:             # evict: budget exhausted
-                st.statuses[rid] = "retried" if tr.retries else "done"
-                st.slot_rid[idx] = -1
-                del st.tracked[rid]
-                freed.append(idx)
+        with TraceAnnotation("batcher.harvest",
+                             emitted=int(emitted_h.sum()),
+                             slot_steps=int(emitted_h.shape[0]
+                                            * emitted_h.shape[1])):
+            for idx in range(s.slots):
+                if unhealthy_h[idx]:
+                    self._quarantine(st, idx)
+                    freed.append(idx)
+                    continue
+                rid = int(st.slot_rid[idx])
+                if rid < 0:
+                    continue
+                tr = st.tracked[rid]
+                out = st.outputs[rid]
+                room = tr.req.budget - len(out)   # hard buffer bound
+                for step in np.nonzero(emitted_h[:, idx])[0]:
+                    if room <= 0:
+                        break
+                    take = toks_h[step, idx,
+                                  :int(emitted_h[step, idx])][:room]
+                    out.extend(int(t) for t in take)
+                    room -= len(take)
+                if not active_h[idx]:             # evict: budget exhausted
+                    st.statuses[rid] = "retried" if tr.retries else "done"
+                    self._stamp(rid, "finished")
+                    st.slot_rid[idx] = -1
+                    del st.tracked[rid]
+                    freed.append(idx)
         self._free_rows(st, freed)
 
     def _sweep_deadlines(self, st: _RunState) -> None:
@@ -544,6 +597,7 @@ class ContinuousBatcher:
             tr = st.tracked[rid]
             if tr.deadline_at is not None and now >= tr.deadline_at:
                 st.statuses[rid] = "timeout"   # partial output kept
+                self._stamp(rid, "finished")
                 st.slot_rid[idx] = -1
                 del st.tracked[rid]
                 expired_rows.append(idx)
@@ -553,6 +607,7 @@ class ContinuousBatcher:
                    and now >= t.deadline_at]:
             st.queue.remove(tr)
             st.statuses[tr.req.rid] = "timeout"
+            self._stamp(tr.req.rid, "finished")
             del st.tracked[tr.req.rid]
 
     def _drop(self, st: _RunState, rid: int) -> None:
@@ -564,6 +619,7 @@ class ContinuousBatcher:
                 st.queue.remove(tr)
             st.statuses[rid] = "failed"
             st.reject_reasons[rid] = "dropped by client"
+            self._stamp(rid, "finished")
             del st.tracked[rid]
         rows = [i for i in range(self.setup.slots)
                 if int(st.slot_rid[i]) == rid]
@@ -741,6 +797,7 @@ class ContinuousBatcher:
         ``requests`` on top (pass ``[]`` to just drain)."""
         s = self.setup
         st = _RunState()
+        self._times = {}
         if resume:
             self._restore(st)
         else:
@@ -758,6 +815,7 @@ class ContinuousBatcher:
 
         wd = StepWatchdog()
         fired: set = set()
+        last_metrics = None     # device telemetry of the last live segment
         t0 = time.perf_counter()
         while st.queue or (st.slot_rid >= 0).any():
             # Kills/drops fire at the boundary, before admission — a
@@ -775,51 +833,59 @@ class ContinuousBatcher:
             # --- one scanned decode segment -----------------------------
             wd.start()
             self._fire_faults(st, fault_plan, fired, ("delay", "nan"))
-            st.key, seg_key = jax.random.split(st.key)
-            (st.caches, st.tok, st.pos, st.remaining, st.active,
-             toks, emitted, unhealthy, metrics) = s.segment_fn(
-                self.params, st.caches, st.tok, st.pos, st.remaining,
-                st.active, seg_key)
+            with TraceAnnotation("batcher.segment", segment=st.segments,
+                                 active_rows=int((st.slot_rid >= 0).sum())):
+                st.key, seg_key = jax.random.split(st.key)
+                (st.caches, st.tok, st.pos, st.remaining, st.active,
+                 toks, emitted, unhealthy, metrics) = s.segment_fn(
+                    self.params, st.caches, st.tok, st.pos, st.remaining,
+                    st.active, seg_key)
             # Host syncs land inside the watchdog window so the EWMA sees
             # the real segment wall clock, not async-dispatch latency.
             # Normalize the two segment shapes to one panel: plain pools
             # emit (S, B) tokens with bool masks -> (S, B, 1) + {0, 1}
             # counts; speculative pools emit (S, B, k+1) + int counts.
-            toks_h = np.asarray(toks)
-            emitted_h = np.asarray(emitted).astype(np.int64)
-            if toks_h.ndim == 2:
-                toks_h = toks_h[..., None]
-            active_h = np.asarray(st.active)
-            unhealthy_h = np.asarray(unhealthy)
-            wd.stop(st.segments)
-            st.segments += 1
-            st.decode_steps += s.segment
-            st.emitted_tokens += int(emitted_h.sum())
-            spec_k = getattr(s, "spec_k", 0)
-            if spec_k:
-                iters = int((emitted_h > 0).sum())
-                st.verify_iters += iters
-                st.drafted_tokens += spec_k * iters
-                st.accepted_tokens += int(
-                    np.maximum(emitted_h - 1, 0).sum())
-            live = emitted_h.any(axis=0)          # rows that decoded here
-            if metrics is not None and live.any():
-                m = {k: np.asarray(v) for k, v in metrics.items()}
-                st.telemetry = {
-                    "conc_drift_max": float(
-                        np.max(np.abs(m["conc_drift"][live]))),
-                    "log_mass_mean": float(np.mean(m["log_mass"][live])),
-                    "log_mass_var_mean": float(
-                        np.mean(m["log_mass_var"][live])),
-                    "tau_hat_mean": float(np.mean(m["tau_hat"][live]))}
+            with TraceAnnotation("batcher.sync"):
+                toks_h = np.asarray(toks)
+                emitted_h = np.asarray(emitted).astype(np.int64)
+                if toks_h.ndim == 2:
+                    toks_h = toks_h[..., None]
+                active_h = np.asarray(st.active)
+                unhealthy_h = np.asarray(unhealthy)
+                wd.stop(st.segments)
+                st.segments += 1
+                st.decode_steps += s.segment
+                st.emitted_tokens += int(emitted_h.sum())
+                spec_k = getattr(s, "spec_k", 0)
+                if spec_k:
+                    iters = int((emitted_h > 0).sum())
+                    st.verify_iters += iters
+                    st.drafted_tokens += spec_k * iters
+                    st.accepted_tokens += int(
+                        np.maximum(emitted_h - 1, 0).sum())
+                live = emitted_h.any(axis=0)      # rows that decoded here
+                if metrics is not None and live.any():
+                    last_metrics = (metrics, live)
 
             # --- harvest / quarantine / deadlines / snapshot ------------
             self._harvest(st, toks_h, emitted_h, active_h, unhealthy_h)
-            self._sweep_deadlines(st)
+            with TraceAnnotation("batcher.deadlines"):
+                self._sweep_deadlines(st)
             if (self.snapshot_mgr is not None and self.snapshot_every
                     and st.segments % self.snapshot_every == 0):
-                self._snapshot(st)
+                with TraceAnnotation("batcher.snapshot"):
+                    self._snapshot(st)
         wall = time.perf_counter() - t0
+        if last_metrics is not None:
+            # Only the last live segment's telemetry is reported, so it is
+            # fetched once, after the loop, not after every segment.
+            metrics, live = last_metrics
+            m = {k: np.asarray(v)[live] for k, v in metrics.items()}
+            st.telemetry = {
+                "conc_drift_max": float(np.max(np.abs(m["conc_drift"]))),
+                "log_mass_mean": float(np.mean(m["log_mass"])),
+                "log_mass_var_mean": float(np.mean(m["log_mass_var"])),
+                "tau_hat_mean": float(np.mean(m["tau_hat"]))}
 
         outputs = {rid: np.asarray(t, np.int32)
                    for rid, t in st.outputs.items()}
@@ -841,6 +907,7 @@ class ContinuousBatcher:
             segment_ewma_s=wd.ewma or 0.0,
             snapshots=st.snapshots, restored_step=st.restored_step,
             telemetry=dict(st.telemetry),
+            times=self._times,
             spec_k=getattr(s, "spec_k", 0),
             drafted_tokens=st.drafted_tokens,
             accepted_tokens=st.accepted_tokens,

@@ -22,7 +22,8 @@ freed slots mid-stream (per-row positions, masked rows), e.g.
   PYTHONPATH=src python -m repro.launch.serve --arch yi-9b --smoke \
       --continuous --requests 16 --batch 4 --gen-lens 4,4,4,24
 
-and reports goodput (completed tok/s) instead of lockstep tok/s.
+and reports goodput (completed tok/s) instead of lockstep tok/s, plus
+queue wait and time to first token (p50/p90, from ``BatchingStats.times``).
 ``--continuous --speculative`` makes the pool rows speculative (pooled
 draft+verify with per-row ``commit_len`` and single-pass verify;
 docs/serving.md "Speculative continuous batching") and adds
@@ -350,6 +351,16 @@ def _run_continuous(cfg, model, mesh, args):
           f"segment EWMA {stats.segment_ewma_s * 1e3:.1f}ms"
           + (f" (restored from step {stats.restored_step})"
              if stats.restored_step is not None else ""))
+    waits = [t["admitted"] - t["enqueued"] for t in stats.times.values()
+             if "admitted" in t and "enqueued" in t]
+    ttft = [t["first_token"] - t["enqueued"] for t in stats.times.values()
+            if "first_token" in t and "enqueued" in t]
+    if waits and ttft:
+        ms = lambda xs, q: 1e3 * float(np.percentile(xs, q))
+        print(f"  queue wait p50/p90 {ms(waits, 50):.1f}/"
+              f"{ms(waits, 90):.1f}ms, time to first token p50/p90 "
+              f"{ms(ttft, 50):.1f}/{ms(ttft, 90):.1f}ms "
+              f"({len(ttft)} requests)")
     if stats.telemetry:
         t = stats.telemetry
         print(f"  concentration: drift_max {t['conc_drift_max']:.2f}, "

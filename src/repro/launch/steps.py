@@ -195,11 +195,12 @@ def make_train_setup(cfg: ArchConfig, shape: ShapeSpec, mesh, *,
                     if p.dtype == jnp.float32 and p.ndim >= 2 else p,
                     params_c)
             loss, grads = compute_grads(params_c, batch)
-            lr = warmup_cosine(state["opt"]["step"], peak_lr=peak_lr,
-                               warmup_steps=min(500, total_steps // 10),
-                               total_steps=total_steps)
-            params, opt, metrics = adamw_update(grads, state["opt"],
-                                                state["params"], lr, opt_cfg)
+            with jax.named_scope("optimizer"):
+                lr = warmup_cosine(state["opt"]["step"], peak_lr=peak_lr,
+                                   warmup_steps=min(500, total_steps // 10),
+                                   total_steps=total_steps)
+                params, opt, metrics = adamw_update(
+                    grads, state["opt"], state["params"], lr, opt_cfg)
         return ({"params": params, "opt": opt},
                 {"loss": loss, "lr": lr, **metrics})
 
@@ -779,9 +780,10 @@ def make_pool_setup(cfg: ArchConfig, mesh, params_struct=None, *,
             # Masked rows' logits are garbage by the decode contract (they
             # may even be NaN from a freshly evicted slot); neutralize them
             # BEFORE sampling so garbage never reaches sample_token.
-            logits = jnp.where(active[:, None], logits, 0.0)
-            nxt = sample_token(logits, temperature,
-                               jax.random.fold_in(key, i))
+            with jax.named_scope("sample"):
+                logits = jnp.where(active[:, None], logits, 0.0)
+                nxt = sample_token(logits, temperature,
+                                   jax.random.fold_in(key, i))
             tok = jnp.where(active, nxt, tok)
             emitted = active
             adv = active.astype(jnp.int32)
@@ -795,7 +797,8 @@ def make_pool_setup(cfg: ArchConfig, mesh, params_struct=None, *,
                 body, (caches, tok, pos, remaining, active),
                 jnp.arange(segment, dtype=jnp.int32))
         caches, tok, pos, remaining, active = carry
-        unhealthy, metrics = _sentinel(caches, active)
+        with jax.named_scope("sentinel"):
+            unhealthy, metrics = _sentinel(caches, active)
         return (caches, tok, pos, remaining, active, toks, emitted,
                 unhealthy, metrics)
 
@@ -862,7 +865,8 @@ def make_pool_setup(cfg: ArchConfig, mesh, params_struct=None, *,
                 body, (caches, tok, pos, remaining, active),
                 jnp.arange(segment, dtype=jnp.int32))
         caches, tok, pos, remaining, active = carry
-        unhealthy, metrics = _sentinel(caches["target"], active)
+        with jax.named_scope("sentinel"):
+            unhealthy, metrics = _sentinel(caches["target"], active)
         return (caches, tok, pos, remaining, active, toks, emitted,
                 unhealthy, metrics)
 

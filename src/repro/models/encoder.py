@@ -35,24 +35,30 @@ def encoder_init(key, cfg):
 
 
 def encoder_hidden(p, tokens, cfg):
-    x = embed_lookup(p["embed"], tokens, cfg.cdtype, cfg.embed_scale)
+    with jax.named_scope("embed"):
+        x = embed_lookup(p["embed"], tokens, cfg.cdtype, cfg.embed_scale)
     positions = jnp.arange(tokens.shape[1])
 
     def body(x, lp):
-        h = apply_norm(lp["ln1"], x, cfg.norm)
-        x = x + attn_apply(lp["attn"], h, cfg, positions,
-                           causal=False).astype(x.dtype)
-        h = apply_norm(lp["ln2"], x, cfg.norm)
-        x = x + apply_mlp(lp["mlp"], h, cfg.act, cfg.cdtype).astype(x.dtype)
+        with jax.named_scope("attn"):
+            h = apply_norm(lp["ln1"], x, cfg.norm)
+            x = x + attn_apply(lp["attn"], h, cfg, positions,
+                               causal=False).astype(x.dtype)
+        with jax.named_scope("mlp"):
+            h = apply_norm(lp["ln2"], x, cfg.norm)
+            x = x + apply_mlp(lp["mlp"], h, cfg.act,
+                              cfg.cdtype).astype(x.dtype)
         return x, None
 
     x, _ = jax.lax.scan(_remat(body, cfg), x, p["layers"],
                         unroll=bool(cfg.scan_unroll))
-    x = apply_norm(p["final_norm"], x, cfg.norm)
+    with jax.named_scope("mlm_head"):
+        x = apply_norm(p["final_norm"], x, cfg.norm)
     return x, jnp.zeros((), jnp.float32)
 
 
 def encoder_logits(p, tokens, cfg):
     h, aux = encoder_hidden(p, tokens, cfg)
-    return logits_from_hidden(p["lm_head"], h, cfg.cdtype,
-                              cfg.logit_softcap), aux
+    with jax.named_scope("mlm_head"):
+        return logits_from_hidden(p["lm_head"], h, cfg.cdtype,
+                                  cfg.logit_softcap), aux

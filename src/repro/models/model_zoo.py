@@ -47,11 +47,11 @@ class Model:
     commit: Optional[Callable] = None
 
 
-def _xent_loss(cfg, h, head, batch):
-    loss = chunked_xent(h, head, batch["targets"], batch["mask"],
-                        vocab=cfg.vocab, dtype=cfg.cdtype,
-                        softcap=cfg.logit_softcap)
-    return loss
+def _xent_loss(cfg, h, head, batch, scope: str = "lm_head"):
+    with jax.named_scope(scope):
+        return chunked_xent(h, head, batch["targets"], batch["mask"],
+                            vocab=cfg.vocab, dtype=cfg.cdtype,
+                            softcap=cfg.logit_softcap)
 
 
 def _count(params) -> int:
@@ -140,7 +140,8 @@ def build_model(cfg: ArchConfig) -> Model:
 
         def loss(params, batch):
             h, aux = enc.encoder_hidden(params, batch["inputs"], cfg)
-            return _xent_loss(cfg, h, params["lm_head"], batch)
+            return _xent_loss(cfg, h, params["lm_head"], batch,
+                              scope="mlm_head")
 
         def hidden(params, batch):
             return enc.encoder_hidden(params, batch["inputs"], cfg)

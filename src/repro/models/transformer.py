@@ -56,39 +56,49 @@ def block_init(key, cfg, *, use_moe: bool):
 def block_apply(p, x, cfg, positions, *, use_moe: bool, causal: bool = True,
                 prefix_len: int = 0):
     x = constrain(x, "act_batch", "act_seq", "embed")
-    h = apply_norm(p["ln1"], x, cfg.norm)
-    if _use_mla(cfg):
-        attn_out = mla_mod.mla_apply(p["attn"], h, cfg, positions,
-                                     causal=causal)
-    else:
-        attn_out = attn_apply(p["attn"], h, cfg, positions, causal=causal,
-                              prefix_len=prefix_len)
-    x = x + attn_out.astype(x.dtype)
-    h = apply_norm(p["ln2"], x, cfg.norm)
-    if use_moe:
-        ffn_out, aux = moe_apply(p["moe"], h, cfg)
-    else:
-        ffn_out, aux = apply_mlp(p["mlp"], h, cfg.act, cfg.cdtype), 0.0
-    x = x + ffn_out.astype(x.dtype)
+    with jax.named_scope("attn"):
+        h = apply_norm(p["ln1"], x, cfg.norm)
+        if _use_mla(cfg):
+            attn_out = mla_mod.mla_apply(p["attn"], h, cfg, positions,
+                                         causal=causal)
+        else:
+            attn_out = attn_apply(p["attn"], h, cfg, positions,
+                                  causal=causal, prefix_len=prefix_len)
+        x = x + attn_out.astype(x.dtype)
+    with jax.named_scope("mlp"):
+        h = apply_norm(p["ln2"], x, cfg.norm)
+        if use_moe:
+            ffn_out, aux = moe_apply(p["moe"], h, cfg)
+        else:
+            ffn_out, aux = apply_mlp(p["mlp"], h, cfg.act, cfg.cdtype), 0.0
+        x = x + ffn_out.astype(x.dtype)
     return constrain(x, "act_batch", "act_seq", "embed"), jnp.asarray(
         aux, jnp.float32)
 
 
 def block_prefill(p, x, cfg, positions, *, use_moe: bool, prefix_len: int = 0,
                   max_len: int = 0):
-    h = apply_norm(p["ln1"], x, cfg.norm)
-    if _use_mla(cfg):
-        attn_out, cache = mla_mod.mla_prefill(p["attn"], h, cfg, positions,
-                                              max_len=max_len)
-    else:
-        attn_out, cache = serve_prefill(p["attn"], h, cfg, positions,
-                                        prefix_len=prefix_len,
-                                        max_len=max_len)
-    x = x + attn_out.astype(x.dtype)
-    h = apply_norm(p["ln2"], x, cfg.norm)
-    ffn_out = (moe_apply(p["moe"], h, cfg)[0] if use_moe
-               else apply_mlp(p["mlp"], h, cfg.act, cfg.cdtype))
-    return x + ffn_out.astype(x.dtype), cache
+    with jax.named_scope("attn"):
+        h = apply_norm(p["ln1"], x, cfg.norm)
+        if _use_mla(cfg):
+            attn_out, cache = mla_mod.mla_prefill(p["attn"], h, cfg,
+                                                  positions, max_len=max_len)
+        else:
+            attn_out, cache = serve_prefill(p["attn"], h, cfg, positions,
+                                            prefix_len=prefix_len,
+                                            max_len=max_len)
+        x = x + attn_out.astype(x.dtype)
+    return _block_mlp(p, x, cfg, use_moe), cache
+
+
+def _block_mlp(p, x, cfg, use_moe: bool):
+    """The block's second half, ``x + MLP(norm(x))``, without the MoE
+    auxiliary loss (the serving paths)."""
+    with jax.named_scope("mlp"):
+        h = apply_norm(p["ln2"], x, cfg.norm)
+        ffn_out = (moe_apply(p["moe"], h, cfg)[0] if use_moe
+                   else apply_mlp(p["mlp"], h, cfg.act, cfg.cdtype))
+        return x + ffn_out.astype(x.dtype)
 
 
 def block_score(p, x, cache, cfg, position, *, use_moe: bool,
@@ -96,39 +106,36 @@ def block_score(p, x, cache, cfg, position, *, use_moe: bool,
     """Speculative score pass over one block: a ``commit_len=0`` decode
     that leaves ``cache`` bitwise unchanged and returns the attention
     layer's ``{"k", "v"}`` commit residuals alongside the activations."""
-    h = apply_norm(p["ln1"], x, cfg.norm)
     if _use_mla(cfg):
         raise NotImplementedError(
             "single-pass speculative verify is not wired for MLA")
-    zeros = jnp.zeros((x.shape[0],), jnp.int32)
-    attn_out, _, resid = serve_decode(p["attn"], h, cache, cfg, position,
-                                      row_mask=row_mask, commit_len=zeros,
-                                      return_residuals=True)
-    x = x + attn_out.astype(x.dtype)
-    h = apply_norm(p["ln2"], x, cfg.norm)
-    ffn_out = (moe_apply(p["moe"], h, cfg)[0] if use_moe
-               else apply_mlp(p["mlp"], h, cfg.act, cfg.cdtype))
-    return x + ffn_out.astype(x.dtype), resid
+    with jax.named_scope("attn"):
+        h = apply_norm(p["ln1"], x, cfg.norm)
+        zeros = jnp.zeros((x.shape[0],), jnp.int32)
+        attn_out, _, resid = serve_decode(p["attn"], h, cache, cfg, position,
+                                          row_mask=row_mask,
+                                          commit_len=zeros,
+                                          return_residuals=True)
+        x = x + attn_out.astype(x.dtype)
+    return _block_mlp(p, x, cfg, use_moe), resid
 
 
 def block_decode(p, x, cache, cfg, position, *, use_moe: bool,
                  row_mask=None, commit_len=None):
-    h = apply_norm(p["ln1"], x, cfg.norm)
-    if _use_mla(cfg):
-        if row_mask is not None or commit_len is not None:
-            raise NotImplementedError(
-                "row-masked / partial-commit decode is not wired for MLA")
-        attn_out, cache = mla_mod.mla_decode(p["attn"], h, cache, cfg,
-                                             position)
-    else:
-        attn_out, cache = serve_decode(p["attn"], h, cache, cfg, position,
-                                       row_mask=row_mask,
-                                       commit_len=commit_len)
-    x = x + attn_out.astype(x.dtype)
-    h = apply_norm(p["ln2"], x, cfg.norm)
-    ffn_out = (moe_apply(p["moe"], h, cfg)[0] if use_moe
-               else apply_mlp(p["mlp"], h, cfg.act, cfg.cdtype))
-    return x + ffn_out.astype(x.dtype), cache
+    if _use_mla(cfg) and (row_mask is not None or commit_len is not None):
+        raise NotImplementedError(
+            "row-masked / partial-commit decode is not wired for MLA")
+    with jax.named_scope("attn"):
+        h = apply_norm(p["ln1"], x, cfg.norm)
+        if _use_mla(cfg):
+            attn_out, cache = mla_mod.mla_decode(p["attn"], h, cache, cfg,
+                                                 position)
+        else:
+            attn_out, cache = serve_decode(p["attn"], h, cache, cfg,
+                                           position, row_mask=row_mask,
+                                           commit_len=commit_len)
+        x = x + attn_out.astype(x.dtype)
+    return _block_mlp(p, x, cfg, use_moe), cache
 
 
 # ---------------------------------------------------------------------------
@@ -171,7 +178,8 @@ def lm_hidden(p, tokens, cfg, *, prefix_embed: Optional[jnp.ndarray] = None):
     mask over those positions.
     """
     first, n_main, is_moe = _layer_groups(cfg)
-    x = embed_lookup(p["embed"], tokens, cfg.cdtype, cfg.embed_scale)
+    with jax.named_scope("embed"):
+        x = embed_lookup(p["embed"], tokens, cfg.cdtype, cfg.embed_scale)
     prefix_len = 0
     if prefix_embed is not None:
         prefix_len = prefix_embed.shape[1]
@@ -194,14 +202,16 @@ def lm_hidden(p, tokens, cfg, *, prefix_embed: Optional[jnp.ndarray] = None):
     x, auxs = jax.lax.scan(body(is_moe), x, p["layers"],
                            unroll=bool(cfg.scan_unroll))
     aux += jnp.sum(auxs)
-    x = apply_norm(p["final_norm"], x, cfg.norm)
+    with jax.named_scope("lm_head"):
+        x = apply_norm(p["final_norm"], x, cfg.norm)
     return x, aux
 
 
 def lm_logits(p, tokens, cfg, **kw):
     h, aux = lm_hidden(p, tokens, cfg, **kw)
-    return logits_from_hidden(lm_head_of(p), h, cfg.cdtype,
-                              cfg.logit_softcap), aux
+    with jax.named_scope("lm_head"):
+        return logits_from_hidden(lm_head_of(p), h, cfg.cdtype,
+                                  cfg.logit_softcap), aux
 
 
 # ---------------------------------------------------------------------------
@@ -232,7 +242,8 @@ def lm_prefill(p, tokens, cfg, max_len: int,
                prefix_embed: Optional[jnp.ndarray] = None):
     """Prompt forward.  Returns (last-position logits, caches)."""
     first, n_main, is_moe = _layer_groups(cfg)
-    x = embed_lookup(p["embed"], tokens, cfg.cdtype, cfg.embed_scale)
+    with jax.named_scope("embed"):
+        x = embed_lookup(p["embed"], tokens, cfg.cdtype, cfg.embed_scale)
     prefix_len = 0
     if prefix_embed is not None:
         prefix_len = prefix_embed.shape[1]
@@ -255,9 +266,10 @@ def lm_prefill(p, tokens, cfg, max_len: int,
                                                  unroll=bool(cfg.scan_unroll))
     x, caches["layers"] = jax.lax.scan(mk(is_moe), x, p["layers"],
                                        unroll=bool(cfg.scan_unroll))
-    x = apply_norm(p["final_norm"], x, cfg.norm)
-    logits = logits_from_hidden(lm_head_of(p), x[:, -1:], cfg.cdtype,
-                                cfg.logit_softcap)
+    with jax.named_scope("lm_head"):
+        x = apply_norm(p["final_norm"], x, cfg.norm)
+        logits = logits_from_hidden(lm_head_of(p), x[:, -1:], cfg.cdtype,
+                                    cfg.logit_softcap)
     return logits, caches
 
 
@@ -290,7 +302,8 @@ def lm_decode(p, caches, token, cfg, position, row_mask=None,
     _count_pass(cfg)
     first, n_main, is_moe = _layer_groups(cfg)
     toks = token[:, None] if single else token
-    x = embed_lookup(p["embed"], toks, cfg.cdtype, cfg.embed_scale)
+    with jax.named_scope("embed"):
+        x = embed_lookup(p["embed"], toks, cfg.cdtype, cfg.embed_scale)
     new_caches = {}
 
     def mk(use_moe):
@@ -309,9 +322,10 @@ def lm_decode(p, caches, token, cfg, position, row_mask=None,
     x, new_caches["layers"] = jax.lax.scan(
         mk(is_moe), x, (p["layers"], caches["layers"]),
         unroll=bool(cfg.scan_unroll))
-    x = apply_norm(p["final_norm"], x, cfg.norm)
-    logits = logits_from_hidden(lm_head_of(p), x, cfg.cdtype,
-                                cfg.logit_softcap)
+    with jax.named_scope("lm_head"):
+        x = apply_norm(p["final_norm"], x, cfg.norm)
+        logits = logits_from_hidden(lm_head_of(p), x, cfg.cdtype,
+                                    cfg.logit_softcap)
     return (logits[:, 0] if single else logits), new_caches
 
 
@@ -330,7 +344,8 @@ def lm_score(p, caches, token, cfg, position, row_mask=None):
     """
     _count_pass(cfg)
     first, n_main, is_moe = _layer_groups(cfg)
-    x = embed_lookup(p["embed"], token, cfg.cdtype, cfg.embed_scale)
+    with jax.named_scope("embed"):
+        x = embed_lookup(p["embed"], token, cfg.cdtype, cfg.embed_scale)
     residuals = {}
 
     def mk(use_moe):
@@ -348,9 +363,10 @@ def lm_score(p, caches, token, cfg, position, row_mask=None):
     x, residuals["layers"] = jax.lax.scan(
         mk(is_moe), x, (p["layers"], caches["layers"]),
         unroll=bool(cfg.scan_unroll))
-    x = apply_norm(p["final_norm"], x, cfg.norm)
-    logits = logits_from_hidden(lm_head_of(p), x, cfg.cdtype,
-                                cfg.logit_softcap)
+    with jax.named_scope("lm_head"):
+        x = apply_norm(p["final_norm"], x, cfg.norm)
+        logits = logits_from_hidden(lm_head_of(p), x, cfg.cdtype,
+                                    cfg.logit_softcap)
     return logits, residuals
 
 
