@@ -521,6 +521,46 @@ def _roll_tail(state: LLNDecodeState, k_t, v_t, posb, cl, idx):
     return tail_k, tail_v
 
 
+def _committed(b, t, row_mask, commit_len):
+    """Per-row (B,) count of the chunk's T tokens that fold into the
+    state: ``commit_len`` under partial commit, T or 0 by ``row_mask``."""
+    if commit_len is not None:
+        return lln_mod.commit_lengths(commit_len, row_mask, t)
+    if row_mask is not None:
+        return t * row_mask.astype(jnp.int32)
+    return jnp.full((b,), t, jnp.int32)
+
+
+def tail_row(state, k_new, v_new, *, row_mask=None, commit_len=None):
+    """The diag-tail row a single-token decode writes, for a caller that
+    defers the write (:func:`write_tail_rows`).  k/v_new: (B,1,G,D[v]).
+
+    Returns ``{"k", "v"}`` (B, G, D[v]) in the tail dtype, ``slot`` (B,)
+    = ``pos % block`` and ``write`` (B,) bool (False where the row
+    commits nothing) — exactly what :func:`_roll_tail` writes for T = 1.
+    """
+    b, block = k_new.shape[0], state.tail_k.shape[1]
+    posb = jnp.broadcast_to(jnp.asarray(state.pos, jnp.int32), (b,))
+    return {"k": k_new[:, 0].astype(state.tail_k.dtype),
+            "v": v_new[:, 0].astype(state.tail_v.dtype),
+            "slot": posb % block,
+            "write": _committed(b, 1, row_mask, commit_len) > 0}
+
+
+@jax.named_scope("diag_tail")
+def write_tail_rows(tail, rows, slot, write):
+    """Write one row per (layer, batch row) into stacked tails, in place.
+
+    tail: (L, B, BLK, G, D); rows: (L, B, G, D); slot, write: (L, B) as
+    :func:`tail_row` gives them, stacked over layers.  One scatter; rows
+    with ``write`` False are dropped, so their tails stay bitwise equal.
+    """
+    n_layers, b, block = tail.shape[:3]
+    idx = jnp.where(write, slot, block)          # past the end: dropped
+    return tail.at[jnp.arange(n_layers)[:, None], jnp.arange(b)[None, :],
+                   idx].set(rows.astype(tail.dtype), mode="drop")
+
+
 def decode_lln_chunk(state: LLNDecodeState, q: jnp.ndarray,
                      k_new: jnp.ndarray, v_new: jnp.ndarray,
                      alpha: jnp.ndarray, beta: jnp.ndarray,
@@ -529,7 +569,8 @@ def decode_lln_chunk(state: LLNDecodeState, q: jnp.ndarray,
                      row_mask: Optional[jnp.ndarray] = None,
                      backend: Optional[str] = None,
                      commit_len: Optional[jnp.ndarray] = None,
-                     renorm: Optional[float] = None
+                     renorm: Optional[float] = None,
+                     defer_tail: bool = False
                      ) -> tuple[jnp.ndarray, LLNDecodeState]:
     """LLN(+Diag) decode of T >= 1 tokens.  q: (B,T,H,D); k/v_new: (B,T,G,D[v]).
 
@@ -560,6 +601,10 @@ def decode_lln_chunk(state: LLNDecodeState, q: jnp.ndarray,
     ``renorm``: optional drift-renormalization threshold on the carried
     ``z`` magnitude (``core.lln.decode_chunk``); semantics-preserving,
     applied uniformly by every backend.
+    ``defer_tail``: return the old tails unchanged; the caller writes the
+    step's row itself (:func:`tail_row`, :func:`write_tail_rows`).  Exact
+    for T = 1: the slot the token overwrites holds a previous-block entry
+    that the block mask already hides, so nothing here reads it.
     """
     b, t, h, d = q.shape
     if backend is None:
@@ -589,15 +634,13 @@ def decode_lln_chunk(state: LLNDecodeState, q: jnp.ndarray,
     v_t = _repeat_kv(v_new, gt) if v_new.shape[2] != gt else v_new
     pos = state.pos
     posb = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))    # (B,)
-    if commit_len is not None:
-        cl = lln_mod.commit_lengths(commit_len, row_mask, t)
-    elif row_mask is not None:
-        cl = t * row_mask.astype(jnp.int32)
-    else:
-        cl = jnp.full((b,), t, jnp.int32)
+    cl = _committed(b, t, row_mask, commit_len)
     idx = jnp.arange(block)
-    with jax.named_scope("diag_tail"):
-        tail_k, tail_v = _roll_tail(state, k_t, v_t, posb, cl, idx)
+    if defer_tail:
+        tail_k, tail_v = state.tail_k, state.tail_v
+    else:
+        with jax.named_scope("diag_tail"):
+            tail_k, tail_v = _roll_tail(state, k_t, v_t, posb, cl, idx)
     if commit_len is not None:
         new_pos = posb + cl         # always per-row under partial commit
     elif row_mask is not None:

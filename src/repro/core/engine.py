@@ -383,7 +383,8 @@ class AttentionEngine:
 
     def decode(self, state: AttentionState, q, k, v, *,
                row_mask: Optional[jnp.ndarray] = None,
-               commit_len: Optional[jnp.ndarray] = None):
+               commit_len: Optional[jnp.ndarray] = None,
+               defer_tail: bool = False):
         """Advance ``state`` over T >= 1 new tokens; returns
         ``(out (B,T,H,Dv), new state)``.
 
@@ -394,6 +395,10 @@ class AttentionEngine:
         partial-commit contract — all T positions are scored, but only
         the accepted prefix folds into the state (see :meth:`verify`).
         Under a multi-device mesh each device runs its own rows and heads.
+        ``defer_tail`` (T = 1, a state with diag tails; the stacked-layer
+        decode of ``models/transformer.py:lm_decode``): the returned state
+        keeps the old tails and a third element carries the row the step
+        writes (``core/attention.py:tail_row``) for the caller to scatter.
         """
         if self.spec.impl == "softmax":
             return self._decode(state, q, k, v, row_mask=row_mask,
@@ -401,14 +406,19 @@ class AttentionEngine:
 
         def run(state, q, k, v, row_mask, commit_len):
             out, st = self._decode(state, q, k, v, row_mask=row_mask,
-                                   commit_len=commit_len)
+                                   commit_len=commit_len,
+                                   defer_tail=defer_tail)
             return {"out": out, "state": st}
         res = shd.per_device(run, STATE_AXES.__getitem__, state=state, q=q,
                              k=k, v=v, row_mask=row_mask,
                              commit_len=commit_len)
+        if defer_tail:
+            return res["out"], res["state"], ca.tail_row(
+                state, k, v, row_mask=row_mask, commit_len=commit_len)
         return res["out"], res["state"]
 
-    def _decode(self, state, q, k, v, *, row_mask, commit_len):
+    def _decode(self, state, q, k, v, *, row_mask, commit_len,
+                defer_tail=False):
         spec = self.spec
         if spec.impl == "softmax":
             out, kv2 = ca.decode_softmax(
@@ -449,7 +459,8 @@ class AttentionEngine:
                                        impl=spec.impl, row_mask=row_mask,
                                        backend=spec.backend,
                                        commit_len=commit_len,
-                                       renorm=spec.renorm or None)
+                                       renorm=spec.renorm or None,
+                                       defer_tail=defer_tail)
         return out, state.replace(
             s=st2.lln.s, z=st2.lln.z, c_k=st2.lln.c_k,
             log_scale=st2.lln.log_scale,

@@ -141,7 +141,8 @@ def serve_prefill(p, x, cfg, positions, *, prefix_len: int = 0,
 
 
 def serve_decode(p, x, state, cfg, position, *, row_mask=None,
-                 commit_len=None, return_residuals: bool = False):
+                 commit_len=None, return_residuals: bool = False,
+                 defer_tail: bool = False):
     """Decode over T >= 1 new tokens.  x: (B, T, d).
 
     ``position``: absolute index of the first new token — a scalar (static
@@ -158,6 +159,9 @@ def serve_decode(p, x, state, cfg, position, *, row_mask=None,
     element — the layer's ``{"k", "v"}`` post-RoPE commit residuals — so a
     ``commit_len=0`` score pass can be folded later by
     :func:`serve_commit` without a second full pass.
+    ``defer_tail=True`` (T = 1, a state with diag tails) leaves the tails
+    unchanged and returns the tail row the step writes as a third element
+    (``AttentionEngine.decode``).
     """
     b, n, _ = x.shape
     hd, h, g = cfg.hd, cfg.n_heads, cfg.n_kv_heads
@@ -183,10 +187,10 @@ def serve_decode(p, x, state, cfg, position, *, row_mask=None,
                                        return_residuals=True)
         out = out.reshape(b, n, h * hd)
         return dense(p["o_w"], out, cfg.cdtype), state, resid
-    out, state = eng.decode(state, q, k, v, row_mask=row_mask,
-                            commit_len=commit_len)
+    out, *rest = eng.decode(state, q, k, v, row_mask=row_mask,
+                            commit_len=commit_len, defer_tail=defer_tail)
     out = out.reshape(b, n, h * hd)
-    return dense(p["o_w"], out, cfg.cdtype), state
+    return (dense(p["o_w"], out, cfg.cdtype), *rest)
 
 
 def serve_commit(state, residual, cfg, *, commit_len, row_mask=None):

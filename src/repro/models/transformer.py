@@ -16,6 +16,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.core.attention import write_tail_rows
 from repro.distributed.sharding import constrain
 from . import mla as mla_mod
 from .attention_block import (attn_apply, attn_init, serve_commit,
@@ -121,7 +122,9 @@ def block_score(p, x, cache, cfg, position, *, use_moe: bool,
 
 
 def block_decode(p, x, cache, cfg, position, *, use_moe: bool,
-                 row_mask=None, commit_len=None):
+                 row_mask=None, commit_len=None, defer_tail: bool = False):
+    """One layer's decode; returns ``(x, cache)``, plus the diag-tail row
+    the step writes when ``defer_tail`` (``serve_decode``)."""
     if _use_mla(cfg) and (row_mask is not None or commit_len is not None):
         raise NotImplementedError(
             "row-masked / partial-commit decode is not wired for MLA")
@@ -130,12 +133,13 @@ def block_decode(p, x, cache, cfg, position, *, use_moe: bool,
         if _use_mla(cfg):
             attn_out, cache = mla_mod.mla_decode(p["attn"], h, cache, cfg,
                                                  position)
+            row = ()
         else:
-            attn_out, cache = serve_decode(p["attn"], h, cache, cfg,
-                                           position, row_mask=row_mask,
-                                           commit_len=commit_len)
+            attn_out, cache, *row = serve_decode(
+                p["attn"], h, cache, cfg, position, row_mask=row_mask,
+                commit_len=commit_len, defer_tail=defer_tail)
         x = x + attn_out.astype(x.dtype)
-    return _block_mlp(p, x, cfg, use_moe), cache
+    return (_block_mlp(p, x, cfg, use_moe), cache, *row)
 
 
 # ---------------------------------------------------------------------------
@@ -297,31 +301,64 @@ def lm_decode(p, caches, token, cfg, position, row_mask=None,
     speculative verify pass: logits cover all T draft positions, every
     layer's cache folds only the accepted prefix (``commit_len=0`` rows
     behave like masked rows).  Returns logits (B, V) for (B,) input,
-    (B, T, V) for chunked input."""
+    (B, T, V) for chunked input.
+
+    The cache contract: the layer loop carries each stacked cache and
+    writes every layer's new state back into its own slice, in place.  A
+    single-token decode ((B,) input) of a cache with diag tails keeps the
+    tails out of that write-back: each layer hands out the one tail row
+    the step writes, and one scatter per tail leaf writes all layers'
+    rows after the loop.  So a step moves only the bytes it changes."""
     single = token.ndim == 1
     _count_pass(cfg)
     first, n_main, is_moe = _layer_groups(cfg)
     toks = token[:, None] if single else token
     with jax.named_scope("embed"):
         x = embed_lookup(p["embed"], toks, cfg.cdtype, cfg.embed_scale)
+
+    def layers(x, lp, stack, use_moe):
+        defer = (single and not _use_mla(cfg)
+                 and getattr(stack, "tail_k", None) is not None)
+        if defer:
+            tails = stack.tail_k, stack.tail_v
+            stack = stack.replace(tail_k=None, tail_v=None)
+
+        def body(carry, xs):
+            x, stack = carry
+            lp, i = xs
+            cache = jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False),
+                stack)
+            if defer:
+                cache = cache.replace(tail_k=tails[0][i], tail_v=tails[1][i])
+            x, cache, *row = block_decode(lp, x, cache, cfg, position,
+                                          use_moe=use_moe, row_mask=row_mask,
+                                          commit_len=commit_len,
+                                          defer_tail=defer)
+            if defer:
+                cache = cache.replace(tail_k=None, tail_v=None)
+            stack = jax.tree.map(
+                lambda a, c: jax.lax.dynamic_update_index_in_dim(a, c, i, 0),
+                stack, cache)
+            return (x, stack), (row[0] if defer else None)
+
+        n = jax.tree.leaves(stack)[0].shape[0]
+        (x, stack), rows = jax.lax.scan(body, (x, stack),
+                                        (lp, jnp.arange(n)),
+                                        unroll=bool(cfg.scan_unroll))
+        if defer:
+            slot, write = rows["slot"], rows["write"]
+            stack = stack.replace(
+                tail_k=write_tail_rows(tails[0], rows["k"], slot, write),
+                tail_v=write_tail_rows(tails[1], rows["v"], slot, write))
+        return x, stack
+
     new_caches = {}
-
-    def mk(use_moe):
-        def fn(x, xs):
-            lp, cache = xs
-            x, cache = block_decode(lp, x, cache, cfg, position,
-                                    use_moe=use_moe, row_mask=row_mask,
-                                    commit_len=commit_len)
-            return x, cache
-        return fn
-
     if first:
-        x, new_caches["first_layers"] = jax.lax.scan(
-            mk(False), x, (p["first_layers"], caches["first_layers"]),
-            unroll=bool(cfg.scan_unroll))
-    x, new_caches["layers"] = jax.lax.scan(
-        mk(is_moe), x, (p["layers"], caches["layers"]),
-        unroll=bool(cfg.scan_unroll))
+        x, new_caches["first_layers"] = layers(
+            x, p["first_layers"], caches["first_layers"], False)
+    x, new_caches["layers"] = layers(x, p["layers"], caches["layers"],
+                                     is_moe)
     with jax.named_scope("lm_head"):
         x = apply_norm(p["final_norm"], x, cfg.norm)
         logits = logits_from_hidden(lm_head_of(p), x, cfg.cdtype,

@@ -25,6 +25,8 @@ from repro.launch.batcher import (ContinuousBatcher, Request,
 from repro.launch.mesh import make_mesh
 from repro.launch.steps import make_pool_setup, make_serve_setup
 from repro.models import build_model
+from repro.models import transformer as tr
+from repro.models.layers import apply_norm, embed_lookup, logits_from_hidden
 
 
 def _tiny_cfg(impl, r, fixed_ab=True):
@@ -367,6 +369,159 @@ class TestPerRowPositions:
         np.testing.assert_array_equal(np.asarray(s1.tail_k),
                                       np.asarray(s2.tail_k))
         assert np.asarray(s2.pos).shape == (b,)
+
+
+def _chunk_path_decode(params, caches, tok, cfg, pos, row_mask=None):
+    """The layer loop as ``lm_decode`` ran it before the in-place carry:
+    the stacked caches go in as scan inputs, each layer's new cache comes
+    out stacked, and each token runs as a (B, 1) chunk, so every layer's
+    tails are rebuilt by ``decode_lln_chunk``'s chunk update."""
+    x = embed_lookup(params["embed"], tok[:, None], cfg.cdtype,
+                     cfg.embed_scale)
+
+    def layer(x, xs):
+        lp, cache = xs
+        return tr.block_decode(lp, x, cache, cfg, pos, use_moe=False,
+                               row_mask=row_mask)
+
+    x, stack = jax.lax.scan(layer, x, (params["layers"], caches["layers"]))
+    x = apply_norm(params["final_norm"], x, cfg.norm)
+    logits = logits_from_hidden(tr.lm_head_of(params), x, cfg.cdtype,
+                                cfg.logit_softcap)
+    return logits[:, 0], {"layers": stack}
+
+
+def _assert_trees_equal(got, want):
+    for kp, a in jax.tree_util.tree_leaves_with_path(want):
+        b = got
+        for k in kp:
+            b = b[k.key]
+        np.testing.assert_array_equal(
+            np.asarray(b), np.asarray(a),
+            err_msg=f"differs: {jax.tree_util.keystr(kp)}")
+
+
+class TestInPlaceDecodeCache:
+    """``lm_decode`` carries the stacked caches through its layer loop and
+    writes single-token diag tails one row per step after it: bitwise the
+    chunk path's update, step for step."""
+
+    @staticmethod
+    def _pool(impl, r):
+        cfg = _tiny_cfg(impl, r, fixed_ab=False)
+        setup = make_pool_setup(cfg, None, slots=4, max_len=40, segment=6,
+                                health=None, telemetry=False)
+        params = setup.model.init(jax.random.PRNGKey(3))
+        caches = setup.cache_init()
+        # diag_block 8: rows at 5 and 14 cross a block boundary inside the
+        # segment, the row at 8 starts a block, the row at 11 does not.
+        plens = (5, 8, 11, 14)
+        for slot, plen in enumerate(plens):
+            prompt = jax.random.randint(jax.random.PRNGKey(10 + slot),
+                                        (1, plen), 0, cfg.vocab, jnp.int32)
+            _, c = setup.prefill_fn(plen)(params, prompt)
+            caches = setup.admit_fn(caches, c,
+                                    jnp.asarray([slot], jnp.int32))
+        return setup, params, caches, jnp.asarray(plens, jnp.int32)
+
+    @pytest.mark.parametrize("impl,r", [("lln_diag", 1), ("lln_diag", 4),
+                                        ("lln", 1)])
+    def test_segment_matches_chunk_path(self, impl, r):
+        """A pool segment with an active row, a masked row, and rows whose
+        budget runs out mid-segment: tokens, ``emitted`` and every cache
+        leaf equal the same steps run through the chunk path, bitwise."""
+        setup, params, caches, pos = self._pool(impl, r)
+        cfg = setup.cfg
+        tok = jnp.asarray([3, 5, 7, 9], jnp.int32)
+        remaining = jnp.asarray([6, 4, 2, 6], jnp.int32)
+        active = jnp.asarray([True, False, True, True])
+
+        @jax.jit
+        def reference(caches, tok, pos, remaining, active):
+            def body(carry, i):
+                caches, tok, pos, remaining, active = carry
+                logits, caches = _chunk_path_decode(params, caches, tok,
+                                                    cfg, pos, active)
+                logits = jnp.where(active[:, None], logits, 0.0)
+                tok = jnp.where(active, jnp.argmax(logits, -1)
+                                .astype(jnp.int32), tok)
+                adv = active.astype(jnp.int32)
+                carry = (caches, tok, pos + adv, remaining - adv,
+                         active & (remaining - adv > 0))
+                return carry, (tok, active)
+            return jax.lax.scan(body, (caches, tok, pos, remaining, active),
+                                jnp.arange(setup.segment))
+
+        before = jax.tree_util.tree_map(np.asarray, caches)
+        (want_c, *want_state), (want_toks, want_emitted) = reference(
+            caches, tok, pos, remaining, active)
+        got = setup.segment_fn(params, caches, tok, pos, remaining, active,
+                               jax.random.PRNGKey(0))
+        got_c, got_state = got[0], got[1:5]
+        np.testing.assert_array_equal(np.asarray(got[5]),
+                                      np.asarray(want_toks))
+        np.testing.assert_array_equal(np.asarray(got[6]),
+                                      np.asarray(want_emitted))
+        for a, b in zip(got_state, want_state):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        _assert_trees_equal(got_c, want_c)
+        # The masked row's cache, its tails included, is bitwise unchanged.
+        for kp, leaf in jax.tree_util.tree_leaves_with_path(got_c):
+            old = before
+            for k in kp:
+                old = old[k.key]
+            np.testing.assert_array_equal(
+                np.asarray(leaf)[:, 1], old[:, 1],
+                err_msg=f"masked row mutated: {jax.tree_util.keystr(kp)}")
+
+    def test_single_token_tails_written_one_row(self):
+        """A single-token decode changes exactly one tail slot per active
+        row (``pos % diag_block``) and no slot of a masked row."""
+        setup, params, caches, pos = self._pool("lln_diag", 2)
+        mask = jnp.asarray([True, False, True, False])
+        before = jax.tree_util.tree_map(np.asarray, caches)
+        _, after = setup.model.decode(params, caches,
+                                      jnp.asarray([1, 2, 3, 4], jnp.int32),
+                                      pos, row_mask=mask)
+        block = setup.cfg.diag_block
+        for name in ("tail_k", "tail_v"):
+            old = before["layers"][name]
+            new = np.asarray(after["layers"][name])
+            changed = (old != new).any(axis=(0, 3, 4))      # (B, BLK)
+            for row in range(4):
+                want = np.zeros(block, bool)
+                if mask[row]:
+                    want[int(pos[row]) % block] = True
+                np.testing.assert_array_equal(changed[row], want,
+                                              err_msg=f"{name} row {row}")
+
+    @pytest.mark.parametrize("impl", ["lln_diag", "softmax"])
+    def test_static_generate_matches_chunk_path(self, impl):
+        """``make_generate`` (static batch, scalar position) gives the
+        tokens of the chunk path run one token at a time."""
+        cfg = _tiny_cfg(impl, 2)
+        model = build_model(cfg)
+        params = model.init(jax.random.PRNGKey(4))
+        b, plen, steps, max_len = 2, 6, 9, 24
+        mesh = make_mesh((1, 1), ("data", "model"))
+        setup = make_serve_setup(cfg, ShapeSpec("gen", max_len, b, "decode"),
+                                 mesh, multi_pod=False)
+        prompt = jax.random.randint(jax.random.PRNGKey(5), (b, plen), 0,
+                                    cfg.vocab, jnp.int32)
+        logits, caches = model.prefill(params, {"inputs": prompt}, max_len)
+        last = logits[:, -1] if logits.ndim == 3 else logits
+        tok0 = jnp.argmax(last, -1).astype(jnp.int32)
+        want, c, tok = [], caches, tok0
+        for i in range(steps):
+            lg, c = _chunk_path_decode(params, c, tok, cfg,
+                                       jnp.asarray(plen + i, jnp.int32))
+            tok = jnp.argmax(lg, -1).astype(jnp.int32)
+            want.append(np.asarray(tok))
+        got, _ = setup.make_generate(steps)(params, caches, tok0,
+                                            jnp.asarray(plen, jnp.int32),
+                                            jax.random.PRNGKey(0))
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.stack(want, axis=1))
 
 
 class TestAdmit:

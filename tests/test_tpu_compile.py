@@ -5,7 +5,8 @@ Interpret mode accepts block shapes and fast-memory use that the chip's
 compiler refuses, so the CPU parity tests cannot catch a kernel that will
 not compile on the chip.  These tests compile each kernel for one chip of a
 described (not attached) ``v5e:2x2`` topology and check that the program
-holds the Mosaic kernel (``tpu_custom_call``).  Nothing runs.
+holds the Mosaic kernel (``tpu_custom_call``).  Nothing runs.  One more
+guard compiles the pool's decode segment program and reads its loops.
 
 Shapes are the serving/training ones: 128 (batch*head) rows of 1024 tokens
 in 256-token blocks, head dims 64 (stablelm-1.6b, roberta-lln) and 128 with
@@ -15,6 +16,7 @@ GQA r = 8 (yi-9b).  Dtypes follow ``kernels/ops.py``: the pre-scaled
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -127,3 +129,63 @@ _NAMES = [c[0] for c in _cases(64, 1)]
 def test_kernel_compiles_for_v5e(one_chip, name, d, r):
     _, fn, shapes = next(c for c in _cases(d, r) if c[0] == name)
     _compile(fn, one_chip, *shapes)
+
+
+def _loop_computations(hlo: str) -> list:
+    """The instruction lines of every computation a while loop runs, with
+    the computations those call (fusions, nested loops), transitively."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{\s*$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name:
+            comps[name].append(line)
+    calls = {n: set(re.findall(r"(?:body|condition|calls|to_apply)="
+                               r"%([\w.\-]+)", "\n".join(body)))
+             for n, body in comps.items()}
+    todo = [b for body in comps.values() for line in body
+            for b in re.findall(r"body=%([\w.\-]+)", line)]
+    seen = set()
+    while todo:
+        c = todo.pop()
+        if c not in seen:
+            seen.add(c)
+            todo.extend(calls.get(c, ()))
+    return [line for c in seen for line in comps.get(c, ())]
+
+
+@pytest.mark.parametrize("impl", ["lln_diag", "lln"])
+def test_pool_segment_carries_caches_in_place(one_chip, impl):
+    """The pool's decode segment at stablelm-1.6b widths (2 layers, 16
+    slots, max_len 1024) copies neither the stacked diag tails nor the
+    stacked LLN state inside its loops: the layer loop writes each layer's
+    state into the carried stack and the tails take one row per step."""
+    from repro.configs.registry import get_config
+    from repro.launch.steps import make_pool_setup
+
+    layers, slots = 2, 16
+    cfg = get_config("stablelm-1.6b", attn_impl=impl, n_layers=layers)
+    setup = make_pool_setup(cfg, None, slots=slots, max_len=1024, segment=8)
+    on_chip = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    row = lambda dt: jax.ShapeDtypeStruct((slots,), dt, sharding=one_chip)
+    hlo = setup.segment_fn.lower(
+        on_chip(jax.eval_shape(setup.model.init, jax.random.PRNGKey(0))),
+        on_chip(jax.eval_shape(setup.cache_init)), row(jnp.int32),
+        row(jnp.int32), row(jnp.int32), row(jnp.bool_),
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip),
+    ).compile().as_text()
+    h, d, blk = cfg.n_heads, cfg.hd, cfg.diag_block
+    stacked = (f"bf16[{layers},{slots},{blk},{h},{d}]",
+               f"f32[{layers},{slots},{h},{d},{d}]")
+    body = _loop_computations(hlo)
+    copies = [line.strip()[:160] for line in body
+              if re.search(r"= (\S+?)\{[^}]*\} copy\(", line)
+              and re.search(r"= (\S+?)\{", line).group(1) in stacked]
+    assert not copies, copies
+    assert any(" scatter(" in line for line in body)
