@@ -82,10 +82,12 @@ clock.  A span costs about a microsecond when no profiler runs.
 * ``batcher.admit`` — :meth:`ContinuousBatcher._admit_all`; inside it
   ``batcher.prefill`` (args ``prompt_len``, ``rows``: the prefill
   dispatch), ``batcher.first_token`` (the wait for the first tokens),
-  ``batcher.scatter`` (``admit_fn`` and the ``tok``/``pos``/``remaining``/
-  ``active`` updates) and ``batcher.resume`` (a quarantined row rebuilt);
-* ``batcher.segment`` (args ``segment``, ``active_rows``): the key split
-  and the ``segment_fn`` dispatch;
+  ``batcher.scatter`` (``admit_fn`` and the ``rows_fn`` update of
+  ``tok``/``pos``/``remaining``/``active``) and ``batcher.resume`` (a
+  quarantined row rebuilt);
+* ``batcher.segment`` (args ``segment``, ``active_rows``, ``devices``:
+  the devices the pool's mesh spans, 1 without one): the key split and
+  the ``segment_fn`` dispatch;
 * ``batcher.sync``: the host reads of the segment's tokens and flags;
 * ``batcher.harvest`` (args ``emitted``: row-steps emitted, ``slot_steps``:
   slots x steps): outputs, quarantine and finishes, eviction excluded;
@@ -131,6 +133,9 @@ class QueueFullError(RequestError):
 
 #: Every request ends in exactly one of these (``BatchingStats.statuses``).
 REQUEST_STATUSES = ("done", "timeout", "rejected", "failed", "retried")
+
+#: The pool's per-slot vectors, in ``PoolSetup.rows_fn``'s order.
+ROW_FIELDS = ("tok", "pos", "remaining", "active")
 
 
 @dataclasses.dataclass
@@ -430,9 +435,11 @@ class ContinuousBatcher:
             prompts = jnp.asarray(np.stack([t.req.prompt for t in group]))
             logits, slot_caches = pf(self.params, prompts)
         with TraceAnnotation("batcher.first_token"):
-            last = logits[:, -1] if logits.ndim == 3 else logits
-            tok0 = np.asarray(jnp.argmax(last, -1).astype(jnp.int32))
-        live, live_slots, live_rem = [], [], []
+            tok0 = self._first_tokens(logits)
+        # Pool row of each group row; a row done at prefill goes to the
+        # out-of-range row ``slots``, which the admission scatter drops.
+        dest = np.full((len(group),), s.slots, np.int32)
+        live = []
         for j, tr in enumerate(group):
             rid = tr.req.rid
             st.outputs[rid].append(int(tok0[j]))
@@ -443,30 +450,44 @@ class ContinuousBatcher:
                 self._stamp(rid, "finished")
                 del st.tracked[rid]
                 continue
-            slot = int(free.pop(0))
+            dest[j] = int(free.pop(0))
             live.append(j)
-            live_slots.append(slot)
-            live_rem.append(tr.req.budget - 1)
-            st.slot_rid[slot] = rid
+            st.slot_rid[dest[j]] = rid
         if not live:
             return
         with TraceAnnotation("batcher.scatter"):
-            if len(live) != len(group):          # drop prefill-only rows
-                sel = jnp.asarray(live)
-                # Leaves whose rank matches the pooled leaf carry the
-                # admit-group axis at position 1; lower-rank leaves
-                # (len/pos/alpha/beta) are shared across the group.
-                slot_caches = jax.tree_util.tree_map(
-                    lambda sl, pl: sl[:, sel] if sl.ndim == pl.ndim
-                    else sl, slot_caches, st.caches)
-            slots_dev = jnp.asarray(live_slots, jnp.int32)
-            st.caches = s.admit_fn(st.caches, slot_caches, slots_dev)
-            st.tok = st.tok.at[slots_dev].set(jnp.asarray(tok0[live]))
-            st.pos = st.pos.at[slots_dev].set(
-                jnp.full((len(live),), plen, jnp.int32))
-            st.remaining = st.remaining.at[slots_dev].set(
-                jnp.asarray(live_rem, jnp.int32))
-            st.active = st.active.at[slots_dev].set(True)
+            st.caches = s.admit_fn(st.caches, slot_caches, jnp.asarray(dest))
+            self._set_rows(st, dest[live], tok=tok0[live], pos=plen,
+                           remaining=[group[j].req.budget - 1
+                                      for j in live], active=True)
+
+    @staticmethod
+    def _first_tokens(logits) -> np.ndarray:
+        """Each group row's first token: greedy over its prefill's
+        last-position logits."""
+        last = logits[:, -1] if logits.ndim == 3 else logits
+        return np.asarray(jnp.argmax(last, -1).astype(jnp.int32))
+
+    def _set_rows(self, st: _RunState, rows, **fields) -> None:
+        """Set the per-slot vectors named in ``fields`` (``tok``, ``pos``,
+        ``remaining``, ``active``) at pool rows ``rows``, in one dispatch
+        of ``PoolSetup.rows_fn`` (fixed shapes: one program for every
+        admission and eviction)."""
+        st.tok, st.pos, st.remaining, st.active = self._rows_with(
+            st, rows, **fields)
+
+    def _rows_with(self, st: _RunState, rows, **fields) -> tuple:
+        """(tok, pos, remaining, active) as :meth:`_set_rows` leaves them,
+        without storing them."""
+        s = self.setup
+        mask = np.zeros((len(ROW_FIELDS), s.slots), np.bool_)
+        values = np.zeros((len(ROW_FIELDS), s.slots), np.int32)
+        for i, name in enumerate(ROW_FIELDS):
+            if name in fields:
+                mask[i, rows] = True
+                values[i, rows] = fields[name]
+        return s.rows_fn((st.tok, st.pos, st.remaining, st.active),
+                         jnp.asarray(mask), jnp.asarray(values))
 
     def _admit_resume(self, st: _RunState, tr: _Tracked, slot: int) -> None:
         """Rebuild a quarantined request's row from its committed tokens:
@@ -482,9 +503,9 @@ class ContinuousBatcher:
         plen = req.prompt.shape[0]
         n = len(emitted)
         pf = s.prefill_fn(plen, 1)
-        _, slot_caches = pf(self.params, jnp.asarray(req.prompt)[None, :])
-        slot_dev = jnp.asarray([slot], jnp.int32)
-        st.caches = s.admit_fn(st.caches, slot_caches, slot_dev)
+        _, slot_caches = pf(self.params, jnp.asarray(req.prompt[None, :]))
+        st.caches = s.admit_fn(st.caches, slot_caches,
+                               jnp.asarray([slot], jnp.int32))
         replay = emitted[:-1]
         r_chunk = s.replay_chunk
         for off in range(0, len(replay), r_chunk):
@@ -493,15 +514,13 @@ class ContinuousBatcher:
             chunk[slot, :len(piece)] = piece
             commit = np.zeros((s.slots,), np.int32)
             commit[slot] = len(piece)
-            pos_r = st.pos.at[slot].set(plen + off)
+            pos_r = self._rows_with(st, [slot], pos=plen + off)[1]
             st.caches = s.replay_fn(self.params, st.caches,
                                     jnp.asarray(chunk), pos_r,
                                     jnp.asarray(commit))
-        st.tok = st.tok.at[slot].set(int(emitted[-1]))
-        st.pos = st.pos.at[slot].set(plen + n - 1)
         left = req.budget - n
-        st.remaining = st.remaining.at[slot].set(left)
-        st.active = st.active.at[slot].set(left > 0)
+        self._set_rows(st, [slot], tok=int(emitted[-1]), pos=plen + n - 1,
+                       remaining=left, active=left > 0)
         st.slot_rid[slot] = req.rid
         st.recoveries += 1
 
@@ -515,9 +534,7 @@ class ContinuousBatcher:
         if not rows:
             return
         with TraceAnnotation("batcher.evict"):
-            sel = jnp.asarray(rows, jnp.int32)
-            st.active = st.active.at[sel].set(False)
-            st.remaining = st.remaining.at[sel].set(0)
+            self._set_rows(st, rows, remaining=0, active=False)
             if s.evict_fn is not None:
                 mask = np.zeros((s.slots,), np.bool_)
                 mask[rows] = True
@@ -717,10 +734,7 @@ class ContinuousBatcher:
                 f"{self.snapshot_mgr.directory}")
         s = self.setup
         template = {"caches": s.cache_init(),
-                    "tok": jnp.zeros((s.slots,), jnp.int32),
-                    "pos": jnp.zeros((s.slots,), jnp.int32),
-                    "remaining": jnp.zeros((s.slots,), jnp.int32),
-                    "active": jnp.zeros((s.slots,), jnp.bool_),
+                    **dict(zip(ROW_FIELDS, s.rows_init())),
                     "key": jax.random.PRNGKey(0)}
         tree = _restore_tree(self.snapshot_mgr.directory, step, template)
         meta = json.loads(
@@ -768,8 +782,9 @@ class ContinuousBatcher:
         pooled = s.cache_init()
         for p in plens:
             for k in sizes:       # mid-stream admits form every group size
-                _, sc = s.prefill_fn(p, k)(self.params,
-                                           jnp.zeros((k, p), jnp.int32))
+                logits, sc = s.prefill_fn(p, k)(
+                    self.params, jnp.zeros((k, p), jnp.int32))
+                self._first_tokens(logits)
                 pooled = s.admit_fn(pooled, sc,
                                     jnp.arange(k, dtype=jnp.int32))
         del pooled
@@ -802,10 +817,7 @@ class ContinuousBatcher:
             self._restore(st)
         else:
             st.caches = s.cache_init()
-            st.tok = jnp.zeros((s.slots,), jnp.int32)
-            st.pos = jnp.zeros((s.slots,), jnp.int32)
-            st.remaining = jnp.zeros((s.slots,), jnp.int32)
-            st.active = jnp.zeros((s.slots,), jnp.bool_)
+            st.tok, st.pos, st.remaining, st.active = s.rows_init()
             st.slot_rid = np.full((s.slots,), -1, np.int64)
             if key is None:   # advance so repeated runs sample fresh streams
                 self.key, key = jax.random.split(self.key)
@@ -813,6 +825,7 @@ class ContinuousBatcher:
         for r in requests:
             self._enqueue(st, r)
 
+        devices = s.mesh.size if s.mesh is not None else 1
         wd = StepWatchdog()
         fired: set = set()
         last_metrics = None     # device telemetry of the last live segment
@@ -834,7 +847,8 @@ class ContinuousBatcher:
             wd.start()
             self._fire_faults(st, fault_plan, fired, ("delay", "nan"))
             with TraceAnnotation("batcher.segment", segment=st.segments,
-                                 active_rows=int((st.slot_rid >= 0).sum())):
+                                 active_rows=int((st.slot_rid >= 0).sum()),
+                                 devices=devices):
                 st.key, seg_key = jax.random.split(st.key)
                 (st.caches, st.tok, st.pos, st.remaining, st.active,
                  toks, emitted, unhealthy, metrics) = s.segment_fn(

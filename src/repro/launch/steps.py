@@ -8,7 +8,7 @@ from __future__ import annotations
 
 import dataclasses
 import re
-from functools import partial
+from functools import lru_cache, partial
 from typing import Any, Optional
 
 import jax
@@ -566,12 +566,22 @@ class PoolSetup:
       every calibration mode, because the replayed trajectory IS the
       original decode trajectory.  Fixed ``R = replay_chunk`` keeps this
       one compile total.
+    * ``rows_init()`` / ``rows_fn(rows, mask, values)`` — the batcher's
+      per-slot vectors ``rows = (tok, pos, remaining, active)``, each
+      (slots,): all zero, and the same with row ``j`` of vector ``i``
+      replaced by ``values[i, j]`` where ``mask[i, j]`` ((4, slots) bool
+      and int32 arrays).  Fixed shapes, so admission and eviction of any
+      rows cost one compile total.
     * ``evict_fn(caches, row_mask)`` — the engine's ``evict`` lifted over
       the stacked layer tree: zeroes the masked rows ((slots,) bool, a
       fixed shape so eviction costs ONE compile total) of every cache
       leaf in one fused (donated) pass, so stale request state never
       outlives its request.  Admission overwrites a slot wholesale either
       way; eviction keeps the pool clean between the two.
+
+    Under a mesh every program takes and returns fixed shardings (see
+    ``make_pool_setup``), so no mix of admissions and evictions compiles
+    anything new once each shape has run.
     """
     cfg: Any
     model: Any
@@ -587,6 +597,8 @@ class PoolSetup:
     segment_fn: Any
     evict_fn: Any = None
     replay_fn: Any = None
+    rows_init: Any = None
+    rows_fn: Any = None
     health: Any = None
     replay_chunk: int = 8
     telemetry: bool = True
@@ -645,6 +657,17 @@ def make_pool_setup(cfg: ArchConfig, mesh, params_struct=None, *,
     own prompt statistics, (B, H) in the slot cache), which is what makes
     a batched slot prefill exact per request and lets the batcher group
     same-length admits even under dynamic moment matching.
+
+    ``mesh``: with a mesh, every program takes and returns fixed
+    shardings: the parameters by ``distributed/sharding.py:
+    param_shardings``, every cache (the pool's and a prefill group's) by
+    :func:`cache_shardings` under the serving rules, and everything else
+    (tokens, positions, budgets, masks, the key, logits, sampled tokens,
+    flags) replicated.  Host and uncommitted arrays are placed by the
+    program; an array committed to another sharding is refused (``jax.jit``
+    raises), so each program compiles once per shape and nothing is copied
+    unseen.  With ``mesh=None`` they are plain ``jax.jit`` programs on the
+    default device.
     """
     if cfg.family not in ("dense", "moe", "ssm", "hybrid") \
             or cfg.kv_lora > 0:
@@ -669,17 +692,23 @@ def make_pool_setup(cfg: ArchConfig, mesh, params_struct=None, *,
         dmodel = build_model(dcfg)
     k = spec_k
 
-    def cache_init():
-        struct = params_struct if params_struct is not None else \
-            jax.eval_shape(model.init, jax.random.PRNGKey(0))
-        tgt = model.cache_init(struct, slots, max_len, per_row=True)
+    struct = params_struct if params_struct is not None else \
+        jax.eval_shape(model.init, jax.random.PRNGKey(0))
+
+    def caches_for(rows: int):
+        """Empty caches of ``rows`` rows: the pool's, or the layout of a
+        prefill group's."""
+        tgt = model.cache_init(struct, rows, max_len, per_row=True)
         if not speculative_pool:
             return tgt
         # lm_cache_init derives the layout from cfg alone — the params
         # struct is signature-compat only, so the target's serves both.
         return {"target": tgt,
-                "draft": dmodel.cache_init(struct, slots, max_len,
+                "draft": dmodel.cache_init(struct, rows, max_len,
                                            per_row=True)}
+
+    def cache_init():
+        return caches_for(slots)
 
     def _pf(params, tokens):
         with shd.logical_rules(mesh, rules):
@@ -689,15 +718,6 @@ def make_pool_setup(cfg: ArchConfig, mesh, params_struct=None, *,
             _, dr = dmodel.prefill(draft_params(params, cfg, draft_layers),
                                    {"inputs": tokens}, max_len)
         return logits, {"target": tgt, "draft": dr}
-
-    _pf_jit = jax.jit(_pf)
-
-    def prefill_fn(plen: int, batch: int = 1):
-        # jax.jit caches executables per input shape, so one jitted object
-        # serves every (prompt length, admit-group size); the signature
-        # documents that each distinct pair costs one trace/compile.
-        del plen, batch
-        return _pf_jit
 
     def _admit(pooled, slot_caches, slot_idx):
         """Scatter a k-row slot-local cache into pool rows ``slot_idx``
@@ -713,8 +733,6 @@ def make_pool_setup(cfg: ArchConfig, mesh, params_struct=None, *,
                     sl[:, None], sl.shape[:1] + (k_rows,) + sl.shape[1:])
             return pl.at[:, slot_idx].set(sl)
         return jax.tree_util.tree_map(leaf, pooled, slot_caches)
-
-    admit_fn = jax.jit(_admit, donate_argnums=(0,))
 
     def _evict(pooled, row_mask):
         """AttentionEngine.evict lifted over the stacked layer tree: reset
@@ -734,8 +752,6 @@ def make_pool_setup(cfg: ArchConfig, mesh, params_struct=None, *,
             keep = ~row_mask.reshape((1, -1) + (1,) * (leaf.ndim - 2))
             return jnp.where(keep, leaf, fill)
         return jax.tree_util.tree_map_with_path(clear, pooled)
-
-    evict_fn = jax.jit(_evict, donate_argnums=(0,))
 
     def _sentinel(tree, active):
         """Health + telemetry on the post-segment caches, fused into the
@@ -870,9 +886,6 @@ def make_pool_setup(cfg: ArchConfig, mesh, params_struct=None, *,
         return (caches, tok, pos, remaining, active, toks, emitted,
                 unhealthy, metrics)
 
-    segment_fn = jax.jit(_segment_spec if speculative_pool else _segment,
-                         donate_argnums=(1,))
-
     def _replay(params, caches, chunk, pos, commit):
         """Advance per-row state over already-committed tokens without
         emitting: one chunked decode under the partial-commit contract
@@ -892,14 +905,66 @@ def make_pool_setup(cfg: ArchConfig, mesh, params_struct=None, *,
                                      commit_len=commit)
         return caches
 
-    replay_fn = jax.jit(_replay, donate_argnums=(1,))
+    def _set_rows(rows, mask, values):
+        """``rows`` (tok, pos, remaining, active) with row j of vector i
+        set to ``values[i, j]`` where ``mask[i, j]``."""
+        return tuple(jnp.where(m, v.astype(r.dtype), r)
+                     for r, m, v in zip(rows, mask, values))
+
+    def _zero_rows():
+        zero = jnp.zeros((slots,), jnp.int32)
+        return zero, zero, zero, jnp.zeros((slots,), jnp.bool_)
+
+    if mesh is None:
+        rep = par = pooled = None
+    else:
+        rep = NamedSharding(mesh, P())
+        par = shd.param_shardings(struct, mesh)
+        pooled = cache_shardings(jax.eval_shape(cache_init), cfg, mesh,
+                                 rules)
+        cache_init = jax.jit(cache_init, out_shardings=pooled)
+
+    def program(fn, ins, outs, **kw):
+        """``jax.jit`` of ``fn``; under a mesh with fixed shardings, so that
+        an argument placed otherwise is refused instead of copied."""
+        if mesh is None:
+            return jax.jit(fn, **kw)
+        return jax.jit(fn, in_shardings=ins, out_shardings=outs, **kw)
+
+    @lru_cache(maxsize=None)
+    def _prefill_of(rows):
+        caches = None if mesh is None else cache_shardings(
+            jax.eval_shape(lambda: caches_for(rows)), cfg, mesh, rules)
+        return program(_pf, (par, rep), (rep, caches))
+
+    def prefill_fn(plen: int, batch: int = 1):
+        # jax.jit caches executables per input shape, so one jitted object
+        # serves every prompt length (and, with no mesh, every group
+        # size); the signature documents that each distinct pair costs
+        # one trace/compile.  Under a mesh a group's caches are laid out
+        # for its rows, which a data axis may split.
+        del plen
+        return _prefill_of(None if mesh is None else batch)
+
+    # A group's caches arrive as its prefill laid them out (unspecified).
+    admit_fn = program(_admit, (pooled, None, rep), pooled,
+                       donate_argnums=(0,))
+    evict_fn = program(_evict, (pooled, rep), pooled, donate_argnums=(0,))
+    segment_fn = program(_segment_spec if speculative_pool else _segment,
+                         (par, pooled) + (rep,) * 5,
+                         (pooled,) + (rep,) * 8, donate_argnums=(1,))
+    replay_fn = program(_replay, (par, pooled, rep, rep, rep), pooled,
+                        donate_argnums=(1,))
+    rows_fn = program(_set_rows, (rep, rep, rep), rep)
+    rows_init = program(_zero_rows, (), rep)
 
     return PoolSetup(cfg=cfg, model=model, mesh=mesh, rules=rules,
                      slots=slots, max_len=max_len, segment=segment,
                      temperature=temperature, cache_init=cache_init,
                      prefill_fn=prefill_fn, admit_fn=admit_fn,
                      segment_fn=segment_fn, evict_fn=evict_fn,
-                     replay_fn=replay_fn, health=health,
+                     replay_fn=replay_fn, rows_init=rows_init,
+                     rows_fn=rows_fn, health=health,
                      replay_chunk=replay_chunk, telemetry=telemetry,
                      spec_k=spec_k, draft_layers=draft_layers,
                      draft_model=dmodel)

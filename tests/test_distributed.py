@@ -170,10 +170,13 @@ class TestElastic:
             mesh2 = make_degraded_mesh(jax.devices()[:5], prefer_model=2)
             assert mesh2.devices.size == 4, mesh2
             params2 = reshard_state(params, mesh2)
-            caches2 = reshard_state(caches, mesh2)
             with mesh2:
                 setup2 = make_pool_setup(cfg, mesh2, slots=2, max_len=32,
                                          segment=4)
+                # The pool's programs take its caches only as its
+                # cache_init lays them out.
+                caches2 = jax.device_put(caches, jax.tree_util.tree_map(
+                    lambda s: s.sharding, jax.eval_shape(setup2.cache_init)))
                 out2 = setup2.segment_fn(params2, caches2, tok, pos,
                                          remaining, active, key)
             np.testing.assert_array_equal(em_ref, np.asarray(out2[6]))
@@ -387,3 +390,82 @@ class TestHeadParallelKernels:
             print("ROWS_HEADS_OK")
         """, devices=8)
         assert "ROWS_HEADS_OK" in out
+
+
+class TestPoolOverMesh:
+    def test_pool_compiles_nothing_after_warmup_and_matches_one_device_subprocess(
+            self):
+        """A Yi-shaped pool (RMSNorm, SwiGLU, GQA r = 4, ``lln_diag``)
+        under a (1, 4) mesh: after ``warmup`` two runs of staggered
+        admissions (mixed prompt lengths, same-length groups), rows that
+        finish mid-segment or at prefill, and evictions lower no program;
+        its greedy tokens and prefill logits equal the one-device pool's,
+        and the pool's caches, parameters and per-slot vectors keep the
+        shardings its programs take."""
+        out = _run_subprocess("""
+            import jax, jax.numpy as jnp, numpy as np
+            from jax.sharding import PartitionSpec as P
+            from repro.configs.base import ArchConfig
+            from repro.launch.batcher import ContinuousBatcher, Request
+            from repro.launch.mesh import make_mesh
+            from repro.launch.steps import init_params, make_pool_setup
+
+            lowered = []
+            jax.monitoring.register_event_duration_secs_listener(
+                lambda event, duration, **kw: lowered.append(
+                    kw.get("fun_name")) if event ==
+                "/jax/core/compile/jaxpr_to_mlir_module_duration" else None)
+            cfg = ArchConfig(
+                name="yi-shaped", family="dense", n_layers=2, d_model=64,
+                n_heads=16, n_kv_heads=4, head_dim=16, d_ff=128, vocab=128,
+                norm="rmsnorm", act="silu_glu", rope_theta=5e6,
+                attn_impl="lln_diag", attn_shard="tp_heads", diag_block=8,
+                lln_chunk=8, softmax_chunk=16, compute_dtype="float32",
+                remat="none")
+            rng = np.random.default_rng(0)
+            # (length, budget); budget 1 finishes at prefill, inside a
+            # same-length group as well as alone.
+            shapes = [(16, 5), (16, 9), (16, 1), (8, 3), (8, 12), (16, 7),
+                      (16, 2), (8, 6), (8, 1), (16, 11), (8, 4), (16, 10)]
+
+            def traffic(order):
+                return [Request(rid=i, gen_len=shapes[j][1],
+                                prompt=rng.integers(0, cfg.vocab,
+                                                    size=shapes[j][0]
+                                                    ).astype(np.int32))
+                        for i, j in enumerate(order)]
+
+            runs = [traffic(range(12)), traffic(range(11, -1, -1))]
+
+            def serve(mesh):
+                setup = make_pool_setup(cfg, mesh, slots=4, max_len=32,
+                                        segment=4)
+                params = init_params(setup.model, mesh or make_mesh(
+                    (1, 1), ("data", "model")), 0)
+                eng = ContinuousBatcher(setup, params)
+                eng.warmup([8, 16])
+                lowered.clear()
+                outs = [eng.run(r, key=jax.random.PRNGKey(3)).outputs
+                        for r in runs]
+                got = list(lowered)
+                logits, _ = setup.prefill_fn(16, 2)(params, jnp.asarray(
+                    np.stack([runs[0][0].prompt, runs[0][1].prompt])))
+                caches = setup.cache_init()
+                rows = setup.rows_init()
+                return got, outs, np.asarray(logits), caches, rows, params
+
+            _, o1, l1, _, _, _ = serve(None)
+            mesh = make_mesh((1, 4), ("data", "model"))
+            n4, o4, l4, caches, rows, params = serve(mesh)
+            assert n4 == [], n4
+            for a, b in zip(o1, o4):
+                assert a.keys() == b.keys()
+                assert all(np.array_equal(a[r], b[r]) for r in a), (a, b)
+            np.testing.assert_allclose(l4, l1, rtol=1e-4, atol=1e-4)
+            s = caches["layers"].s
+            assert s.sharding.spec == P(None, "data", "model", None, None)
+            assert all(r.sharding.spec == P() for r in rows)
+            assert len(params["layers"]["attn"]["q_w"].sharding.device_set) == 4
+            print("POOL_MESH_OK")
+        """, devices=4)
+        assert "POOL_MESH_OK" in out

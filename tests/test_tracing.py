@@ -34,7 +34,7 @@ IN_ADMIT = ("batcher.prefill", "batcher.first_token", "batcher.scatter",
 TOP = ("batcher.admit", "batcher.segment", "batcher.sync",
        "batcher.harvest", "batcher.deadlines", "batcher.snapshot")
 ARGS = {"batcher.prefill": {"prompt_len", "rows"},
-        "batcher.segment": {"segment", "active_rows"},
+        "batcher.segment": {"segment", "active_rows", "devices"},
         "batcher.harvest": {"emitted", "slot_steps"}}
 
 SEGMENT_SCOPES = {"embed", "attn", "mlp", "lm_head", "sample", "sentinel",
@@ -202,3 +202,104 @@ def _lowered(which: str, pool) -> str:
                                         ("train", TRAIN_SCOPES)])
 def test_programs_carry_their_scopes(pool, which, want):
     assert want <= _scopes(_lowered(which, pool))
+
+
+MESH_TRACE = """
+    import glob, json, re
+    import jax
+    from jax.profiler import ProfileData
+    from repro.configs.base import ArchConfig
+    from repro.launch.batcher import ContinuousBatcher, synthetic_traffic
+    from repro.launch.mesh import make_mesh
+    from repro.launch.steps import init_params, make_pool_setup
+
+    cfg = ArchConfig(
+        name="tracing-mesh", family="dense", n_layers=2, d_model=64,
+        n_heads=8, n_kv_heads=4, d_ff=128, vocab=128, head_dim=16,
+        attn_impl="lln_diag", diag_block=8, lln_chunk=8, softmax_chunk=16,
+        compute_dtype="float32", param_dtype="float32", remat="none")
+    mesh = make_mesh((1, 4), ("data", "model"))
+    setup = make_pool_setup(cfg, mesh, slots=4, max_len=48, segment=3)
+    params = init_params(setup.model, mesh, 0)
+    eng = ContinuousBatcher(setup, params)
+    eng.warmup([8, 16])
+    reqs = synthetic_traffic(10, 128, [8, 16], [2, 5, 9], seed=3)
+    jax.profiler.start_trace(DIR)
+    eng.run(reqs, key=jax.random.PRNGKey(7))
+    jax.profiler.stop_trace()
+    path = sorted(glob.glob(DIR + "/plugins/profile/*/*.xplane.pb"))[-1]
+    events = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:CPU"):
+            for line in plane.lines:
+                events.extend([e.name, e.start_ns, e.start_ns + e.duration_ns,
+                               {k: v for k, v in e.stats
+                                if isinstance(v, (int, float))}]
+                              for e in line.events
+                              if e.name.startswith("batcher.")
+                              or re.search(r" _rows_with$", e.name))
+    i32 = jax.ShapeDtypeStruct((4,), "int32")
+    seg = setup.segment_fn.lower(
+        params, setup.cache_init(), i32, i32, i32,
+        jax.ShapeDtypeStruct((4,), "bool"), jax.random.PRNGKey(0))
+    pf = setup.prefill_fn(16, 2).lower(
+        params, jax.ShapeDtypeStruct((2, 16), "int32"))
+    print(json.dumps({"events": events,
+                      "segment": seg.as_text(debug_info=True),
+                      "prefill": pf.as_text(debug_info=True)}))
+"""
+
+
+@pytest.fixture(scope="module")
+def over_mesh(tmp_path_factory):
+    """A pool split over a (1, 4) mesh of host devices, run under the
+    profiler: (host events, lowered segment text, lowered prefill text)."""
+    import json
+    import os
+    import subprocess
+    import sys
+    import textwrap
+
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=src + os.pathsep + os.environ.get("PYTHONPATH", ""))
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
+                        + " --xla_force_host_platform_device_count=4")
+    code = f"DIR = {str(tmp_path_factory.mktemp('mesh'))!r}\n" + \
+        textwrap.dedent(MESH_TRACE)
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env=env, timeout=600)
+    assert out.returncode == 0, out.stderr[-4000:]
+    got = json.loads(out.stdout.strip().splitlines()[-1])
+    return got["events"], got["segment"], got["prefill"]
+
+
+@pytest.mark.parametrize("which,want", [("segment", SEGMENT_SCOPES),
+                                        ("prefill", PREFILL_SCOPES)])
+def test_programs_carry_their_scopes_over_a_mesh(over_mesh, which, want):
+    text = over_mesh[1] if which == "segment" else over_mesh[2]
+    assert want <= _scopes(text)
+
+
+def test_placements_lie_under_batcher_spans_over_a_mesh(over_mesh):
+    # Every per-slot vector update the loop makes (the only host work the
+    # fixed shardings add between segments) runs inside a batcher span:
+    # the admission's scatter (or a resume), an eviction.
+    events = over_mesh[0]
+    spans = [e for e in events if e[0].startswith("batcher.")]
+    placed = [e for e in events if not e[0].startswith("batcher.")]
+    assert any(e[0].endswith(" _rows_with") for e in placed)
+    for e in placed:
+        assert any(s[1] <= e[1] and e[2] <= s[2] for s in spans), e[0]
+    rows = [e for e in placed if e[0].endswith(" _rows_with")]
+    for e in rows:
+        assert any(s[0] in ("batcher.scatter", "batcher.evict",
+                            "batcher.resume")
+                   and s[1] <= e[1] and e[2] <= s[2] for s in spans)
+
+
+def test_segment_span_carries_the_mesh_devices(over_mesh, clean):
+    segs = [e[3] for e in over_mesh[0] if e[0] == "batcher.segment"]
+    assert segs and all(a["devices"] == 4 for a in segs)
+    assert all(s[3]["devices"] == 1 for s in clean[1]
+               if s[0] == "batcher.segment")
