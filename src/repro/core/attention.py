@@ -561,6 +561,81 @@ def write_tail_rows(tail, rows, slot, write):
                    idx].set(rows.astype(tail.dtype), mode="drop")
 
 
+def _tail_visibility(posb, t, block):
+    """Which tail slots each query of a T-token chunk at per-row positions
+    ``posb`` (B,) sees, (B, T, BLK), and each query's block start, (B, T).
+
+    Absolute position of tail slot i: entries from the previous block get
+    positions < the current block start and are masked; never-written
+    slots get negative positions."""
+    idx = jnp.arange(block)
+    cur_base = (posb // block) * block                            # (B,)
+    abs_idx = cur_base[:, None] + idx[None, :]                    # (B, BLK)
+    tail_pos = jnp.where(idx[None, :] < (posb - cur_base)[:, None],
+                         abs_idx, abs_idx - block)
+    q_pos = posb[:, None] + jnp.arange(t)[None, :]                # (B, T)
+    q_base = (q_pos // block) * block                 # block start per query
+    m_tail = (tail_pos[:, None, :] >= q_base[:, :, None]) \
+        & (tail_pos[:, None, :] >= 0)                             # (B, T, BLK)
+    return m_tail, q_base
+
+
+def _diag_chunk(q, k_new, v_new, tail_k, tail_v, posb):
+    """The diag component of a T-token chunk: one masked softmax over
+    [tail ∪ chunk] keys with per-token block-diagonal visibility.
+    q: (B,T,H,D); k/v_new: (B,T,G,D[v]); tails (B,BLK,G,D[v]); posb (B,).
+    Returns (B,T,H,Dv) f32."""
+    b, t, h, d = q.shape
+    m_tail, q_base = _tail_visibility(posb, t, tail_k.shape[1])
+    m_chunk = (jnp.arange(t)[None, None, :] <= jnp.arange(t)[None, :, None]) \
+        & (q_base[:, None, :] == q_base[:, :, None])  # (B,T,T): j<=i, same blk
+    allowed = jnp.concatenate([m_tail, m_chunk], axis=2)
+
+    keys = jnp.concatenate([tail_k, k_new.astype(tail_k.dtype)], axis=1)
+    vals = jnp.concatenate([tail_v, v_new.astype(tail_v.dtype)], axis=1)
+    # GQA repeat only here, on the (BLK+T)-key tail-softmax operands.
+    kf = _repeat_kv(keys, h).astype(jnp.float32)
+    vf = _repeat_kv(vals, h).astype(jnp.float32)
+    s = jnp.einsum("bihd,bjhd->bhij", q.astype(jnp.float32), kf) * (d ** -0.5)
+    s = jnp.where(allowed[:, None], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhij,bjhv->bihv", p, vf)
+
+
+def _diag_single(q, k_new, v_new, tail_k, tail_v, posb):
+    """:func:`_diag_chunk` for one token (T = 1), read from the tails as
+    stored.
+
+    The concatenated softmax in two parts: the tail's scores and the
+    token's own (always visible) score share one max and one denominator.
+    Query heads are grouped by kv head, (B,G,r,D), so the tails are neither
+    repeated to H heads, concatenated nor cast: q·k takes the stored
+    operands with f32 accumulation (products of bf16 values are exact),
+    and p·v keeps the f32 probabilities unrounded (``HIGHEST``).  Only the
+    order of the f32 sums differs."""
+    b, _, h, d = q.shape
+    g = tail_k.shape[2]
+    m_tail = _tail_visibility(posb, 1, tail_k.shape[1])[0][:, 0]  # (B, BLK)
+    qg = q.reshape(b, g, h // g, d)
+    f32 = jnp.float32
+    hi = jax.lax.Precision.HIGHEST
+    k_self = k_new[:, 0].astype(tail_k.dtype).astype(f32)         # (B,G,D)
+    v_self = v_new[:, 0].astype(tail_v.dtype).astype(f32)
+    scale = d ** -0.5
+    s_tail = jnp.einsum("bgrd,bjgd->bgrj", qg, tail_k, precision=hi,
+                        preferred_element_type=f32) * scale
+    s_tail = jnp.where(m_tail[:, None, None, :], s_tail, NEG_INF)
+    s_self = jnp.sum(qg.astype(f32) * k_self[:, :, None, :], -1) * scale
+    m = jnp.maximum(jnp.max(s_tail, -1), s_self)                  # (B,G,r)
+    e_tail = jnp.exp(s_tail - m[..., None])
+    e_self = jnp.exp(s_self - m)
+    den = jnp.sum(e_tail, -1) + e_self
+    pv = jnp.einsum("bgrj,bjgv->bgrv", e_tail / den[..., None], tail_v,
+                    precision=hi, preferred_element_type=f32)
+    out = pv + (e_self / den)[..., None] * v_self[:, :, None, :]
+    return out.reshape(b, 1, h, -1)
+
+
 def decode_lln_chunk(state: LLNDecodeState, q: jnp.ndarray,
                      k_new: jnp.ndarray, v_new: jnp.ndarray,
                      alpha: jnp.ndarray, beta: jnp.ndarray,
@@ -580,7 +655,8 @@ def decode_lln_chunk(state: LLNDecodeState, q: jnp.ndarray,
     component runs one masked softmax over [tail block ∪ chunk keys] with
     per-token block-diagonal visibility derived from absolute positions, so
     a chunk may straddle a diag-block boundary and still match T sequential
-    single-token steps exactly.
+    single-token steps exactly.  A single token (T = 1) takes the same
+    softmax in two parts, read from the tails as stored (:func:`_diag_single`).
 
     ``state.pos`` may be a scalar (static batch) or a per-row ``(B,)``
     vector (continuous batching: every row sits at its own absolute
@@ -606,7 +682,7 @@ def decode_lln_chunk(state: LLNDecodeState, q: jnp.ndarray,
     for T = 1: the slot the token overwrites holds a previous-block entry
     that the block mask already hides, so nothing here reads it.
     """
-    b, t, h, d = q.shape
+    b, t, h, _ = q.shape
     if backend is None:
         backend = "auto" if use_kernel else "ref"
     if backend not in ("scan", "ref"):
@@ -652,33 +728,13 @@ def decode_lln_chunk(state: LLNDecodeState, q: jnp.ndarray,
     if impl == "lln":
         return lln_out, new_state
 
-    # --- diagonal component: one softmax over [tail ∪ chunk] keys.
-    # Absolute position of tail slot i (entries from the previous block get
-    # positions < the current block start and are masked; never-written
-    # slots get negative positions).  All per-row: (B, ...) masks.
-    cur_base = (posb // block) * block                            # (B,)
-    abs_idx = cur_base[:, None] + idx[None, :]                    # (B, BLK)
-    tail_pos = jnp.where(idx[None, :] < (posb - cur_base)[:, None],
-                         abs_idx, abs_idx - block)
-    q_pos = posb[:, None] + jnp.arange(t)[None, :]                # (B, T)
-    q_base = (q_pos // block) * block                 # block start per query
-    m_tail = (tail_pos[:, None, :] >= q_base[:, :, None]) \
-        & (tail_pos[:, None, :] >= 0)                             # (B, T, BLK)
-    m_chunk = (jnp.arange(t)[None, None, :] <= jnp.arange(t)[None, :, None]) \
-        & (q_base[:, None, :] == q_base[:, :, None])  # (B,T,T): j<=i, same blk
-    allowed = jnp.concatenate([m_tail, m_chunk], axis=2)
-
-    keys = jnp.concatenate(
-        [state.tail_k, k_t.astype(state.tail_k.dtype)], axis=1)
-    vals = jnp.concatenate(
-        [state.tail_v, v_t.astype(state.tail_v.dtype)], axis=1)
-    # GQA repeat only here, on the (BLK+T)-key tail-softmax operands.
-    kf = _repeat_kv(keys, h).astype(jnp.float32)
-    vf = _repeat_kv(vals, h).astype(jnp.float32)
-    s = jnp.einsum("bihd,bjhd->bhij", q.astype(jnp.float32), kf) * (d ** -0.5)
-    s = jnp.where(allowed[:, None], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    diag_out = jnp.einsum("bhij,bjhv->bihv", p, vf)
+    # --- diagonal component: a softmax over [tail ∪ chunk] keys.
+    if t == 1:
+        with jax.named_scope("diag_tail"):
+            diag_out = _diag_single(q, k_t, v_t, state.tail_k, state.tail_v,
+                                    posb)
+    else:
+        diag_out = _diag_chunk(q, k_t, v_t, state.tail_k, state.tail_v, posb)
     out = 0.5 * (lln_out.astype(jnp.float32) + diag_out)
     return out.astype(v_new.dtype), new_state
 

@@ -234,6 +234,71 @@ class TestDecodeChunk:
             assert int(stc.pos) == int(sts.pos)
 
 
+class TestSingleTokenDiag:
+    """The T = 1 diag component, read from the tails as stored in two
+    parts, == the concatenated form that chunks of T > 1 keep."""
+
+    BLOCK = 256
+    # Per row: block start with an empty tail, block start with a full
+    # previous block (all masked), mid-block, the last slot of a block
+    # after a full one, mid-block, the last slot of the first block.
+    POS = (0, 256, 100, 511, 700, 255)
+    ROW_MASK = (True, False, True, True, False, True)
+
+    def _inputs(self, r, d, dtype, seed):
+        b, g = len(self.POS), 2
+        h = g * r
+        kq, kk, kv, kt, ku = jax.random.split(jax.random.PRNGKey(seed), 5)
+        q = jax.random.normal(kq, (b, 1, h, d)).astype(dtype)
+        kn = jax.random.normal(kk, (b, 1, g, d)).astype(dtype)
+        vn = jax.random.normal(kv, (b, 1, g, d)).astype(dtype)
+        tail_k = (2 * jax.random.normal(kt, (b, self.BLOCK, g, d))
+                  ).astype(jnp.bfloat16)
+        tail_v = jax.random.normal(ku, (b, self.BLOCK, g, d)
+                                   ).astype(jnp.bfloat16)
+        return q, kn, vn, tail_k, tail_v, jnp.asarray(self.POS, jnp.int32)
+
+    @pytest.mark.parametrize("d", [64, 128])
+    @pytest.mark.parametrize("r", [1, 8])
+    def test_matches_concatenated_form(self, r, d):
+        """Serving dtypes (bf16 q, keys and tails), every row position."""
+        args = self._inputs(r, d, jnp.bfloat16, seed=r + d)
+        two = ca._diag_single(*args)
+        cat = ca._diag_chunk(*args)
+        assert two.dtype == cat.dtype == jnp.float32
+        np.testing.assert_allclose(np.asarray(two), np.asarray(cat),
+                                   rtol=1e-5, atol=1e-6)
+
+    @pytest.mark.parametrize("defer_tail", [False, True])
+    @pytest.mark.parametrize("d", [64, 128])
+    @pytest.mark.parametrize("r", [1, 8])
+    def test_decode_matches_concatenated_form(self, r, d, defer_tail):
+        """decode_lln_chunk at T = 1 with masked rows == its LLN output
+        plus the concatenated diag form; the state is the LLN path's."""
+        q, kn, vn, tail_k, tail_v, pos = self._inputs(r, d, jnp.float32,
+                                                      seed=3 * r + d)
+        b, _, h, _ = q.shape
+        qp, kp, vp = _qkv(r + d, b, 16, h, kn.shape[2], d)
+        alpha, beta = jnp.full((h,), 1.3), jnp.full((kn.shape[2],), 1.1)
+        _, s, z, c_k = kops.lln_prefill(qp, kp, vp, alpha, beta, chunk=8)
+        st = ca.LLNDecodeState(lln=core_lln.LLNState(s=s, z=z, c_k=c_k),
+                               tail_k=tail_k, tail_v=tail_v, pos=pos)
+        beta_h = jnp.repeat(beta, r)
+        mask = jnp.asarray(self.ROW_MASK)
+        kw = dict(row_mask=mask, defer_tail=defer_tail)
+        out, st_new = ca.decode_lln_chunk(st, q, kn, vn, alpha, beta_h, **kw)
+        lln_out, st_lln = ca.decode_lln_chunk(st, q, kn, vn, alpha, beta_h,
+                                              impl="lln", **kw)
+        ref = 0.5 * (lln_out.astype(jnp.float32)
+                     + ca._diag_chunk(q, kn, vn, tail_k, tail_v, pos))
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=1e-5, atol=1e-6)
+        for a, e in zip(jax.tree.leaves(st_new), jax.tree.leaves(st_lln)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(e))
+        np.testing.assert_array_equal(np.asarray(st_new.pos),
+                                      np.asarray(pos + mask))
+
+
 def _tiny_cfg(impl, r, **kw):
     h = 4
     return ArchConfig(

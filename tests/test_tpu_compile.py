@@ -131,9 +131,11 @@ def test_kernel_compiles_for_v5e(one_chip, name, d, r):
     _compile(fn, one_chip, *shapes)
 
 
-def _loop_computations(hlo: str) -> list:
+def _loop_computations(hlo: str, fused: bool = True) -> list:
     """The instruction lines of every computation a while loop runs, with
-    the computations those call (fusions, nested loops), transitively."""
+    the computations those call (fusions, nested loops), transitively.
+    ``fused=False`` leaves out the fusions' own computations, so each line
+    is an instruction whose result the program keeps in memory."""
     comps, name = {}, None
     for line in hlo.splitlines():
         head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{\s*$", line)
@@ -145,7 +147,9 @@ def _loop_computations(hlo: str) -> list:
         elif name:
             comps[name].append(line)
     calls = {n: set(re.findall(r"(?:body|condition|calls|to_apply)="
-                               r"%([\w.\-]+)", "\n".join(body)))
+                               r"%([\w.\-]+)", "\n".join(
+                                   line for line in body
+                                   if fused or " fusion(" not in line)))
              for n, body in comps.items()}
     todo = [b for body in comps.values() for line in body
             for b in re.findall(r"body=%([\w.\-]+)", line)]
@@ -158,6 +162,37 @@ def _loop_computations(hlo: str) -> list:
     return [line for c in seen for line in comps.get(c, ())]
 
 
+def _segment_hlo(sharding, cfg, slots):
+    """The compiled text of the pool's decode segment program for ``cfg``
+    at ``slots`` slots and max_len 1024, on the chip of ``sharding``."""
+    from repro.launch.steps import make_pool_setup
+
+    setup = make_pool_setup(cfg, None, slots=slots, max_len=1024, segment=8)
+    on_chip = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+    row = lambda dt: jax.ShapeDtypeStruct((slots,), dt, sharding=sharding)
+    return setup.segment_fn.lower(
+        on_chip(jax.eval_shape(setup.model.init, jax.random.PRNGKey(0))),
+        on_chip(jax.eval_shape(setup.cache_init)), row(jnp.int32),
+        row(jnp.int32), row(jnp.int32), row(jnp.bool_),
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=sharding),
+    ).compile().as_text()
+
+
+def _results(lines, opcodes):
+    """(shape, line) of each instruction in ``lines`` whose opcode is one of
+    ``opcodes``: its result's dtype and dims, the dims of size 1 dropped."""
+    out = []
+    for line in lines:
+        m = re.search(r"= (\w+)\[([\d,]*)\]\S* (\w[\w-]*)\(", line)
+        if m and m.group(3) in opcodes:
+            dims = tuple(int(x) for x in m.group(2).split(",") if x not in
+                         ("", "1"))
+            out.append(((m.group(1),) + dims, line.strip()[:160]))
+    return out
+
+
 @pytest.mark.parametrize("impl", ["lln_diag", "lln"])
 def test_pool_segment_carries_caches_in_place(one_chip, impl):
     """The pool's decode segment at stablelm-1.6b widths (2 layers, 16
@@ -165,21 +200,10 @@ def test_pool_segment_carries_caches_in_place(one_chip, impl):
     stacked LLN state inside its loops: the layer loop writes each layer's
     state into the carried stack and the tails take one row per step."""
     from repro.configs.registry import get_config
-    from repro.launch.steps import make_pool_setup
 
     layers, slots = 2, 16
     cfg = get_config("stablelm-1.6b", attn_impl=impl, n_layers=layers)
-    setup = make_pool_setup(cfg, None, slots=slots, max_len=1024, segment=8)
-    on_chip = lambda tree: jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        tree)
-    row = lambda dt: jax.ShapeDtypeStruct((slots,), dt, sharding=one_chip)
-    hlo = setup.segment_fn.lower(
-        on_chip(jax.eval_shape(setup.model.init, jax.random.PRNGKey(0))),
-        on_chip(jax.eval_shape(setup.cache_init)), row(jnp.int32),
-        row(jnp.int32), row(jnp.int32), row(jnp.bool_),
-        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip),
-    ).compile().as_text()
+    hlo = _segment_hlo(one_chip, cfg, slots)
     h, d, blk = cfg.n_heads, cfg.hd, cfg.diag_block
     stacked = (f"bf16[{layers},{slots},{blk},{h},{d}]",
                f"f32[{layers},{slots},{h},{d},{d}]")
@@ -189,3 +213,27 @@ def test_pool_segment_carries_caches_in_place(one_chip, impl):
               and re.search(r"= (\S+?)\{", line).group(1) in stacked]
     assert not copies, copies
     assert any(" scatter(" in line for line in body)
+
+
+@pytest.mark.parametrize("arch,slots,overrides", [
+    ("stablelm-1.6b", 16, {}),
+    ("yi-9b", 32, {"n_heads": 8, "n_kv_heads": 1, "d_ff": 2752}),
+], ids=["stablelm-1.6b", "yi-9b-per-chip"])
+def test_pool_segment_reads_diag_tail_in_place(one_chip, arch, slots,
+                                               overrides):
+    """Single-token LLN+Diag decode reads each layer's diag tail where the
+    stacked cache holds it: no fusion or copy inside the segment's loops
+    makes a per-layer tail (slots, block, kv heads, head size).  Shapes are
+    one chip's: stablelm-1.6b whole (32 kv heads of 64), and yi-9b as one
+    of four chips holds it under ``tp_heads`` (8 query heads over 1 kv
+    head of 128)."""
+    from repro.configs.registry import get_config
+
+    cfg = get_config(arch, attn_impl="lln_diag", n_layers=2, **overrides)
+    hlo = _segment_hlo(one_chip, cfg, slots)
+    g, d, blk = cfg.n_kv_heads, cfg.hd, cfg.diag_block
+    tail = tuple(x for x in ("bf16", slots, blk, g, d) if x != 1)
+    made = [line for shape, line in _results(
+                _loop_computations(hlo, fused=False), ("fusion", "copy"))
+            if shape == tail]
+    assert not made, made
