@@ -19,7 +19,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
-from repro.core.attention import batch_alpha_beta, AttnConfig
+from repro.core.attention import batch_alpha_beta
+from repro.kernels.registry import AttnSpec
 from repro.data.synthetic import mlm_batches
 from repro.models import build_model
 from repro.optim import AdamWConfig, adamw_init, adamw_update
@@ -50,7 +51,7 @@ def _train_curve(cfg, steps, seed=0, lr=3e-3, batch=8, seq=128,
             bq, n, cfg.n_heads, cfg.hd)
         k = dense(lp["attn"]["k_w"], h, cfg.cdtype).reshape(
             bq, n, cfg.n_kv_heads, cfg.hd)
-        a, b_ = batch_alpha_beta(q, k, AttnConfig())
+        a, b_ = batch_alpha_beta(q, k, AttnSpec())
         return jnp.mean(a), jnp.mean(b_)
 
     gen = mlm_batches(cfg.vocab, batch, seq, seed=0)

@@ -105,7 +105,7 @@ def run(out_path: str = DEFAULT_OUT, smoke: bool = False,
             lln=core_lln.LLNState(s=state.s, z=state.z, c_k=state.c_k),
             tail_k=state.tail_k, tail_v=state.tail_v, pos=state.pos)
         return ca.decode_lln_chunk(st, qn, kn, vn, state.alpha, state.beta,
-                                   impl="lln_diag")
+                                   impl="lln_diag", backend="auto")
 
     legacy_dec = jax.jit(legacy_step)
     engine_dec = jax.jit(lambda state, qn, kn, vn: eng.decode(state, qn,

@@ -19,15 +19,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import AttnConfig, multi_head_attention
+from repro.core import AttentionEngine
+from repro.kernels.registry import AttnSpec
 
 
-def _make_fn(impl, causal=True):
-    cfg = AttnConfig(impl=impl, causal=causal, diag_block=64, lln_chunk=64,
-                     softmax_chunk=64, fixed_ab=2.0)
+def _make_fn(impl, causal=True, h=4, d=32):
+    spec = AttnSpec(impl=impl, causal=causal, diag_block=64, lln_chunk=64,
+                    softmax_chunk=64, fixed_ab=2.0, train_twin="scan")
+    eng = AttentionEngine(spec=spec, heads=h, kv_heads=h, head_dim=d,
+                          v_dim=d)
 
     def loss(q, k, v):
-        return jnp.sum(multi_head_attention(q, k, v, cfg) ** 2)
+        return jnp.sum(eng.attention(q, k, v) ** 2)
     return jax.jit(jax.grad(loss))
 
 

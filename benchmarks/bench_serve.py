@@ -4,7 +4,7 @@ Measures, per (GQA ratio r, attention impl), on a small real model driven
 through the real serving machinery (``launch/steps.py:make_serve_setup``):
 
 * **prefill latency** — ``seed``: the seed two-pass prefill
-  (``use_serve_kernel=False``: jnp causal scan + a second full-key einsum to
+  (``attn_backend="ref"``: jnp causal scan + a second full-key einsum to
   rebuild the decode state, repeated KV, H-head tails) vs ``kernel``: the
   state-emitting one-pass prefill (``kernels/ops.py:lln_prefill`` — Pallas
   kernel on TPU, its chunked ``lax.scan`` twin on the CPU container — plus
@@ -49,13 +49,13 @@ DEFAULT_OUT = os.path.join(ROOT, "BENCH_serve.json")
 IMPLS = ("softmax", "lln", "lln_diag")
 
 
-def _cfg(r: int, impl: str, *, blk: int, serve_kernel: bool) -> ArchConfig:
+def _cfg(r: int, impl: str, *, blk: int, backend: str) -> ArchConfig:
     h = 4
     return ArchConfig(
         name=f"serve-bench-r{r}", family="dense", n_layers=2, d_model=128,
         n_heads=h, n_kv_heads=h // r, d_ff=256, vocab=512, head_dim=32,
         attn_impl=impl, diag_block=blk, lln_chunk=blk, softmax_chunk=2 * blk,
-        use_serve_kernel=serve_kernel, compute_dtype="float32",
+        attn_backend=backend, compute_dtype="float32",
         param_dtype="float32", remat="none", tie_embeddings=True)
 
 
@@ -137,8 +137,8 @@ class _Bench:
 def bench_one(r: int, impl: str, *, batch: int, prompt: int, gen: int,
               blk: int, chunk_t: int, repeats: int, mesh) -> dict:
     modes = {}
-    for mode, sk in (("seed", False), ("kernel", True)):
-        modes[mode] = _Bench(_cfg(r, impl, blk=blk, serve_kernel=sk),
+    for mode, backend in (("seed", "ref"), ("kernel", "auto")):
+        modes[mode] = _Bench(_cfg(r, impl, blk=blk, backend=backend),
                              batch, prompt, gen, mesh)
     # --- prefill: warm both, then interleave min-of-K (order alternated
     # per round so host-load drift and order bias hit both modes equally).
@@ -225,7 +225,7 @@ def run(out_path: str = DEFAULT_OUT, smoke: bool = False,
         "interpret_mode": jax.default_backend() == "cpu",
         "repeats": repeats,
         "modes": {
-            "seed": "use_serve_kernel=False prefill (jnp scan + second "
+            "seed": "attn_backend='ref' prefill (jnp scan + second "
                     "full-key state einsum, repeated KV, H-head tails) + "
                     "per-token Python dispatch loop",
             "kernel": "state-emitting one-pass prefill (Pallas / scan twin) "

@@ -67,14 +67,14 @@ SMOKE_SHAPES = [Shape(b=1, n=64, h=2, g=1, d=8, e=16, chunk=32)]
 
 def _attn(impl: str, q, k, v, alpha, beta, chunk: int, pallas_bwd: bool):
     if impl == "lln_causal":
-        return kops.lln_attention(q, k, v, alpha, beta, True, chunk, None,
+        return kops.lln_attention(q, k, v, alpha, beta, True, chunk,
                                   pallas_bwd)
     if impl == "lln_bidir":
-        return kops.lln_attention(q, k, v, alpha, beta, False, chunk, None,
+        return kops.lln_attention(q, k, v, alpha, beta, False, chunk,
                                   pallas_bwd)
     if impl == "lln_diag":
         return kops.lln_diag_attention(q, k, v, alpha, beta, True, chunk,
-                                       None, pallas_bwd)
+                                       pallas_bwd)
     raise ValueError(impl)
 
 

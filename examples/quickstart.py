@@ -3,19 +3,20 @@
 Demonstrates the paper's three pieces on raw tensors:
   1. moment matching (eq. 10) — solve (alpha, beta) from input statistics;
   2. LLN attention (eq. 8) — linear-complexity, log-normal score matrix;
-  3. the LLN+Diag hybrid (§4.2) via the unified multi_head_attention API,
-     identical to what every assigned architecture uses internally.
+  3. the LLN+Diag hybrid (§4.2) via the AttentionEngine's full-sequence
+     attention, identical to what every assigned architecture uses
+     internally.
 
 Run:  PYTHONPATH=src python examples/quickstart.py
 """
 import jax
 import jax.numpy as jnp
 
-from repro.core import (AttnConfig, multi_head_attention, lln_causal,
-                        solve_alpha_beta)
+from repro.core import AttentionEngine, lln_causal, solve_alpha_beta
 from repro.core.metrics import attention_log_moments, lognormality_score
 from repro.core.moment_matching import (constants_for_dim, lln_attn_matrix,
                                         softmax_attn_matrix)
+from repro.kernels.registry import AttnSpec
 
 key = jax.random.PRNGKey(0)
 B, N, H, D = 2, 512, 8, 64
@@ -43,11 +44,16 @@ q = jax.random.normal(kq, (B, N, H, D), jnp.bfloat16)
 k = jax.random.normal(kk, (B, N, H, D), jnp.bfloat16)
 v = jax.random.normal(kv, (B, N, H, D), jnp.bfloat16)
 
+
+
+def engine(impl):
+    return AttentionEngine(spec=AttnSpec(impl=impl, causal=True), heads=H,
+                           kv_heads=H, head_dim=D, v_dim=D)
+
+
 out_lln = lln_causal(q, k, v, alpha, beta, chunk=128)      # pure LLN
-cfg = AttnConfig(impl="lln_diag", causal=True)             # paper §4.2 hybrid
-out_hybrid = multi_head_attention(q, k, v, cfg)            # auto moment-match
-cfg_sa = AttnConfig(impl="softmax", causal=True)
-out_sa = multi_head_attention(q, k, v, cfg_sa)
+out_hybrid = engine("lln_diag").attention(q, k, v)        # §4.2 hybrid,
+out_sa = engine("softmax").attention(q, k, v)             # auto moment-match
 
 cos = jnp.sum(out_hybrid.astype(jnp.float32) * out_sa.astype(jnp.float32)) / (
     jnp.linalg.norm(out_hybrid.astype(jnp.float32))

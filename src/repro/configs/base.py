@@ -33,11 +33,9 @@ class ArchConfig:
     use_kernel: bool = False         # training forward on the CPU backend:
                                      # True runs the Pallas kernels (in
                                      # interpret mode), False the jnp
-                                     # reference.  Accelerators always run
-                                     # the kernels.
-    use_serve_kernel: bool = True    # legacy escape: False maps to
-                                     # attn_backend="ref" (the seed jnp
-                                     # serving path), kept for benchmarking
+                                     # scan.  Accelerators always run the
+                                     # kernels (kernels/registry.py:
+                                     # train_twin_of)
     attn_backend: str = "auto"       # kernels/registry.py backend:
                                      # auto | pallas | scan | ref
     qk_norm: bool = False

@@ -1,72 +1,39 @@
-"""Unified multi-head attention front-end.
+"""Multi-head attention math in jnp: calibration, softmax, decode state.
 
-One entry point — :func:`multi_head_attention` — dispatching on
-``impl in {"softmax", "lln", "lln_diag"}``:
+* :func:`batch_alpha_beta` — the moment-matched (alpha, beta) of eq. 10;
+* :func:`flash_softmax` / :func:`naive_softmax` — the arch-faithful
+  softmax baseline (flash-style: online softmax, chunked over keys, so a
+  32k-token prefill never materializes an N x N matrix) and its quadratic
+  reference;
+* the decode state (:class:`KVCache`, :class:`LLNDecodeState`) and the
+  chunked decode/commit of softmax and LLN(+Diag) attention.
 
-* ``softmax``  — arch-faithful baseline; flash-style (online-softmax, chunked
-  over keys) so 32k-token prefill never materializes an N x N matrix.
-* ``lln``      — the paper's Linear Log-Normal attention (eq. 8) with
-  moment-matched (alpha, beta) (eq. 10), causal or bidirectional.
-* ``lln_diag`` — the paper's §4.2 hybrid: average of LLN and block-diagonal
-  softmax attention.
-
-GQA/MQA: k/v may carry fewer heads (G) than q (H); G must divide H.
-All inputs are (batch, seq, heads, head_dim).
+The front-end that picks an implementation is
+:class:`repro.core.engine.AttentionEngine`; nothing here reads the
+platform.  GQA/MQA: k/v may carry fewer heads (G) than q (H); G must
+divide H.  All inputs are (batch, seq, heads, head_dim).
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Union
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from . import lln as lln_mod
 from .numerics import einsum_f32
-from .diag import block_diag_attn
-from .lln import LLNState, lln_bidir, lln_causal
-from .moment_matching import (constants_for_dim, length_gain,
-                              solve_alpha_beta)
+from .lln import LLNState
+from .moment_matching import constants_for_dim, solve_alpha_beta
 
 NEG_INF = -1e30
 
-#: Logical axes of the arrays attention hands a kernel, by name, for
-#: ``distributed/sharding.py:per_device``: q/k/v/out (B, N, H|G, D) and the
-#: calibrations alpha/beta, heads last ((H|G,) or per-row (B, H|G)).  Query
-#: and kv heads share the name "heads", so they split together or not at all.
-ATTN_AXES = {
-    "q": ("act_batch", None, "heads", None),
-    "k": ("act_batch", None, "heads", None),
-    "v": ("act_batch", None, "heads", None),
-    "out": ("act_batch", None, "heads", None),
-    "alpha": ("act_batch", "heads"),
-    "beta": ("act_batch", "heads"),
-}
+
+def _no_constraint(x, *logical_axes):
+    return x
 
 
-@dataclasses.dataclass(frozen=True)
-class AttnConfig:
-    impl: str = "softmax"          # softmax | lln | lln_diag | log_linear
-    causal: bool = True
-    diag_block: int = 256          # block size of the §4.2 diagonal component
-    lln_chunk: int = 128           # chunk of the causal LLN scan (also the
-                                   # log_linear bucket granule)
-    softmax_chunk: int = 1024      # key-chunk of the flash softmax path
-    use_kernel: bool = False       # route through Pallas kernels (kernels/ops)
-    backend: Optional[str] = None  # explicit kernel backend (kernels/registry
-                                   # auto|pallas|scan|ref); None -> "auto"
-    # Moment-matching constants; None -> calibrated defaults for head_dim.
-    mm_a: Optional[float] = None
-    mm_b: Optional[float] = None
-    # Fixed alpha=beta (paper §A.8.4 ablation); 0 = dynamic moment matching.
-    fixed_ab: float = 0.0
-    # log_linear only: Fenwick pyramid depth and per-level mix decay
-    # (core/loglinear.py; num_scales=1 or scale_decay=1 reduce to lln).
-    num_scales: int = 4
-    scale_decay: float = 0.5
-
-
-def _repeat_kv(t: jnp.ndarray, h: int) -> jnp.ndarray:
+def repeat_kv(t: jnp.ndarray, h: int) -> jnp.ndarray:
     """Expand (B, N, G, D) kv heads to H = G*R query heads."""
     g = t.shape[2]
     if g == h:
@@ -74,7 +41,7 @@ def _repeat_kv(t: jnp.ndarray, h: int) -> jnp.ndarray:
     return jnp.repeat(t, h // g, axis=2)
 
 
-def batch_alpha_beta(q: jnp.ndarray, k: jnp.ndarray, cfg: AttnConfig,
+def batch_alpha_beta(q: jnp.ndarray, k: jnp.ndarray, cfg,
                      per_row: bool = False, n: int | None = None
                      ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Moment-matched (alpha, beta) from current-batch statistics.
@@ -91,8 +58,8 @@ def batch_alpha_beta(q: jnp.ndarray, k: jnp.ndarray, cfg: AttnConfig,
     beta: (B, G).  This is the continuous-batching admission setting: a
     batched slot prefill then yields exactly the calibration each request
     would get prefilled solo, so grouped admission stays per-request exact
-    even under dynamic moment matching.  ``cfg`` may be any object with
-    ``fixed_ab`` / ``mm_a`` / ``mm_b`` attributes (``AttnConfig`` or
+    even under dynamic moment matching.  ``cfg`` is any object with
+    ``fixed_ab`` / ``mm_a`` / ``mm_b`` attributes (a
     ``kernels.registry.AttnSpec``).
 
     ``n`` (optional, static int) is the sequence length the calibration is
@@ -149,6 +116,7 @@ def flash_softmax(
     scale: Optional[float] = None,
     prefix_len: int = 0,
     q_start: Optional[jnp.ndarray] = None,
+    constrain=_no_constraint,
 ) -> jnp.ndarray:
     """Flash-style (online-softmax) attention, chunked over keys.
 
@@ -166,9 +134,9 @@ def flash_softmax(
     at its own depth in the cache).
     ``prefix_len``: prefix-LM — keys < prefix_len are visible to every query
     (PaliGemma-style bidirectional image prefix).
+    ``constrain``: the logical-axis sharding annotation for the stacked scan
+    operands (``distributed/sharding.py:constrain``; none by default).
     """
-    from repro.distributed.sharding import constrain
-
     b, nq, h, d = q.shape
     nk, g = k.shape[1], k.shape[2]
     dv = v.shape[-1]
@@ -200,8 +168,8 @@ def flash_softmax(
     # softmax statistics and accumulators are fp32 (preferred_element_type
     # on the two matmuls).  Upcasting k/v here would materialize fp32
     # copies of the whole cache.  The stacked scan operands are explicitly
-    # constrained (no-op outside a mesh) so the partitioner keeps batch on
-    # the data axis and heads on the model axis.
+    # constrained (a no-op outside a mesh) so the partitioner keeps batch
+    # on the data axis and heads on the model axis.
     qg = (q.reshape(b, nqc, qchunk, h, d).transpose(1, 0, 2, 3, 4)
           * jnp.asarray(scale, q.dtype))                     # (nqc,B,Cq,H,D)
     kc = k.reshape(b, nkc, chunk, h, d).transpose(1, 0, 2, 3, 4)
@@ -271,8 +239,8 @@ def naive_softmax(
     """Quadratic reference (small N / tests only)."""
     b, nq, h, d = q.shape
     nk = k.shape[1]
-    k = _repeat_kv(k, h)
-    v = _repeat_kv(v, h)
+    k = repeat_kv(k, h)
+    v = repeat_kv(v, h)
     scale = (d ** -0.5) if scale is None else scale
     s = jnp.einsum("bqhd,bjhd->bhqj", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
@@ -286,89 +254,6 @@ def naive_softmax(
         s = s + jnp.where(allowed[None, None], 0.0, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqj,bjhv->bqhv", p, v.astype(jnp.float32)).astype(v.dtype)
-
-
-# ---------------------------------------------------------------------------
-# Unified entry point.
-# ---------------------------------------------------------------------------
-
-def multi_head_attention(
-    q: jnp.ndarray,
-    k: jnp.ndarray,
-    v: jnp.ndarray,
-    cfg: AttnConfig,
-    *,
-    mask: Optional[jnp.ndarray] = None,
-    alpha: Optional[jnp.ndarray] = None,
-    beta: Optional[jnp.ndarray] = None,
-    prefix_len: int = 0,
-) -> jnp.ndarray:
-    """Full-sequence attention (training / prefill).  See module docstring."""
-    h = q.shape[2]
-    if cfg.impl == "softmax":
-        return flash_softmax(q, k, v, causal=cfg.causal,
-                             chunk=min(cfg.softmax_chunk, k.shape[1]),
-                             mask=mask, prefix_len=prefix_len)
-    g = k.shape[2]
-    if alpha is None or beta is None:
-        alpha, beta = batch_alpha_beta(q, k, cfg)
-    alpha = jnp.asarray(alpha, jnp.float32)
-    beta = jnp.asarray(beta, jnp.float32)
-    if alpha.ndim == 0:
-        alpha = jnp.broadcast_to(alpha, (h,))
-    if beta.ndim == 0:
-        beta = jnp.broadcast_to(beta, (g,))
-    # Heads live on the LAST axis ((H,) or per-row (B, H)) — pool a
-    # per-q-head beta to the kv groups either way.
-    if beta.shape[-1] == h and g != h:
-        beta = beta.reshape(beta.shape[:-1] + (g, h // g)).mean(axis=-1)
-
-    if cfg.use_kernel:
-        # Kernels handle GQA via BlockSpec index maps — no KV repeat; the
-        # backend registry owns the pallas/scan/ref dispatch.
-        if mask is not None:
-            raise ValueError("the attention kernels take no padding mask; "
-                             "use the jnp path (use_kernel=False, or "
-                             "backend 'ref') for masked attention")
-        from repro.kernels import registry as kreg
-        spec = kreg.AttnSpec(impl=cfg.impl, causal=cfg.causal, r=h // g,
-                             backend=cfg.backend or "auto",
-                             lln_chunk=cfg.lln_chunk,
-                             diag_block=cfg.diag_block,
-                             softmax_chunk=cfg.softmax_chunk,
-                             fixed_ab=cfg.fixed_ab,
-                             num_scales=cfg.num_scales,
-                             scale_decay=cfg.scale_decay)
-        from repro.distributed.sharding import per_device
-        return per_device(
-            lambda **a: {"out": kreg.attention(spec, **a)},
-            ATTN_AXES.__getitem__, q=q, k=k, v=v, alpha=alpha,
-            beta=beta)["out"]
-
-    kv_k = _repeat_kv(k, h)
-    kv_v = _repeat_kv(v, h)
-    beta_h = jnp.repeat(beta, h // g, axis=-1) if g != h else beta
-    if cfg.impl == "log_linear":
-        if not cfg.causal:
-            raise ValueError("log_linear attention is causal-only")
-        from . import loglinear as _loglin
-        out, _ = _loglin.prefill(q, kv_k, kv_v, alpha, beta_h,
-                                 granule=cfg.lln_chunk,
-                                 num_scales=cfg.num_scales,
-                                 scale_decay=cfg.scale_decay)
-        return out.astype(v.dtype)
-    if cfg.causal:
-        lln_out = lln_causal(q, kv_k, kv_v, alpha, beta_h, chunk=cfg.lln_chunk)
-    else:
-        lln_out = lln_bidir(q, kv_k, kv_v, alpha, beta_h, mask=mask)
-    if cfg.impl == "lln":
-        return lln_out
-    if cfg.impl == "lln_diag":
-        diag_out = block_diag_attn(q, kv_k, kv_v, block=cfg.diag_block,
-                                   causal=cfg.causal, mask=mask)
-        return (0.5 * (lln_out.astype(jnp.float32)
-                       + diag_out.astype(jnp.float32))).astype(v.dtype)
-    raise ValueError(f"unknown attention impl: {cfg.impl}")
 
 
 # ---------------------------------------------------------------------------
@@ -401,8 +286,7 @@ class LLNDecodeState:
     this is what makes long_500k decode O(d^2 + block) per token.  Under GQA
     the tail carries the G kv heads (cache bytes / r); it is repeated to the
     H query heads only inside the tiny tail-softmax.  H-head tails (the seed
-    layout, still produced by MLA and the ``use_serve_kernel=False`` path)
-    are accepted too — the head count is read off the buffer shape.
+    layout) are accepted too — the head count is read off the buffer shape.
     """
     lln: LLNState
     tail_k: jnp.ndarray     # (B, BLK, G, D)
@@ -425,7 +309,8 @@ def decode_softmax(cache: KVCache, q: jnp.ndarray, k_new: jnp.ndarray,
                    v_new: jnp.ndarray, *, scale: Optional[float] = None,
                    chunk: int = 1024,
                    row_mask: Optional[jnp.ndarray] = None,
-                   commit_len: Optional[jnp.ndarray] = None
+                   commit_len: Optional[jnp.ndarray] = None,
+                   constrain=_no_constraint
                    ) -> tuple[jnp.ndarray, KVCache]:
     """Softmax decode of T >= 1 tokens against a KV cache.
 
@@ -445,10 +330,10 @@ def decode_softmax(cache: KVCache, q: jnp.ndarray, k_new: jnp.ndarray,
     where they are invisible to scoring and overwritten by the next
     commit before ``length`` can ever reach them; ``commit_len=0`` rows
     restore their buffer bitwise (the masked-row contract).  Requires
-    per-row ``length``.  Returns (out (B,T,H,Dv), new cache).
+    per-row ``length``.  ``constrain`` annotates the cache's and the
+    scan's sharding as in :func:`flash_softmax`.  Returns
+    (out (B,T,H,Dv), new cache).
     """
-    from repro.distributed.sharding import constrain
-
     per_row = jnp.ndim(cache.length) == 1
     if commit_len is not None and not per_row:
         raise ValueError("decode_softmax: commit_len requires a per-row "
@@ -493,7 +378,8 @@ def decode_softmax(cache: KVCache, q: jnp.ndarray, k_new: jnp.ndarray,
     valid = jnp.arange(kc.shape[1])[None, :] < lens[:, None]
     out = flash_softmax(q, kc, vc, causal=True,
                         chunk=min(chunk, kc.shape[1]),
-                        mask=valid, scale=scale, q_start=cache.length)
+                        mask=valid, scale=scale, q_start=cache.length,
+                        constrain=constrain)
     if ret_k is None:
         ret_k, ret_v = kc, vc
     return out, KVCache(k=ret_k, v=ret_v, length=new_len)
@@ -594,8 +480,8 @@ def _diag_chunk(q, k_new, v_new, tail_k, tail_v, posb):
     keys = jnp.concatenate([tail_k, k_new.astype(tail_k.dtype)], axis=1)
     vals = jnp.concatenate([tail_v, v_new.astype(tail_v.dtype)], axis=1)
     # GQA repeat only here, on the (BLK+T)-key tail-softmax operands.
-    kf = _repeat_kv(keys, h).astype(jnp.float32)
-    vf = _repeat_kv(vals, h).astype(jnp.float32)
+    kf = repeat_kv(keys, h).astype(jnp.float32)
+    vf = repeat_kv(vals, h).astype(jnp.float32)
     s = jnp.einsum("bihd,bjhd->bhij", q.astype(jnp.float32), kf) * (d ** -0.5)
     s = jnp.where(allowed[:, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
@@ -639,10 +525,8 @@ def _diag_single(q, k_new, v_new, tail_k, tail_v, posb):
 def decode_lln_chunk(state: LLNDecodeState, q: jnp.ndarray,
                      k_new: jnp.ndarray, v_new: jnp.ndarray,
                      alpha: jnp.ndarray, beta: jnp.ndarray,
-                     *, impl: str = "lln_diag",
-                     use_kernel: bool = True,
+                     *, impl: str = "lln_diag", backend: str,
                      row_mask: Optional[jnp.ndarray] = None,
-                     backend: Optional[str] = None,
                      commit_len: Optional[jnp.ndarray] = None,
                      renorm: Optional[float] = None,
                      defer_tail: bool = False
@@ -650,8 +534,8 @@ def decode_lln_chunk(state: LLNDecodeState, q: jnp.ndarray,
     """LLN(+Diag) decode of T >= 1 tokens.  q: (B,T,H,D); k/v_new: (B,T,G,D[v]).
 
     The LLN state advance is vectorized over the chunk (one rescale, one
-    intra-chunk causal quadratic — kernels/ops.py:lln_decode_chunk when
-    ``use_kernel``; the jnp ``core.lln.decode_chunk`` otherwise).  The diag
+    intra-chunk causal quadratic — kernels/ops.py:lln_decode_chunk under
+    ``auto``/``pallas``; the jnp ``core.lln.decode_chunk`` otherwise).  The diag
     component runs one masked softmax over [tail block ∪ chunk keys] with
     per-token block-diagonal visibility derived from absolute positions, so
     a chunk may straddle a diag-block boundary and still match T sequential
@@ -666,9 +550,8 @@ def decode_lln_chunk(state: LLNDecodeState, q: jnp.ndarray,
     ``row_mask``: optional (B,) bool; rows where it is False advance
     NOTHING — lln state, tails and ``pos`` keep their old values (their
     outputs are garbage and must be discarded).  Requires per-row ``pos``.
-    ``backend``: explicit registry backend (``auto``/``pallas`` route
-    through ``kernels/ops.py``; ``scan``/``ref`` run the jnp twin below);
-    None derives it from the legacy ``use_kernel`` flag.
+    ``backend``: the registry backend (``auto``/``pallas`` route through
+    ``kernels/ops.py``; ``scan``/``ref`` run the jnp twin below).
     ``commit_len``: optional per-row (B,) int32 in [0, T] — speculative
     partial commit: all T positions are scored, but only the accepted
     prefix folds into the LLN state, the diag tail and ``pos``
@@ -683,8 +566,6 @@ def decode_lln_chunk(state: LLNDecodeState, q: jnp.ndarray,
     that the block mask already hides, so nothing here reads it.
     """
     b, t, h, _ = q.shape
-    if backend is None:
-        backend = "auto" if use_kernel else "ref"
     if backend not in ("scan", "ref"):
         from repro.kernels import ops as kops
         lln_out, lln_state = kops.lln_decode_chunk(state.lln, q, k_new,
@@ -699,15 +580,15 @@ def decode_lln_chunk(state: LLNDecodeState, q: jnp.ndarray,
         if beta_h.ndim and beta_h.shape[-1] == g and g != h:
             beta_h = jnp.repeat(beta_h, h // g, axis=-1)
         lln_out, lln_state = lln_mod.decode_chunk(
-            state.lln, q, _repeat_kv(k_new, h), _repeat_kv(v_new, h),
+            state.lln, q, repeat_kv(k_new, h), repeat_kv(v_new, h),
             alpha, beta_h, row_mask=row_mask, commit_len=commit_len,
             renorm=renorm)
 
     # --- rolling tail update, vectorized (``_roll_tail``).
     block = state.tail_k.shape[1]
     gt = state.tail_k.shape[2]          # tail head count (G, or H for seed)
-    k_t = _repeat_kv(k_new, gt) if k_new.shape[2] != gt else k_new
-    v_t = _repeat_kv(v_new, gt) if v_new.shape[2] != gt else v_new
+    k_t = repeat_kv(k_new, gt) if k_new.shape[2] != gt else k_new
+    v_t = repeat_kv(v_new, gt) if v_new.shape[2] != gt else v_new
     pos = state.pos
     posb = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))    # (B,)
     cl = _committed(b, t, row_mask, commit_len)
@@ -768,10 +649,9 @@ def commit_softmax(cache: KVCache, k_new: jnp.ndarray, v_new: jnp.ndarray,
 
 def commit_lln_chunk(state: LLNDecodeState, k_new: jnp.ndarray,
                      v_new: jnp.ndarray, beta: jnp.ndarray,
-                     *, impl: str = "lln_diag",
+                     *, impl: str = "lln_diag", backend: str,
                      commit_len: jnp.ndarray,
                      row_mask: Optional[jnp.ndarray] = None,
-                     backend: Optional[str] = None,
                      renorm: Optional[float] = None) -> LLNDecodeState:
     """Commit half of :func:`decode_lln_chunk` — fold the accepted prefix
     of a previously scored chunk into the LLN state, the diag tail and
@@ -784,8 +664,6 @@ def commit_lln_chunk(state: LLNDecodeState, k_new: jnp.ndarray,
     per-row ``pos``.
     """
     b, t = k_new.shape[0], k_new.shape[1]
-    if backend is None:
-        backend = "auto"
     if backend not in ("scan", "ref"):
         from repro.kernels import ops as kops
         lln_state = kops.lln_commit_chunk(state.lln, k_new, v_new, beta,
@@ -800,15 +678,15 @@ def commit_lln_chunk(state: LLNDecodeState, k_new: jnp.ndarray,
         if beta_h.ndim and beta_h.shape[-1] == g and g != h:
             beta_h = jnp.repeat(beta_h, h // g, axis=-1)
         lln_state = lln_mod.commit_chunk(
-            state.lln, _repeat_kv(k_new, h), _repeat_kv(v_new, h), beta_h,
+            state.lln, repeat_kv(k_new, h), repeat_kv(v_new, h), beta_h,
             row_mask=row_mask, commit_len=commit_len, renorm=renorm)
 
     # Rolling diag-tail update — same per-slot last-committed-writer gather
     # as decode_lln_chunk.
     block = state.tail_k.shape[1]
     gt = state.tail_k.shape[2]
-    k_t = _repeat_kv(k_new, gt) if k_new.shape[2] != gt else k_new
-    v_t = _repeat_kv(v_new, gt) if v_new.shape[2] != gt else v_new
+    k_t = repeat_kv(k_new, gt) if k_new.shape[2] != gt else k_new
+    v_t = repeat_kv(v_new, gt) if v_new.shape[2] != gt else v_new
     posb = jnp.broadcast_to(jnp.asarray(state.pos, jnp.int32), (b,))
     cl = lln_mod.commit_lengths(commit_len, row_mask, t)
     with jax.named_scope("diag_tail"):
@@ -816,19 +694,3 @@ def commit_lln_chunk(state: LLNDecodeState, k_new: jnp.ndarray,
                                     jnp.arange(block))
     return LLNDecodeState(lln=lln_state, tail_k=tail_k, tail_v=tail_v,
                           pos=posb + cl)
-
-
-def decode_lln(state: LLNDecodeState, q: jnp.ndarray, k_new: jnp.ndarray,
-               v_new: jnp.ndarray, alpha: jnp.ndarray, beta: jnp.ndarray,
-               *, impl: str = "lln_diag") -> tuple[jnp.ndarray, LLNDecodeState]:
-    """One-token LLN(+Diag) decode (T=1 :func:`decode_lln_chunk`).
-
-    .. deprecated:: use :meth:`repro.core.engine.AttentionEngine.decode`
-       (or :func:`decode_lln_chunk` directly) — chunked decode subsumes the
-       single-token case.
-    """
-    from repro.kernels.registry import warn_deprecated
-    warn_deprecated("repro.core.attention.decode_lln",
-                    "AttentionEngine.decode / decode_lln_chunk")
-    return decode_lln_chunk(state, q, k_new, v_new, alpha, beta, impl=impl,
-                            use_kernel=False)

@@ -1,7 +1,7 @@
 """The unified AttentionEngine: one spec, one state pytree, one lifecycle.
 
 Every attention path in this repo — training forward, prefill, chunked
-decode, continuous batching, MLA — now runs through this module:
+decode, continuous batching, MLA — runs through this module:
 
 * :class:`AttentionState` is the ONE decode-state pytree.  It carries the
   softmax KV cache (``k``/``v``/``len``), the LLN O(d^2) state
@@ -13,13 +13,10 @@ decode, continuous batching, MLA — now runs through this module:
   row agrees; there is no separate scalar cache layout any more.
 * :class:`AttentionEngine` binds an :class:`~repro.kernels.registry.AttnSpec`
   to one layer's head geometry and exposes the lifecycle
-  ``init_state -> prefill -> decode* -> evict``.  Backend selection
-  (pallas / scan twin / jnp ref) is owned by ``kernels/registry.py``.
-
-The legacy entry points (``attn_prefill``/``attn_decode``/
-``attn_cache_init``/``mla_decode``/…) survive as thin shims delegating
-here — see ``models/attention_block.py`` and ``docs/api.md`` for the
-old→new migration table.
+  ``init_state -> prefill -> decode* -> evict``, and
+  :meth:`AttentionEngine.attention`, the one full-sequence (training /
+  scoring) entry point.  Backend selection (pallas / scan twin / jnp ref)
+  is owned by ``kernels/registry.py:resolve``.
 """
 from __future__ import annotations
 
@@ -31,9 +28,11 @@ import jax.numpy as jnp
 
 from . import attention as ca
 from . import health as health_mod
+from . import loglinear
 from . import moment_matching as mm
-from .attention import KVCache, LLNDecodeState, batch_alpha_beta
-from .lln import LLNState, commit_lengths
+from .attention import KVCache, LLNDecodeState, batch_alpha_beta, repeat_kv
+from .diag import block_diag_attn
+from .lln import LLNState, commit_lengths, lln_bidir, lln_causal
 from .loglinear import LogLinState
 from repro.distributed import sharding as shd
 from repro.kernels import registry as kreg
@@ -105,9 +104,17 @@ _STATE_FIELDS = tuple(f.name for f in dataclasses.fields(AttentionState))
 #: Logical axes of the LLN and log_linear ``AttentionState`` leaves and of
 #: the other arrays the engine hands ``distributed/sharding.py:per_device``
 #: (q/k/v/out, per-row masks), by name.  A leaf missing here fails loudly
-#: there.  Softmax states never go there: XLA partitions that path.
+#: there.  Softmax states never go there: XLA partitions that path.  The
+#: calibrations alpha/beta keep heads last ((H|G,) or per-row (B, H|G));
+#: query and kv heads share the name "heads", so they split together or
+#: not at all.
 STATE_AXES = {
-    **ca.ATTN_AXES,
+    "q": ("act_batch", None, "heads", None),
+    "k": ("act_batch", None, "heads", None),
+    "v": ("act_batch", None, "heads", None),
+    "out": ("act_batch", None, "heads", None),
+    "alpha": ("act_batch", "heads"),
+    "beta": ("act_batch", "heads"),
     "pos": ("act_batch",),
     "row_mask": ("act_batch",), "commit_len": ("act_batch",),
     "s": ("act_batch", "heads", None, None),
@@ -257,9 +264,17 @@ class AttentionEngine:
 
     def attention(self, q, k, v, *, mask=None, alpha=None, beta=None,
                   prefix_len: int = 0):
-        """Stateless full-sequence attention (training / scoring).
-        q: (B,N,H,D); k/v: (B,N,G,D[v]).  Softmax ``backend='ref'`` is the
-        naive quadratic; other softmax backends run flash."""
+        """Stateless full-sequence attention (training / scoring) — the one
+        full-sequence entry point.  q: (B,N,H,D); k/v: (B,N,G,D[v]).
+
+        Softmax runs the naive quadratic under ``backend='ref'`` and flash
+        otherwise.  LLN impls resolve ``spec.backend`` with the spec's
+        ``train_twin`` (``kernels/registry.py:resolve``): ``scan`` runs the
+        model-layout jnp math (repeated KV; the only form that takes a
+        padding ``mask``); any other kind runs the kernels
+        (``registry.attention``, per device under a mesh).  ``alpha``/
+        ``beta`` default to this spec's calibration at length N.
+        """
         spec = self.spec
         if spec.impl == "softmax":
             if spec.backend == "ref":
@@ -268,26 +283,58 @@ class AttentionEngine:
             return ca.flash_softmax(q, k, v, causal=spec.causal,
                                     chunk=min(spec.softmax_chunk,
                                               k.shape[1]),
-                                    mask=mask, prefix_len=prefix_len)
+                                    mask=mask, prefix_len=prefix_len,
+                                    constrain=shd.constrain)
+        h, g = q.shape[2], k.shape[2]
         if alpha is None or beta is None:
-            # Calibrate HERE so spec.calibration="per_row" applies to the
-            # full-sequence forward too (multi_head_attention's internal
-            # batch_alpha_beta only knows the batch-pooled mode).
             alpha, beta = self.calibrate(q, k, n=q.shape[1])
             gain = self._length_gain(q.shape[1])
             if gain is not None:
                 alpha = jnp.asarray(alpha, jnp.float32) * gain
                 beta = jnp.asarray(beta, jnp.float32) * gain
-        acfg = ca.AttnConfig(
-            impl=spec.impl, causal=spec.causal, diag_block=spec.diag_block,
-            lln_chunk=spec.lln_chunk, softmax_chunk=spec.softmax_chunk,
-            use_kernel=spec.backend != "ref",
-            backend=None if spec.backend == "auto" else spec.backend,
-            fixed_ab=spec.fixed_ab, mm_a=spec.mm_a, mm_b=spec.mm_b,
-            num_scales=spec.num_scales, scale_decay=spec.scale_decay)
-        return ca.multi_head_attention(q, k, v, acfg, mask=mask,
-                                       alpha=alpha, beta=beta,
-                                       prefix_len=prefix_len)
+        alpha = jnp.asarray(alpha, jnp.float32)
+        beta = jnp.asarray(beta, jnp.float32)
+        if alpha.ndim == 0:
+            alpha = jnp.broadcast_to(alpha, (h,))
+        if beta.ndim == 0:
+            beta = jnp.broadcast_to(beta, (g,))
+        # Heads live on the LAST axis ((H,) or per-row (B, H)) — pool a
+        # per-q-head beta to the kv groups either way.
+        if beta.shape[-1] == h and g != h:
+            beta = beta.reshape(beta.shape[:-1] + (g, h // g)).mean(axis=-1)
+
+        if kreg.resolve(spec.backend, cpu_twin=spec.train_twin).kind \
+                != "scan":
+            # Kernels handle GQA via BlockSpec index maps — no KV repeat.
+            if mask is not None:
+                raise ValueError("the attention kernels take no padding "
+                                 "mask; use backend 'scan' for masked "
+                                 "attention")
+            return shd.per_device(
+                lambda **a: {"out": kreg.attention(spec, **a)},
+                STATE_AXES.__getitem__, q=q, k=k, v=v, alpha=alpha,
+                beta=beta)["out"]
+
+        kv_k = repeat_kv(k, h)
+        kv_v = repeat_kv(v, h)
+        beta_h = jnp.repeat(beta, h // g, axis=-1) if g != h else beta
+        if spec.impl == "log_linear":
+            out, _ = loglinear.prefill(q, kv_k, kv_v, alpha, beta_h,
+                                       granule=spec.lln_chunk,
+                                       num_scales=spec.num_scales,
+                                       scale_decay=spec.scale_decay)
+            return out.astype(v.dtype)
+        if spec.causal:
+            lln_out = lln_causal(q, kv_k, kv_v, alpha, beta_h,
+                                 chunk=spec.lln_chunk)
+        else:
+            lln_out = lln_bidir(q, kv_k, kv_v, alpha, beta_h, mask=mask)
+        if spec.impl == "lln":
+            return lln_out
+        diag_out = block_diag_attn(q, kv_k, kv_v, block=spec.diag_block,
+                                   causal=spec.causal, mask=mask)
+        return (0.5 * (lln_out.astype(jnp.float32)
+                       + diag_out.astype(jnp.float32))).astype(v.dtype)
 
     def prefill(self, q, k, v, *, max_len: int, prefix_len: int = 0,
                 alpha=None, beta=None):
@@ -329,7 +376,8 @@ class AttentionEngine:
             else:
                 out = ca.flash_softmax(q, k, v, causal=spec.causal,
                                        chunk=min(spec.softmax_chunk, n),
-                                       prefix_len=prefix_len)
+                                       prefix_len=prefix_len,
+                                       constrain=shd.constrain)
             pad = ((0, 0), (0, max_len - n), (0, 0), (0, 0))
             return out, AttentionState(
                 k=jnp.pad(k.astype(self.state_dtype), pad),
@@ -424,7 +472,7 @@ class AttentionEngine:
             out, kv2 = ca.decode_softmax(
                 KVCache(k=state.k, v=state.v, length=state.len),
                 q, k, v, chunk=spec.softmax_chunk, row_mask=row_mask,
-                commit_len=commit_len)
+                commit_len=commit_len, constrain=shd.constrain)
             return out, state.replace(k=kv2.k, v=kv2.v, len=kv2.length)
         # beta(n) schedule: each row's effective calibration keys off its
         # OWN depth (state.pos) — a 400k-context row and a 2k row in the

@@ -6,7 +6,9 @@ Responsibilities:
   (global per batch*head constants — exactly invariant, see core/lln.py);
 * GQA ratio r = H // G threaded to the kernels' BlockSpec index maps
   (repeated KV is never materialized);
-* interpret-mode dispatch (CPU container -> interpret=True; TPU -> compiled);
+* backend dispatch: each op's CPU twin and ragged fallback are named here;
+  ``kernels/registry.py:resolve`` picks the implementation (CPU ->
+  interpreted kernel or twin; TPU -> compiled kernel);
 * custom_vjp: Pallas forward AND a fused analytic backward (lln_backward.py
   / block_diag.py).  The forward saves the pre-scaled (qs, ks), the kernel-
   layout v, the output and the per-row normalizer ``den`` as residuals, so
@@ -51,29 +53,21 @@ from .lln_backward import (lln_bidir_bwd_pallas, lln_bidir_bwd_scan,
 from .ssd import ssd_pallas
 
 
-def _interpret(flag: Optional[bool]) -> bool:
-    if flag is not None:
-        return flag
-    return jax.default_backend() == "cpu"
-
-
-def _dispatch(backend: str, interpret: Optional[bool], *, ragged: bool,
-              cpu_twin: str, ragged_kind: str = "ref") -> tuple[str, bool]:
-    """Resolve (kind, interpret) for one op call.
-
-    ``backend='auto'`` reproduces the historical per-op dispatch (honouring
-    the legacy ``interpret=`` override): ragged lengths fall back to
-    ``ragged_kind``, interpret mode runs ``cpu_twin``, compiled backends run
-    the Pallas kernel.  Explicit backends go through
-    :func:`repro.kernels.registry.resolve`.
+def _dispatch(backend: str, *, ragged: bool, cpu_twin: str,
+              ragged_kind: str = "ref") -> tuple[str, bool]:
+    """Resolve (kind, interpret) for one op call: under ``auto`` a ragged
+    length falls back to the op's ``ragged_kind``; everything else is
+    :func:`repro.kernels.registry.resolve`'s, with the op's ``cpu_twin``.
     """
-    if backend == "auto":
-        if ragged:
-            return ragged_kind, False
-        ip = _interpret(interpret)
-        return (cpu_twin if ip else "pallas"), ip
+    if backend == "auto" and ragged:
+        return ragged_kind, False
     res = registry.resolve(backend, ragged=ragged, cpu_twin=cpu_twin)
     return res.kind, res.interpret
+
+
+def _interpreted() -> bool:
+    """Whether a Pallas kernel runs interpreted here (on the CPU)."""
+    return registry.resolve("pallas").interpret
 
 
 # Interpret-mode Pallas pays a full block copy per grid step, so the fused
@@ -83,8 +77,8 @@ def _dispatch(backend: str, interpret: Optional[bool], *, ragged: bool,
 FORCE_KERNEL_BWD = False
 
 
-def _kernel_bwd(interpret: Optional[bool]) -> bool:
-    return FORCE_KERNEL_BWD or not _interpret(interpret)
+def _kernel_bwd() -> bool:
+    return FORCE_KERNEL_BWD or not _interpreted()
 
 
 def _to_kernel(t: jnp.ndarray) -> jnp.ndarray:
@@ -143,10 +137,10 @@ def _zero_ab(alpha, beta):
 # LLN attention.
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
 def lln_attention(q, k, v, alpha, beta, causal: bool = True,
-                  chunk: int = 256, interpret: Optional[bool] = None,
-                  pallas_bwd: bool = True, backend: str = "auto"):
+                  chunk: int = 256, pallas_bwd: bool = True,
+                  backend: str = "auto"):
     """LLN attention (paper eq. 8) via Pallas — the training entry point.
 
     Args:
@@ -159,8 +153,7 @@ def lln_attention(q, k, v, alpha, beta, causal: bool = True,
       chunk: block size of the causal scan; ``N % chunk != 0`` falls back
         to the jnp reference (``core.lln``) — same math, ragged-safe.
 
-    Backend: ``backend='auto'`` (the default) keeps the historical
-    dispatch — compiled (TPU) runs the Pallas forward and, under
+    Backend: ``backend='auto'`` (the default): compiled (TPU) runs the Pallas forward and, under
     ``custom_vjp``, the fused Pallas backward (kernels/lln_backward.py);
     interpret mode (CPU container) runs the forward kernel interpreted and
     the backward's chunked ``lax.scan`` twins.  Explicit
@@ -170,16 +163,14 @@ def lln_attention(q, k, v, alpha, beta, causal: bool = True,
     ``pallas_bwd=False`` forces the chunked-jnp reference backward (the
     pre-fused behaviour) — kept for benchmarking and debugging.
     """
-    return _lln_fwd_impl(q, k, v, alpha, beta, causal, chunk, interpret,
-                         backend)
+    return _lln_fwd_impl(q, k, v, alpha, beta, causal, chunk, backend)
 
 
-def _lln_fwd_impl(q, k, v, alpha, beta, causal, chunk, interpret,
-                  backend="auto"):
+def _lln_fwd_impl(q, k, v, alpha, beta, causal, chunk, backend="auto"):
     b, n, h, _ = q.shape
     g = k.shape[2]
-    # The historical ragged fallback IS the core chunked scan ("scan").
-    kind, ip = _dispatch(backend, interpret, ragged=bool(n % chunk),
+    # The ragged fallback is the core chunked scan ("scan").
+    kind, ip = _dispatch(backend, ragged=bool(n % chunk),
                          cpu_twin="pallas", ragged_kind="scan")
     if kind == "scan":
         return _lln_ref(q, k, v, alpha, beta, causal, chunk)
@@ -226,19 +217,17 @@ def _lln_quad_ref(q, k, v, alpha, beta, causal):
     return _from_kernel(fn(qs, ks, vk, r=h // g), b).astype(v.dtype)
 
 
-def _lln_vjp_fwd(q, k, v, alpha, beta, causal, chunk, interpret, pallas_bwd,
+def _lln_vjp_fwd(q, k, v, alpha, beta, causal, chunk, pallas_bwd,
                  backend="auto"):
     n, h = q.shape[1], q.shape[2]
     g = k.shape[2]
     if n % chunk or not pallas_bwd or backend in ("scan", "ref"):
-        out = _lln_fwd_impl(q, k, v, alpha, beta, causal, chunk, interpret,
-                            backend)
+        out = _lln_fwd_impl(q, k, v, alpha, beta, causal, chunk, backend)
         return out, {"ref": (q, k, v, alpha, beta)}
     b = q.shape[0]
     qs, ks, alpha_b, beta_b = _scaled_stabilized(q, k, alpha, beta)
     vk = _to_kernel(v)
-    ip = True if backend == "pallas" and registry.on_cpu() \
-        else _interpret(interpret)
+    ip = _interpreted()
     if causal:
         out_k, den = lln_causal_pallas(qs, ks, vk, r=h // g, blk=chunk,
                                        interpret=ip, return_res=True)
@@ -255,7 +244,7 @@ def _lln_vjp_fwd(q, k, v, alpha, beta, causal, chunk, interpret, pallas_bwd,
     return _from_kernel(out_k, b), res
 
 
-def _lln_vjp_bwd(causal, chunk, interpret, pallas_bwd, backend, res, g_out):
+def _lln_vjp_bwd(causal, chunk, pallas_bwd, backend, res, g_out):
     if "ref" in res:
         q, k, v, alpha, beta = res["ref"]
         _, vjp = jax.vjp(
@@ -268,9 +257,9 @@ def _lln_vjp_bwd(causal, chunk, interpret, pallas_bwd, backend, res, g_out):
     b = g_out.shape[0]
     r = (qs.shape[0] // b) // (ks.shape[0] // b)
     gk = _to_kernel(g_out)
-    ip = _interpret(interpret)
+    ip = _interpreted()
     if causal:
-        if _kernel_bwd(interpret):
+        if _kernel_bwd():
             dqs, dks, dvk = lln_causal_bwd_pallas(qs, ks, vk, gk, out_k,
                                                   den, r=r, blk=chunk,
                                                   interpret=ip)
@@ -279,7 +268,7 @@ def _lln_vjp_bwd(causal, chunk, interpret, pallas_bwd, backend, res, g_out):
                                                 r=r, blk=chunk)
     else:
         with jax.named_scope("lln_bidir_bwd"):
-            if _kernel_bwd(interpret):
+            if _kernel_bwd():
                 dqs, dks, dvk = lln_bidir_bwd_pallas(
                     qs, ks, vk, gk, out_k, den, s, z, r=r, blk=chunk,
                     interpret=ip)
@@ -300,13 +289,13 @@ lln_attention.defvjp(_lln_vjp_fwd, _lln_vjp_bwd)
 # ---------------------------------------------------------------------------
 # Serving entry points: state-emitting prefill + chunked multi-token decode.
 # Inference-only (no custom_vjp); same three-way dispatch as the training
-# forward: Pallas on compiled backends, chunked lax.scan twin under
-# interpret mode (CPU container), jnp reference for ragged lengths.
+# forward: Pallas on compiled backends, chunked lax.scan twin on the CPU,
+# jnp reference for ragged lengths.
 # ---------------------------------------------------------------------------
 
 @jax.named_scope("lln_prefill")
 def lln_prefill(q, k, v, alpha, beta, chunk: int = 256,
-                interpret: Optional[bool] = None, backend: str = "auto"):
+                backend: str = "auto"):
     """Causal LLN prefill emitting outputs AND the decode state in one pass.
 
     q: (B,N,H,D); k/v: (B,N,G,D[v]) — GQA via the kernels' ``h // r`` index
@@ -315,14 +304,13 @@ def lln_prefill(q, k, v, alpha, beta, chunk: int = 256,
     exactly the ``core.lln.LLNState`` layout the decode cache stores (state
     per query head: GQA groups share values, matching the H-head cache).
 
-    ``backend``: ``auto`` (historical dispatch — Pallas compiled, scan twin
-    on CPU, jnp reference for ragged lengths) | ``pallas`` | ``scan`` |
+    ``backend``: ``auto`` (Pallas compiled, scan twin on CPU, jnp
+    reference for ragged lengths) | ``pallas`` | ``scan`` |
     ``ref`` (the seed two-pass jnp path, ``core/lln.py:prefill``).
     """
     b, n, h, _ = q.shape
     g = k.shape[2]
-    kind, ip = _dispatch(backend, interpret, ragged=bool(n % chunk),
-                         cpu_twin="scan")
+    kind, ip = _dispatch(backend, ragged=bool(n % chunk), cpu_twin="scan")
     if kind == "ref":
         return _lln_prefill_ref(q, k, v, alpha, beta, chunk)
     qs, ks, _, _, c_k = _scaled_stabilized(q, k, alpha, beta, with_const=True)
@@ -388,17 +376,16 @@ def _lln_prefill_scan(qs, ks, vk, *, r: int, blk: int):
 
 @jax.named_scope("block_diag")
 def block_diag_fwd(q, k, v, block: int = 256, causal: bool = True,
-                   interpret: Optional[bool] = None, backend: str = "auto"):
+                   backend: str = "auto"):
     """Inference-only block-diagonal softmax with the serving dispatch:
     Pallas kernel on compiled backends, a GQA-aware grouped-einsum twin
-    under interpret mode (no repeated KV either way), jnp reference for
+    on the CPU (no repeated KV either way), jnp reference for
     ragged lengths; explicit ``backend=pallas|scan|ref`` forces one path
     (kernels/registry.py).  Training keeps the ``block_diag_attention``
     custom_vjp entry; this is the prefill path of the §4.2 hybrid."""
     b, n, h, _ = q.shape
     g = k.shape[2]
-    kind, ip = _dispatch(backend, interpret, ragged=bool(n % block),
-                         cpu_twin="scan")
+    kind, ip = _dispatch(backend, ragged=bool(n % block), cpu_twin="scan")
     if kind == "ref":
         return _diag_ref(q, k, v, block, causal)
     if kind == "scan":
@@ -431,7 +418,6 @@ def _block_diag_twin(q, k, v, block, causal):
 
 @jax.named_scope("lln_decode")
 def lln_decode_chunk(state, q, k, v, alpha, beta,
-                     interpret: Optional[bool] = None,
                      row_mask: Optional[jnp.ndarray] = None,
                      backend: str = "auto",
                      commit_len: Optional[jnp.ndarray] = None,
@@ -471,8 +457,7 @@ def lln_decode_chunk(state, q, k, v, alpha, beta,
     Backend dispatch: one Pallas kernel launch (grid over B*H, T padded to
     a sublane multiple with NEG_INF keys so padded Phi(k) = 0) after a
     single group-level max-rescale of the carried state on compiled
-    backends; the jnp twin ``core.lln.decode_chunk`` under interpret mode
-    (the CPU container).  Both equal T sequential ``decode_step`` calls.
+    backends; the jnp twin ``core.lln.decode_chunk`` on the CPU.  Both equal T sequential ``decode_step`` calls.
     ``backend='pallas'`` forces the kernel (interpreted on CPU);
     ``'scan'``/``'ref'`` force the jnp twin (they coincide for decode —
     the twin IS the reference).
@@ -481,11 +466,11 @@ def lln_decode_chunk(state, q, k, v, alpha, beta,
 
     b, t, h, d = q.shape
     g = k.shape[2]
-    kind, ip = _dispatch(backend, interpret, ragged=False, cpu_twin="ref")
+    kind, ip = _dispatch(backend, ragged=False, cpu_twin="ref")
     # Per-G-head beta shared by BOTH dispatch branches: an (H,)/(B,H) beta
     # that is not a group-uniform repeat is group-mean-pooled (the
-    # batch_alpha_beta convention, cf. multi_head_attention) — identically
-    # on every backend.
+    # batch_alpha_beta convention, cf. AttentionEngine.attention) —
+    # identically on every backend.
     beta_b = jnp.asarray(beta, jnp.float32)
     if beta_b.ndim and beta_b.shape[-1] == h and g != h:
         beta_b = beta_b.reshape(beta_b.shape[:-1] + (g, h // g)).mean(axis=-1)
@@ -581,7 +566,6 @@ def lln_decode_chunk(state, q, k, v, alpha, beta,
 
 
 def lln_commit_chunk(state, k, v, beta,
-                     interpret: Optional[bool] = None,
                      row_mask: Optional[jnp.ndarray] = None,
                      backend: str = "auto",
                      commit_len: Optional[jnp.ndarray] = None,
@@ -602,7 +586,7 @@ def lln_commit_chunk(state, k, v, beta,
 
     b, t, g, _ = k.shape
     h = state.s.shape[1]
-    kind, _ = _dispatch(backend, interpret, ragged=False, cpu_twin="ref")
+    kind, _ = _dispatch(backend, ragged=False, cpu_twin="ref")
     beta_b = jnp.asarray(beta, jnp.float32)
     if beta_b.ndim and beta_b.shape[-1] == h and g != h:
         beta_b = beta_b.reshape(beta_b.shape[:-1] + (g, h // g)).mean(axis=-1)
@@ -677,7 +661,6 @@ def _loglin_repeat(q, k, v, beta):
 def loglin_attention(q, k, v, alpha, beta, causal: bool = True,
                      chunk: int = 256, num_scales: int = 4,
                      scale_decay: float = 0.5,
-                     interpret: Optional[bool] = None,
                      backend: str = "auto"):
     """Full-sequence log-linear LLN attention (causal-only).
 
@@ -688,16 +671,14 @@ def loglin_attention(q, k, v, alpha, beta, causal: bool = True,
     or ``scale_decay=1`` reduce exactly to plain :func:`lln_attention`.
 
     Dispatch: Pallas kernel (``kernels/loglinear.py``) on compiled
-    backends; the core granule-``lax.scan`` under ``scan`` / interpret
-    mode; the quadratic jnp oracle under ``ref`` (and for ragged
+    backends; the core granule-``lax.scan`` under ``scan`` / on the CPU; the quadratic jnp oracle under ``ref`` (and for ragged
     lengths).
     """
     if not causal:
         raise ValueError("log_linear attention is causal-only")
     b, n, h, _ = q.shape
     g = k.shape[2]
-    kind, ip = _dispatch(backend, interpret, ragged=bool(n % chunk),
-                         cpu_twin="scan")
+    kind, ip = _dispatch(backend, ragged=bool(n % chunk), cpu_twin="scan")
     if kind in ("ref", "scan"):
         kf, vf, beta_h = _loglin_repeat(q, k, v, beta)
         if kind == "ref":
@@ -719,7 +700,6 @@ def loglin_attention(q, k, v, alpha, beta, causal: bool = True,
 
 def loglin_prefill(q, k, v, alpha, beta, chunk: int = 256,
                    num_scales: int = 4, scale_decay: float = 0.5,
-                   interpret: Optional[bool] = None,
                    backend: str = "auto"):
     """Causal log-linear prefill emitting outputs AND the multi-scale
     decode state in one pass.
@@ -735,8 +715,7 @@ def loglin_prefill(q, k, v, alpha, beta, chunk: int = 256,
     b, n, h, d = q.shape
     g, dv = k.shape[2], v.shape[-1]
     ls = num_scales
-    kind, ip = _dispatch(backend, interpret, ragged=bool(n % chunk),
-                         cpu_twin="scan")
+    kind, ip = _dispatch(backend, ragged=bool(n % chunk), cpu_twin="scan")
     if kind == "ref":
         kf, vf, beta_h = _loglin_repeat(q, k, v, beta)
         out, st = core_loglin.prefill(q, kf, vf, alpha, beta_h,
@@ -831,7 +810,6 @@ def _loglin_prefill_scan(qs, ks, vk, *, r: int, blk: int, num_scales: int,
 def loglin_decode_chunk(state, q, k, v, alpha, beta, *,
                         pos, granule: int, num_scales: int,
                         scale_decay: float,
-                        interpret: Optional[bool] = None,
                         row_mask: Optional[jnp.ndarray] = None,
                         backend: str = "auto",
                         commit_len: Optional[jnp.ndarray] = None,
@@ -845,7 +823,7 @@ def loglin_decode_chunk(state, q, k, v, alpha, beta, *,
     determines each row's bucket layout — and the Fenwick carry-merge
     when the chunk crosses a granule boundary.
 
-    Backend dispatch: ``scan``/``ref``/interpret run the jnp core twin
+    Backend dispatch: ``scan``/``ref``/the CPU run the jnp core twin
     (the twin IS the reference, as for lln decode).  The ``pallas`` kind
     runs the committed fold as the same jnp ``core.loglinear._advance``
     (bitwise-identical state on every backend) and scores with TWO
@@ -860,7 +838,7 @@ def loglin_decode_chunk(state, q, k, v, alpha, beta, *,
     """
     b, t, h, d = q.shape
     g = k.shape[2]
-    kind, ip = _dispatch(backend, interpret, ragged=False, cpu_twin="ref")
+    kind, ip = _dispatch(backend, ragged=False, cpu_twin="ref")
     beta_b = jnp.asarray(beta, jnp.float32)
     if beta_b.ndim and beta_b.shape[-1] == h and g != h:
         beta_b = beta_b.reshape(beta_b.shape[:-1] + (g, h // g)).mean(axis=-1)
@@ -889,8 +867,7 @@ def loglin_decode_chunk(state, q, k, v, alpha, beta, *,
             o, state = loglin_decode_chunk(
                 state, q[:, sl], k[:, sl], v[:, sl], alpha, beta_b,
                 pos=posv + done, granule=granule, num_scales=num_scales,
-                scale_decay=scale_decay, interpret=interpret,
-                row_mask=row_mask, backend=backend, renorm=renorm)
+                scale_decay=scale_decay, row_mask=row_mask, backend=backend, renorm=renorm)
             step = sl.stop - sl.start
             adv = jnp.full((b,), step, jnp.int32)
             done = done + (jnp.where(row_mask, adv, 0)
@@ -968,7 +945,6 @@ def loglin_decode_chunk(state, q, k, v, alpha, beta, *,
 
 def loglin_commit_chunk(state, k, v, beta, *, pos, granule: int,
                         num_scales: int,
-                        interpret: Optional[bool] = None,
                         row_mask: Optional[jnp.ndarray] = None,
                         backend: str = "auto",
                         commit_len: Optional[jnp.ndarray] = None,
@@ -985,7 +961,7 @@ def loglin_commit_chunk(state, k, v, beta, *, pos, granule: int,
     t = k.shape[1]
     g = k.shape[2]
     h = state.s.shape[1]
-    _dispatch(backend, interpret, ragged=False, cpu_twin="ref")
+    _dispatch(backend, ragged=False, cpu_twin="ref")
     beta_b = jnp.asarray(beta, jnp.float32)
     if beta_b.ndim and beta_b.shape[-1] == h and g != h:
         beta_b = beta_b.reshape(beta_b.shape[:-1] + (g, h // g)).mean(axis=-1)
@@ -1004,28 +980,26 @@ def loglin_commit_chunk(state, k, v, beta, *, pos, granule: int,
 # Block-diagonal softmax attention.
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def block_diag_attention(q, k, v, block: int = 256, causal: bool = False,
-                         interpret: Optional[bool] = None,
                          pallas_bwd: bool = True, backend: str = "auto"):
     """Block-diagonal softmax attention via Pallas (§4.2 diag component).
 
     q: (B, N, H, D); k/v: (B, N, G, D[v]), GQA via the ``h // r`` index map.
     Each ``block``-sized diagonal block attends only within itself
     (causally masked when ``causal``).  Training entry point (custom_vjp:
-    Pallas backward on compiled backends, scan twin under interpret mode,
+    Pallas backward on compiled backends, scan twin on the CPU,
     jnp reference when ``N % block`` or ``pallas_bwd=False``); returns
     (B, N, H, Dv) in ``v.dtype``.  Inference prefill uses
     :func:`block_diag_fwd` instead.
     """
-    return _diag_fwd_impl(q, k, v, block, causal, interpret, backend)
+    return _diag_fwd_impl(q, k, v, block, causal, backend)
 
 
-def _diag_fwd_impl(q, k, v, block, causal, interpret, backend="auto"):
+def _diag_fwd_impl(q, k, v, block, causal, backend="auto"):
     b, n, h, _ = q.shape
     g = k.shape[2]
-    kind, ip = _dispatch(backend, interpret, ragged=bool(n % block),
-                         cpu_twin="pallas")
+    kind, ip = _dispatch(backend, ragged=bool(n % block), cpu_twin="pallas")
     if kind == "ref":
         return _diag_ref(q, k, v, block, causal)
     if kind == "scan":
@@ -1045,23 +1019,22 @@ def _diag_ref(q, k, v, block, causal):
     return core_diag(q, kf, vf, block=block, causal=causal).astype(v.dtype)
 
 
-def _diag_vjp_fwd(q, k, v, block, causal, interpret, pallas_bwd,
-                  backend="auto"):
+def _diag_vjp_fwd(q, k, v, block, causal, pallas_bwd, backend="auto"):
     n = q.shape[1]
     if n % block or not pallas_bwd or backend in ("scan", "ref"):
-        return (_diag_fwd_impl(q, k, v, block, causal, interpret, backend),
+        return (_diag_fwd_impl(q, k, v, block, causal, backend),
                 {"ref": (q, k, v)})
     qk, kk, vk = _to_kernel(q), _to_kernel(k), _to_kernel(v)
     with jax.named_scope("block_diag"):
         out = block_diag_pallas(qk, kk, vk, r=q.shape[2] // k.shape[2],
                                 blk=block, causal=causal,
-                                interpret=_interpret(interpret))
+                                interpret=_interpreted())
     res = {"pallas": (qk, kk, vk, _dtype_tag(q), _dtype_tag(k),
                       _dtype_tag(v))}
     return _from_kernel(out, q.shape[0]), res
 
 
-def _diag_vjp_bwd(block, causal, interpret, pallas_bwd, backend, res, g_out):
+def _diag_vjp_bwd(block, causal, pallas_bwd, backend, res, g_out):
     if "ref" in res:
         q, k, v = res["ref"]
         _, vjp = jax.vjp(lambda q_, k_, v_: _diag_ref(q_, k_, v_, block,
@@ -1071,10 +1044,10 @@ def _diag_vjp_bwd(block, causal, interpret, pallas_bwd, backend, res, g_out):
     b = g_out.shape[0]
     r = (qk.shape[0] // b) // (kk.shape[0] // b)
     with jax.named_scope("block_diag"):
-        if _kernel_bwd(interpret):
+        if _kernel_bwd():
             dq, dk, dv = block_diag_bwd_pallas(
                 qk, kk, vk, _to_kernel(g_out), r=r, blk=block,
-                causal=causal, interpret=_interpret(interpret))
+                causal=causal, interpret=_interpreted())
         else:
             dq, dk, dv = block_diag_bwd_scan(qk, kk, vk, _to_kernel(g_out),
                                              r=r, blk=block, causal=causal)
@@ -1090,10 +1063,10 @@ block_diag_attention.defvjp(_diag_vjp_fwd, _diag_vjp_bwd)
 # Fused LLN + Diag (causal): single-pass hybrid, shared block loads.
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
 def lln_diag_attention(q, k, v, alpha, beta, causal: bool = True,
-                       block: int = 256, interpret: Optional[bool] = None,
-                       pallas_bwd: bool = True, backend: str = "auto"):
+                       block: int = 256, pallas_bwd: bool = True,
+                       backend: str = "auto"):
     """The paper's §4.2 hybrid: 0.5 * (LLN + block-diag softmax).
 
     Shapes/dtypes/GQA semantics as :func:`lln_attention` (``block`` doubles
@@ -1101,20 +1074,18 @@ def lln_diag_attention(q, k, v, alpha, beta, causal: bool = True,
     components run as ONE fused Pallas kernel sharing block loads (fused
     backward likewise); bidirectional runs them as two kernels.  Fallbacks:
     jnp reference when ``N % block`` or ``pallas_bwd=False``; scan twins
-    under interpret mode for the backward.  ``backend='scan'`` forces the
+    on the CPU for the backward.  ``backend='scan'`` forces the
     core chunked-scan hybrid, ``'ref'`` the quadratic-oracle hybrid,
     ``'pallas'`` the fused kernel (interpreted on CPU).
     """
-    return _lln_diag_fwd_impl(q, k, v, alpha, beta, causal, block, interpret,
-                              backend)
+    return _lln_diag_fwd_impl(q, k, v, alpha, beta, causal, block, backend)
 
 
-def _lln_diag_fwd_impl(q, k, v, alpha, beta, causal, block, interpret,
-                       backend="auto"):
+def _lln_diag_fwd_impl(q, k, v, alpha, beta, causal, block, backend="auto"):
     b, n, h, _ = q.shape
     g = k.shape[2]
-    kind, ip_forced = _dispatch(backend, interpret, ragged=bool(n % block),
-                                cpu_twin="pallas", ragged_kind="scan")
+    kind, ip = _dispatch(backend, ragged=bool(n % block), cpu_twin="pallas",
+                         ragged_kind="scan")
     if kind == "scan":
         return _lln_diag_ref(q, k, v, alpha, beta, causal, block)
     if kind == "ref":
@@ -1126,7 +1097,6 @@ def _lln_diag_fwd_impl(q, k, v, alpha, beta, causal, block, interpret,
     # per call, and the LLN pre-scaling runs once for both components.
     qs, ks, _, _ = _scaled_stabilized(q, k, alpha, beta)
     vk = _to_kernel(v)
-    ip = ip_forced
     if causal:
         out = lln_diag_fused_pallas(qs, ks, _to_kernel(q), _to_kernel(k),
                                     vk, r=h // g, blk=block, causal=True,
@@ -1149,17 +1119,17 @@ def _lln_diag_ref(q, k, v, alpha, beta, causal, block):
             ).astype(v.dtype)
 
 
-def _lln_diag_vjp_fwd(q, k, v, alpha, beta, causal, block, interpret,
-                      pallas_bwd, backend="auto"):
+def _lln_diag_vjp_fwd(q, k, v, alpha, beta, causal, block, pallas_bwd,
+                      backend="auto"):
     b, n, h, _ = q.shape
     g = k.shape[2]
     if n % block or not pallas_bwd or backend in ("scan", "ref"):
         out = _lln_diag_fwd_impl(q, k, v, alpha, beta, causal, block,
-                                 interpret, backend)
+                                 backend)
         return out, {"ref": (q, k, v, alpha, beta)}
     qs, ks, alpha_b, beta_b = _scaled_stabilized(q, k, alpha, beta)
     qk, kk, vk = _to_kernel(q), _to_kernel(k), _to_kernel(v)
-    ip = _interpret(interpret)
+    ip = _interpreted()
     tags = (_dtype_tag(q), _dtype_tag(k), _dtype_tag(v),
             jnp.asarray(alpha, jnp.float32), jnp.asarray(beta, jnp.float32))
     if causal:
@@ -1181,8 +1151,7 @@ def _lln_diag_vjp_fwd(q, k, v, alpha, beta, causal, block, interpret,
     return _from_kernel(out, b).astype(v.dtype), res
 
 
-def _lln_diag_vjp_bwd(causal, block, interpret, pallas_bwd, backend, res,
-                      g_out):
+def _lln_diag_vjp_bwd(causal, block, pallas_bwd, backend, res, g_out):
     if "ref" in res:
         q, k, v, alpha, beta = res["ref"]
         _, vjp = jax.vjp(
@@ -1192,12 +1161,12 @@ def _lln_diag_vjp_bwd(causal, block, interpret, pallas_bwd, backend, res,
         return (dq, dk, dv) + _zero_ab(alpha, beta)
     b = g_out.shape[0]
     gk = _to_kernel(g_out)
-    ip = _interpret(interpret)
+    ip = _interpreted()
     if causal:
         (qs, ks, qk, kk, vk, out_k, den, alpha_b, beta_b,
          tq, tk, tv, alpha0, beta0) = res["pallas_fused"]
         r = (qs.shape[0] // b) // (ks.shape[0] // b)
-        if _kernel_bwd(interpret):
+        if _kernel_bwd():
             dqs, dqd, dks, dkd, dvk = lln_diag_fused_bwd_pallas(
                 qs, ks, qk, kk, vk, gk, out_k, den, r=r, blk=block,
                 interpret=ip)
@@ -1209,7 +1178,7 @@ def _lln_diag_vjp_bwd(causal, block, interpret, pallas_bwd, backend, res,
          tq, tk, tv, alpha0, beta0) = res["pallas_bidir"]
         r = (qs.shape[0] // b) // (ks.shape[0] // b)
         gh = 0.5 * gk.astype(jnp.float32)
-        kernel = _kernel_bwd(interpret)
+        kernel = _kernel_bwd()
         with jax.named_scope("lln_bidir_bwd"):
             if kernel:
                 dqs, dks, dvl = lln_bidir_bwd_pallas(
@@ -1242,16 +1211,15 @@ lln_diag_attention.defvjp(_lln_diag_vjp_fwd, _lln_diag_vjp_bwd)
 # Mamba2 SSD chunked scan.
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def ssd_scan(xbar, b_in, c_in, log_a, chunk: int = 256,
-             interpret: Optional[bool] = None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def ssd_scan(xbar, b_in, c_in, log_a, chunk: int = 256):
     """SSD via Pallas.  xbar: (B,L,H,P); b_in/c_in: (B,L,G,S);
     log_a: (B,L,H).  Returns y: (B,L,H,P) (no final state — training path;
     prefill uses the jnp ssd_chunked which also returns the state)."""
-    return _ssd_fwd_impl(xbar, b_in, c_in, log_a, chunk, interpret)
+    return _ssd_fwd_impl(xbar, b_in, c_in, log_a, chunk)
 
 
-def _ssd_fwd_impl(xbar, b_in, c_in, log_a, chunk, interpret):
+def _ssd_fwd_impl(xbar, b_in, c_in, log_a, chunk):
     b, l, h, p_dim = xbar.shape
     g = b_in.shape[2]
     if l % chunk:
@@ -1261,7 +1229,7 @@ def _ssd_fwd_impl(xbar, b_in, c_in, log_a, chunk, interpret):
     ck = _to_kernel(c_in)
     lk = log_a.transpose(0, 2, 1).reshape(b * h, 1, l)
     out = ssd_pallas(lk, xk, bk, ck, r=h // g, blk=chunk,
-                     interpret=_interpret(interpret))
+                     interpret=_interpreted())
     return _from_kernel(out, b)
 
 
@@ -1275,12 +1243,12 @@ def _ssd_ref(xbar, b_in, c_in, log_a, chunk):
     return y.astype(xbar.dtype)
 
 
-def _ssd_vjp_fwd(xbar, b_in, c_in, log_a, chunk, interpret):
-    return _ssd_fwd_impl(xbar, b_in, c_in, log_a, chunk, interpret), \
+def _ssd_vjp_fwd(xbar, b_in, c_in, log_a, chunk):
+    return _ssd_fwd_impl(xbar, b_in, c_in, log_a, chunk), \
         (xbar, b_in, c_in, log_a)
 
 
-def _ssd_vjp_bwd(chunk, interpret, res, g_out):
+def _ssd_vjp_bwd(chunk, res, g_out):
     xbar, b_in, c_in, log_a = res
     _, vjp = jax.vjp(
         lambda x, b, c, a: _ssd_ref(x, b, c, a, chunk),

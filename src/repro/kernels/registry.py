@@ -1,18 +1,14 @@
-"""Attention backend registry: one declarative spec, one dispatch table.
+"""Attention backend registry: one declarative spec, one resolution.
 
-Before this module, every attention entry point re-decided its own backend:
-``kernels/ops.py`` threaded ``interpret=`` flags per call,
-``models/attention_block.py`` forked on ``cfg.use_serve_kernel`` and
-``models/mla.py`` hand-rolled its own decode.  The registry centralizes
-that choice behind a single declarative :class:`AttnSpec` and four named
-backends:
+:class:`AttnSpec` declares an attention configuration; :func:`resolve` is
+the one function that reads the platform and turns a backend name into
+the implementation that runs.  Four named backends:
 
 ``auto``
-    Reproduces the historical dispatch exactly: compiled backends (TPU) run
-    the Pallas kernels, the CPU container runs each op's designated twin
-    (interpreted Pallas for the training forward, the chunked ``lax.scan``
-    twin for prefill, the jnp twin for decode), and ragged sequence lengths
-    fall back to the jnp reference.
+    Compiled backends (TPU) run the Pallas kernels; the CPU runs each op's
+    designated twin (interpreted Pallas for the training forward, the
+    chunked ``lax.scan`` twin for prefill, the jnp twin for decode), and
+    ragged sequence lengths fall back to the jnp reference.
 ``pallas``
     Force the Pallas kernel (interpret mode on CPU, so the kernel path is a
     first-class testable target everywhere).  Raises on ragged lengths —
@@ -20,26 +16,24 @@ backends:
 ``scan``
     Force the chunked ``lax.scan`` / grouped-einsum twin (kernel layout, no
     repeated KV).  For ops with no dedicated twin this is the core chunked
-    scan.
+    scan; the full-sequence forward (``AttentionEngine.attention``) runs it
+    in model layout.
 ``ref``
     Force the jnp reference (``core/lln.py`` / ``core/diag.py`` — model
-    layout, repeated KV).  This is exactly the seed serving path that
-    ``use_serve_kernel=False`` used to select; for the training forward it
-    is the quadratic oracle from ``kernels/ref.py``.
+    layout, repeated KV) for serving; for the training forward it is the
+    quadratic oracle from ``kernels/ref.py``.
 
-The per-op twin tables live next to the kernels in ``kernels/ops.py``;
-this module owns the *policy* (spec validation + backend resolution) and
-the spec-level entry points the :class:`~repro.core.engine.AttentionEngine`
+The per-op twin and ragged tables live next to the kernels in
+``kernels/ops.py``, which hands ``auto`` to :func:`resolve`; this module
+owns the *policy* (spec validation + backend resolution) and the
+spec-level entry points the :class:`~repro.core.engine.AttentionEngine`
 calls (:func:`attention`, :func:`prefill`, :func:`decode_chunk`,
-:func:`diag_fwd`).  It also hosts the deprecation machinery for the legacy
-entry points that the engine supersedes.
+:func:`diag_fwd`).
 """
 from __future__ import annotations
 
 import dataclasses
-import warnings
-import functools
-from typing import Callable, Optional
+from typing import Optional
 
 import jax
 
@@ -47,15 +41,13 @@ IMPLS = ("softmax", "lln", "lln_diag", "log_linear")
 BACKENDS = ("auto", "pallas", "scan", "ref")
 CALIBRATIONS = ("batch", "per_row")
 PRECISIONS = ("float32", "bfloat16", "float16")
+TRAIN_TWINS = ("pallas", "scan")
 
 
 @dataclasses.dataclass(frozen=True)
 class AttnSpec:
-    """Declarative description of one attention configuration.
-
-    Every knob that used to be scattered across ``AttnConfig`` flags,
-    ``use_serve_kernel`` forks and per-call ``interpret=`` arguments in one
-    validated place.  The spec is hashable and cheap — build one per layer
+    """Declarative description of one attention configuration, validated
+    in one place.  The spec is hashable and cheap — build one per layer
     call (``AttnSpec.from_cfg``) or inline in tests.
 
     Attributes:
@@ -64,7 +56,13 @@ class AttnSpec:
       causal: decoder (True) vs encoder (False) masking.
       r: GQA ratio ``H // G`` (1 = MHA; k/v carry ``G = H // r`` heads).
       backend: ``auto`` | ``pallas`` | ``scan`` | ``ref`` — see module
-        docstring.  ``auto`` reproduces the historical dispatch.
+        docstring.
+      train_twin: what ``auto`` runs the full-sequence forward
+        (``AttentionEngine.attention``) as on the CPU: ``pallas`` (the
+        kernels, interpreted) or ``scan`` (the model-layout jnp math, which
+        also takes a padding mask).  ``from_cfg`` takes it from
+        :func:`train_twin_of`.  Off the CPU ``auto`` runs the kernels whatever
+        it says.
       precision: dtype name for cached tensors (KV cache / diag tails);
         accumulators are always fp32.
       calibration: ``batch`` pools moment-matching statistics over the
@@ -105,6 +103,7 @@ class AttnSpec:
     causal: bool = True
     r: int = 1
     backend: str = "auto"
+    train_twin: str = "pallas"
     precision: str = "float32"
     calibration: str = "batch"
     lln_chunk: int = 128
@@ -127,6 +126,10 @@ class AttnSpec:
             raise ValueError(
                 f"AttnSpec.backend must be one of {BACKENDS}, "
                 f"got {self.backend!r}")
+        if self.train_twin not in TRAIN_TWINS:
+            raise ValueError(
+                f"AttnSpec.train_twin must be one of {TRAIN_TWINS}, "
+                f"got {self.train_twin!r}")
         if self.calibration not in CALIBRATIONS:
             raise ValueError(
                 f"AttnSpec.calibration must be one of {CALIBRATIONS}, "
@@ -167,17 +170,13 @@ class AttnSpec:
                  r: Optional[int] = None) -> "AttnSpec":
         """Build the spec an :class:`ArchConfig` implies.
 
-        ``cfg.attn_backend`` selects the backend explicitly; the legacy
-        ``use_serve_kernel=False`` escape maps to ``backend='ref'`` (the
-        seed jnp serving path it used to select).  ``r`` overrides the
-        GQA ratio (MLA runs full heads regardless of ``cfg.n_kv_heads``).
+        ``cfg.attn_backend`` selects the backend; :func:`train_twin_of` the
+        full-sequence forward's CPU twin.  ``r`` overrides the GQA ratio
+        (MLA runs full heads regardless of ``cfg.n_kv_heads``).
         """
-        backend = getattr(cfg, "attn_backend", "auto")
-        if backend == "auto" and not getattr(cfg, "use_serve_kernel", True):
-            backend = "ref"
         return cls(impl=cfg.attn_impl, causal=causal,
                    r=r if r is not None else cfg.n_heads // cfg.n_kv_heads,
-                   backend=backend,
+                   backend=cfg.attn_backend, train_twin=train_twin_of(cfg),
                    precision=str(cfg.compute_dtype),
                    calibration=("per_row" if getattr(
                        cfg, "lln_per_row_calib", False) else "batch"),
@@ -191,8 +190,15 @@ class AttnSpec:
                    scale_decay=getattr(cfg, "lln_scale_decay", 0.5))
 
 
+def train_twin_of(cfg) -> str:
+    """The CPU twin of a training forward (attention and the Mamba2 SSD
+    scan): the kernels, interpreted, where ``cfg.use_kernel`` asks; the
+    jnp scan otherwise.  The one reader of ``use_kernel``."""
+    return "pallas" if cfg.use_kernel else "scan"
+
+
 # ---------------------------------------------------------------------------
-# Backend resolution — the one place that owns the interpret/twin/ref choice.
+# Backend resolution — the one place that reads the platform.
 # ---------------------------------------------------------------------------
 
 @dataclasses.dataclass(frozen=True)
@@ -247,11 +253,10 @@ def resolve(backend: str, *, ragged: bool = False,
 # ---------------------------------------------------------------------------
 
 def attention(spec: AttnSpec, q, k, v, alpha, beta, **kw):
-    """Full-sequence LLN / LLN+Diag attention under ``spec.backend``.
-
-    (Softmax lives in ``core/attention.py`` — it has no Pallas kernel and
-    its flash/naive fork is resolved there.)
-    """
+    """Full-sequence LLN / LLN+Diag / log-linear attention under
+    ``spec.backend`` — the kernel route of ``AttentionEngine.attention``,
+    which also owns softmax (no Pallas kernel) and the model-layout jnp
+    route."""
     from . import ops
     if spec.impl == "lln":
         return ops.lln_attention(q, k, v, alpha, beta, spec.causal,
@@ -337,42 +342,3 @@ def diag_fwd(spec: AttnSpec, q, k, v):
     from . import ops
     return ops.block_diag_fwd(q, k, v, spec.diag_block, spec.causal,
                               backend=spec.backend)
-
-
-# ---------------------------------------------------------------------------
-# Deprecation shims for the legacy entry points the engine supersedes.
-# ---------------------------------------------------------------------------
-
-_WARNED: set[str] = set()
-
-
-def reset_deprecations() -> None:
-    """Forget which shims already warned (tests assert warn-once)."""
-    _WARNED.clear()
-
-
-def warn_deprecated(name: str, replacement: str) -> None:
-    """Emit one DeprecationWarning per process for ``name``."""
-    if name in _WARNED:
-        return
-    _WARNED.add(name)
-    warnings.warn(
-        f"{name} is deprecated; use {replacement} instead",
-        DeprecationWarning, stacklevel=3)
-
-
-def deprecated_shim(name: str, replacement: str) -> Callable:
-    """Decorator marking a legacy entry point: warns once, then delegates.
-
-    The wrapped function keeps its signature and return value — it IS the
-    delegation.  ``tests/test_shims.py`` guards that every shim both warns
-    exactly once and reaches the engine path.
-    """
-    def deco(fn):
-        @functools.wraps(fn)
-        def wrapper(*args, **kwargs):
-            warn_deprecated(name, replacement)
-            return fn(*args, **kwargs)
-        wrapper.__deprecated_shim__ = (name, replacement)
-        return wrapper
-    return deco

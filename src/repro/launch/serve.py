@@ -11,9 +11,9 @@ the whole tail of the generation, donated cache carry); the first decode
 step runs standalone — it carries the compile — and is reported separately
 so the tok/s figure measures steady state.  ``--no-scan`` restores the
 seed-style one-dispatch-per-token Python loop (the benchmark baseline);
-``--no-serve-kernel`` selects ``attn_backend=ref`` (the seed two-pass jnp
-path); ``--attn-backend`` picks any registry backend explicitly
-(``kernels/registry.py``: auto | pallas | scan | ref).
+``--attn-backend`` picks a registry backend explicitly
+(``kernels/registry.py``: auto | pallas | scan | ref; ``ref`` is the seed
+two-pass jnp path).
 
 ``--continuous`` switches to the continuous-batching pool
 (``launch/batcher.py``): mixed-length synthetic traffic is admitted into
@@ -80,9 +80,6 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--no-scan", dest="scan", action="store_false",
                     default=True, help="seed-style per-token dispatch loop")
-    ap.add_argument("--no-serve-kernel", dest="serve_kernel",
-                    action="store_false", default=True,
-                    help="seed two-pass prefill (attn_backend=ref)")
     ap.add_argument("--attn-backend", default=None,
                     choices=[None, "auto", "pallas", "scan", "ref"],
                     help="explicit attention backend (kernels/registry.py)")
@@ -131,8 +128,6 @@ def main(argv=None):
     overrides = {}
     if args.attn_impl:
         overrides["attn_impl"] = args.attn_impl
-    if not args.serve_kernel:
-        overrides["use_serve_kernel"] = False
     if args.attn_backend:
         overrides["attn_backend"] = args.attn_backend
     cfg = get_config(args.arch, smoke=args.smoke, **overrides)
@@ -212,7 +207,7 @@ def main(argv=None):
         mode = "scan" if args.scan else "loop"
         tok_s = steady_steps * args.batch / max(t_steady, 1e-9)
         print(f"prefill: {args.batch}x{args.prompt_len} in {t_prefill:.3f}s"
-              f"  (serve_kernel={cfg.use_serve_kernel})")
+              f"  (attn_backend={cfg.attn_backend})")
         print(f"decode : first step {t_first:.3f}s (compile, excluded); "
               f"{steady_steps} steady steps [{mode}] in {t_steady:.3f}s "
               f"({tok_s:.1f} tok/s)")

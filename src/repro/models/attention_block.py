@@ -5,12 +5,11 @@ PaliGemma decoder and Zamba2's shared attention block.  Supports the three
 attention impls (softmax / lln / lln_diag), GQA/MQA, qk-norm and partial
 RoPE.
 
-Serving runs through the unified :class:`repro.core.engine.AttentionEngine`
-(one ``AttentionState`` pytree, per-row counters, backend dispatch owned by
-``kernels/registry.py``): ``serve_state_init`` / ``serve_prefill`` /
-``serve_decode`` are the canonical entry points; the legacy
-``attn_cache_init`` / ``attn_prefill`` / ``attn_decode`` names survive as
-deprecation shims delegating to them (see ``docs/api.md``).
+Every attention call runs through the unified
+:class:`repro.core.engine.AttentionEngine` (backend dispatch owned by
+``kernels/registry.py``): :func:`attn_apply` through its full-sequence
+``attention``; serving through ``serve_state_init`` / ``serve_prefill`` /
+``serve_decode`` (one ``AttentionState`` pytree, per-row counters).
 """
 from __future__ import annotations
 
@@ -20,28 +19,23 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import attention as ca
-from repro.core.attention import AttnConfig
 from repro.core.engine import AttentionEngine
-from repro.kernels.registry import deprecated_shim, on_cpu
 from repro.distributed.sharding import constrain
 from .layers import dense, dense_init, rms_head_norm, rope
 
 
-def attn_cfg_of(cfg, causal: bool = True) -> AttnConfig:
-    return AttnConfig(impl=cfg.attn_impl, causal=causal,
-                      diag_block=cfg.diag_block, lln_chunk=cfg.lln_chunk,
-                      softmax_chunk=cfg.softmax_chunk,
-                      use_kernel=cfg.use_kernel or not on_cpu(),
-                      backend=(None if cfg.attn_backend == "auto"
-                               else cfg.attn_backend),
-                      fixed_ab=cfg.lln_fixed_ab,
-                      num_scales=getattr(cfg, "lln_num_scales", 4),
-                      scale_decay=getattr(cfg, "lln_scale_decay", 0.5))
-
-
 def attn_engine(cfg, causal: bool = True) -> AttentionEngine:
-    """The serving engine an ``ArchConfig`` attention layer implies."""
+    """The engine an ``ArchConfig`` attention layer implies."""
     return AttentionEngine.from_cfg(cfg, causal=causal)
+
+
+def batch_attention(eng: AttentionEngine, q, k, v, **kw):
+    """The model's full-sequence attention: ``eng.attention`` with the
+    batch-pooled moment-matched (alpha, beta) — no per-row calibration and
+    no beta(n) gain, whatever the spec says."""
+    if eng.spec.impl != "softmax":
+        kw["alpha"], kw["beta"] = ca.batch_alpha_beta(q, k, eng.spec)
+    return eng.attention(q, k, v, **kw)
 
 
 def attn_init(key, cfg, d_in: Optional[int] = None):
@@ -85,8 +79,8 @@ def attn_apply(p, x, cfg, positions, *, causal: bool = True,
     hd, h, g = cfg.hd, cfg.n_heads, cfg.n_kv_heads
     if kv is None:
         q, k, v = _project_qkv(p, x, cfg, positions)
-        out = ca.multi_head_attention(q, k, v, attn_cfg_of(cfg, causal),
-                                      mask=mask, prefix_len=prefix_len)
+        out = batch_attention(attn_engine(cfg, causal), q, k, v, mask=mask,
+                              prefix_len=prefix_len)
     else:
         m = kv.shape[1]
         q = dense(p["q_w"], x, cfg.cdtype).reshape(b, n, h, hd)
@@ -96,7 +90,8 @@ def attn_apply(p, x, cfg, positions, *, causal: bool = True,
         k = constrain(k, "act_batch", None, "kv_heads", None)
         v = constrain(v, "act_batch", None, "kv_heads", None)
         out = ca.flash_softmax(q, k, v, causal=False,
-                               chunk=min(cfg.softmax_chunk, m), mask=mask)
+                               chunk=min(cfg.softmax_chunk, m), mask=mask,
+                               constrain=constrain)
     out = out.reshape(b, n, h * hd)
     out = constrain(out, "act_batch", "attn_seq", None)
     return dense(p["o_w"], out, cfg.cdtype)
@@ -108,10 +103,8 @@ def attn_apply(p, x, cfg, positions, *, causal: bool = True,
 # One ``AttentionState`` pytree for every impl, per-row counters always
 # (static lockstep batching is the degenerate case where all rows agree),
 # diag tails at the G kv heads, backend dispatch (pallas / scan twin / jnp
-# ref) owned by ``kernels/registry.py``.  The legacy seed path that
-# ``use_serve_kernel=False`` selected is now ``backend='ref'``
-# (``AttnSpec.from_cfg`` does that mapping) — used by
-# ``benchmarks/bench_serve.py`` as the baseline.
+# ref) owned by ``kernels/registry.py``.  ``attn_backend='ref'`` selects
+# the seed jnp serving path (``benchmarks/bench_serve.py``'s baseline).
 # ---------------------------------------------------------------------------
 
 def serve_state_init(cfg, batch: int, max_len: int):
@@ -204,29 +197,3 @@ def serve_commit(state, residual, cfg, *, commit_len, row_mask=None):
     """
     return attn_engine(cfg).commit(state, residual, commit_len=commit_len,
                                    row_mask=row_mask)
-
-
-# --- legacy entry points (deprecation shims over the engine) ---------------
-
-@deprecated_shim("models.attention_block.attn_cache_init",
-                 "attn_engine(cfg).init_state / serve_state_init")
-def attn_cache_init(cfg, batch: int, max_len: int, per_row: bool = False):
-    """Legacy cache initializer.  The engine state is always per-row now,
-    so ``per_row`` is accepted and ignored (the scalar layout was the
-    degenerate case and has been deleted)."""
-    del per_row
-    return serve_state_init(cfg, batch, max_len)
-
-
-@deprecated_shim("models.attention_block.attn_prefill", "serve_prefill")
-def attn_prefill(p, x, cfg, positions, *, prefix_len: int = 0,
-                 max_len: int = 0):
-    """Legacy prefill — delegates to :func:`serve_prefill`."""
-    return serve_prefill(p, x, cfg, positions, prefix_len=prefix_len,
-                         max_len=max_len)
-
-
-@deprecated_shim("models.attention_block.attn_decode", "serve_decode")
-def attn_decode(p, x, cache, cfg, position, *, row_mask=None):
-    """Legacy decode — delegates to :func:`serve_decode`."""
-    return serve_decode(p, x, cache, cfg, position, row_mask=row_mask)

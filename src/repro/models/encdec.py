@@ -141,7 +141,8 @@ def encdec_prefill(p, src_embed, tgt_tokens, cfg, max_len: int):
         ck = constrain(ck, "act_batch", None, "kv_heads", None)
         cv = constrain(cv, "act_batch", None, "kv_heads", None)
         xa = ca.flash_softmax(q, ck, cv, causal=False,
-                              chunk=min(cfg.softmax_chunk, m))
+                              chunk=min(cfg.softmax_chunk, m),
+                              constrain=constrain)
         xa = dense(lp["cross"]["o_w"], xa.reshape(b, n, -1), cfg.cdtype)
         x = x + xa.astype(x.dtype)
         h = apply_norm(lp["ln2"], x, cfg.norm)
@@ -174,7 +175,8 @@ def encdec_decode(p, caches, token, cfg, position):
             b, 1, cfg.n_heads, cfg.hd)
         xa = ca.flash_softmax(q, cache["ck"], cache["cv"], causal=False,
                               chunk=min(cfg.softmax_chunk,
-                                        cache["ck"].shape[1]))
+                                        cache["ck"].shape[1]),
+                              constrain=constrain)
         xa = dense(lp["cross"]["o_w"], xa.reshape(b, 1, -1), cfg.cdtype)
         x = x + xa.astype(x.dtype)
         h = apply_norm(lp["ln2"], x, cfg.norm)

@@ -23,11 +23,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core import attention as ca
 from repro.core.engine import AttentionEngine, AttentionState
-from repro.kernels.registry import deprecated_shim
 from repro.distributed.sharding import constrain
-from .attention_block import attn_cfg_of
+from .attention_block import batch_attention
 from .layers import dense, dense_init, rope
 
 
@@ -107,7 +105,7 @@ def mla_apply(p, x, cfg, positions, *, causal: bool = True):
     q = constrain(q, "act_batch", "attn_seq", "heads", None)
     k = constrain(k, "act_batch", "attn_seq", "heads", None)
     v = constrain(v, "act_batch", "attn_seq", "heads", None)
-    out = ca.multi_head_attention(q, k, v, attn_cfg_of(cfg, causal))
+    out = batch_attention(mla_engine(cfg, causal), q, k, v)
     out = out.reshape(b, n, -1)
     return dense(p["o_w"], out, cfg.cdtype)
 
@@ -149,7 +147,7 @@ def mla_prefill(p, x, cfg, positions, *, max_len: int = 0):
     k_nope, v = _decompress(p, ckv, cfg)
     q, k = _assemble(q_nope, q_rope, k_nope, kr)
     if cfg.attn_impl == "softmax":
-        out = ca.multi_head_attention(q, k, v, attn_cfg_of(cfg, True))
+        out = mla_engine(cfg).attention(q, k, v)
         ml = max(max_len, n)
         pad = ((0, 0), (0, ml - n), (0, 0))
         state = AttentionState(
@@ -222,9 +220,3 @@ def mla_decode(p, x, state, cfg, position):
         out, state = mla_engine(cfg).decode(state, q, k, v)
     out = out.reshape(b, n, -1)
     return dense(p["o_w"], out, cfg.cdtype), state
-
-
-@deprecated_shim("models.mla.mla_cache_init", "mla_state_init")
-def mla_cache_init(cfg, batch: int, max_len: int):
-    """Legacy cache initializer — delegates to :func:`mla_state_init`."""
-    return mla_state_init(cfg, batch, max_len)

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 
 from repro.core.numerics import einsum_f32
 from repro.distributed.sharding import constrain, per_device
-from repro.kernels.registry import on_cpu
+from repro.kernels import registry
 from .layers import apply_norm, dense, dense_init, norm_init, trunc_normal
 
 
@@ -163,9 +163,9 @@ def ssm_apply(p, x, cfg, *, state0=None, return_state: bool = False,
     xh = constrain(xh, "act_batch", None, "heads", None)
     xbar = xh.astype(jnp.float32) * dt[..., None]
     rep = h // g
-    if (cfg.use_kernel or not on_cpu()) and state0 is None \
-            and not return_state \
-            and l % cfg.ssm_chunk == 0:
+    if state0 is None and not return_state and registry.resolve(
+            "auto", ragged=bool(l % cfg.ssm_chunk),
+            cpu_twin=registry.train_twin_of(cfg)).kind == "pallas":
         # Pallas SSD kernel (training fwd; groups via index maps, no repeat),
         # per device over rows and heads under a mesh.  One group serves
         # every head, so it is replicated; more groups split with the heads.

@@ -11,8 +11,8 @@ except ImportError:                                    # container has no
     from _hypothesis_shim import given, settings       # hypothesis; use the
     from _hypothesis_shim import strategies as st      # deterministic shim
 
-from repro.core import AttnConfig, flash_softmax, multi_head_attention, \
-    naive_softmax
+from repro.core import AttentionEngine, flash_softmax, naive_softmax
+from repro.kernels.registry import AttnSpec
 
 
 def _qkv(seed, b, n, h, g, d, dtype=jnp.float32):
@@ -98,9 +98,11 @@ def test_flash_grads_match_naive():
        causal=st.booleans(), seed=st.integers(0, 2**16))
 def test_dispatcher_finite_and_shaped(impl, causal, seed):
     q, k, v = _qkv(seed, 2, 32, 4, 2, 16)
-    cfg = AttnConfig(impl=impl, causal=causal, diag_block=16, lln_chunk=16,
-                     softmax_chunk=16)
-    out = multi_head_attention(q, k, v, cfg)
+    spec = AttnSpec(impl=impl, causal=causal, r=2, diag_block=16,
+                    lln_chunk=16, softmax_chunk=16, train_twin="scan")
+    eng = AttentionEngine(spec=spec, heads=4, kv_heads=2, head_dim=16,
+                          v_dim=16)
+    out = eng.attention(q, k, v)
     assert out.shape == q.shape
     assert np.all(np.isfinite(np.asarray(out, np.float32)))
 
@@ -108,4 +110,5 @@ def test_dispatcher_finite_and_shaped(impl, causal, seed):
 def test_dispatcher_rejects_unknown_impl():
     q, k, v = _qkv(0, 1, 16, 2, 2, 8)
     with pytest.raises(ValueError):
-        multi_head_attention(q, k, v, AttnConfig(impl="bogus"))
+        AttentionEngine(spec=AttnSpec(impl="bogus"), heads=2, kv_heads=2,
+                        head_dim=8, v_dim=8).attention(q, k, v)

@@ -180,8 +180,8 @@ class TestMaskedRows:
                 rows(after, 0), rows(got, 0),
                 err_msg=f"active row diverged under masking: {path}")
 
-    @pytest.mark.parametrize("use_kernel", [True, False])
-    def test_masked_rows_lln_decode_chunk(self, use_kernel):
+    @pytest.mark.parametrize("backend", ["auto", "ref"])
+    def test_masked_rows_lln_decode_chunk(self, backend):
         """decode_lln_chunk row mask: masked rows keep (s, z, c_k), tails
         and pos exactly."""
         b, t, g, r, d, block = 3, 2, 2, 2, 8, 8
@@ -203,7 +203,7 @@ class TestMaskedRows:
         mask = jnp.asarray([False, True, False])
         _, st2 = ca.decode_lln_chunk(st, qn, kn, vn, alpha,
                                      jnp.repeat(beta, r),
-                                     use_kernel=use_kernel, row_mask=mask)
+                                     backend=backend, row_mask=mask)
         for name in ("tail_k", "tail_v", "pos"):
             a, bfr = getattr(st2, name), getattr(st, name)
             for i in (0, 2):
@@ -363,8 +363,10 @@ class TestPerRowPositions:
                                       pos=jnp.asarray(n0, jnp.int32))
         st_vec = ca.LLNDecodeState(lln=lln, tail_k=tail_k, tail_v=tail_v,
                                    pos=jnp.full((b,), n0, jnp.int32))
-        o1, s1 = ca.decode_lln_chunk(st_scalar, qn, kn, vn, alpha, beta_h)
-        o2, s2 = ca.decode_lln_chunk(st_vec, qn, kn, vn, alpha, beta_h)
+        o1, s1 = ca.decode_lln_chunk(st_scalar, qn, kn, vn, alpha, beta_h,
+                                     backend="auto")
+        o2, s2 = ca.decode_lln_chunk(st_vec, qn, kn, vn, alpha, beta_h,
+                                     backend="auto")
         np.testing.assert_array_equal(np.asarray(o1), np.asarray(o2))
         np.testing.assert_array_equal(np.asarray(s1.tail_k),
                                       np.asarray(s2.tail_k))

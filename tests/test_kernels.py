@@ -331,7 +331,7 @@ class TestPallasVJPGradParity:
             q_, k_, v_, self.CHUNK, causal) ** 2),
             argnums=(0, 1, 2))(q, k, v)
         gr = jax.grad(lambda q_, k_, v_: jnp.sum(block_diag_attention(
-            q_, k_, v_, self.CHUNK, causal, None, False) ** 2),
+            q_, k_, v_, self.CHUNK, causal, False) ** 2),
             argnums=(0, 1, 2))(q, k, v)
         for a, b_, nm in zip(gk, gr, "qkv"):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b_),

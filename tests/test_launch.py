@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.kernels import registry
 from repro.launch import compile_cache
 from repro.models import attention_block, ssm
 
@@ -41,27 +42,31 @@ class TestKernelsOnAccelerator:
     ``use_kernel`` says; on the CPU ``use_kernel`` still chooses."""
 
     def test_attention_uses_kernels_off_cpu(self, monkeypatch):
+        def kind(cfg):
+            spec = attention_block.attn_engine(cfg, causal=False).spec
+            return registry.resolve(spec.backend,
+                                    cpu_twin=spec.train_twin).kind
+
         cfg = get_config("roberta-lln", smoke=True)
         assert not cfg.use_kernel
-        assert not attention_block.attn_cfg_of(cfg).use_kernel
-        monkeypatch.setattr(attention_block, "on_cpu", lambda: False)
-        acfg = attention_block.attn_cfg_of(cfg)
-        assert acfg.use_kernel and acfg.backend is None
-        ref = attention_block.attn_cfg_of(cfg.replace(attn_backend="ref"))
-        assert ref.backend == "ref"
+        assert kind(cfg) == "scan"            # the jnp route
+        assert kind(cfg.replace(use_kernel=True)) == "pallas"
+        monkeypatch.setattr(registry, "on_cpu", lambda: False)
+        assert kind(cfg) == "pallas"
+        assert kind(cfg.replace(attn_backend="ref")) == "ref"
 
     @pytest.mark.parametrize("off_cpu", [False, True])
     def test_ssd_kernel_off_cpu(self, monkeypatch, off_cpu):
-        import repro.kernels as kernels
+        from repro.kernels import ops
         calls = []
-        real = kernels.ssd_scan
+        real = ops.ssd_pallas
 
-        def spy(*a, **k):
+        def spy(*a, **k):              # the kernel, interpreted on this CPU
             calls.append(1)
-            return real(*a, **k)
+            return real(*a, **{**k, "interpret": True})
 
-        monkeypatch.setattr(kernels, "ssd_scan", spy)
-        monkeypatch.setattr(ssm, "on_cpu", lambda: not off_cpu)
+        monkeypatch.setattr(ops, "ssd_pallas", spy)
+        monkeypatch.setattr(registry, "on_cpu", lambda: not off_cpu)
         cfg = get_config("mamba2-130m", smoke=True)
         key = jax.random.PRNGKey(0)
         p = ssm.ssm_init(key, cfg)

@@ -4,12 +4,22 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import (AttnConfig, LLNDecodeState, block_diag_attn,
-                        decode_lln, lln_bidir, lln_causal,
-                        multi_head_attention, naive_softmax)
+from repro.core import (AttentionEngine, LLNDecodeState, block_diag_attn,
+                        decode_lln_chunk, lln_bidir, lln_causal,
+                        naive_softmax)
 from repro.core import metrics as M
 from repro.core import moment_matching as mm
 from repro.core.lln import prefill as lln_prefill
+from repro.kernels.registry import AttnSpec
+
+
+def _jnp_attention(q, k, v, impl, alpha, beta, **kw):
+    """The engine's full-sequence forward on its model-layout jnp route."""
+    spec = AttnSpec(impl=impl, causal=True, r=q.shape[2] // k.shape[2],
+                    train_twin="scan", **kw)
+    eng = AttentionEngine(spec=spec, heads=q.shape[2], kv_heads=k.shape[2],
+                          head_dim=q.shape[3], v_dim=v.shape[3])
+    return eng.attention(q, k, v, alpha=alpha, beta=beta)
 
 
 def _qkv(key, b=2, n=64, h=4, d=16, g=None):
@@ -82,17 +92,17 @@ class TestLLNForms:
 
     def test_lln_diag_decode_matches_full(self):
         q, k, v = _qkv(jax.random.PRNGKey(5), g=2)
-        cfg = AttnConfig(impl="lln_diag", causal=True, diag_block=16,
-                         lln_chunk=16)
         alpha = jnp.full((4,), 1.2)
         beta = jnp.full((2,), 1.2)
-        full = multi_head_attention(q, k, v, cfg, alpha=alpha, beta=beta)
+        full = _jnp_attention(q, k, v, "lln_diag", alpha, beta,
+                              diag_block=16, lln_chunk=16)
         st = LLNDecodeState.init(2, 4, 16, 16, 16, jnp.float32)
         beta_h = jnp.repeat(beta, 2)
         outs = []
         for t in range(q.shape[1]):
-            o, st = decode_lln(st, q[:, t:t + 1], k[:, t:t + 1],
-                               v[:, t:t + 1], alpha, beta_h)
+            o, st = decode_lln_chunk(st, q[:, t:t + 1], k[:, t:t + 1],
+                                     v[:, t:t + 1], alpha, beta_h,
+                                     backend="ref")
             outs.append(o)
         np.testing.assert_allclose(np.asarray(jnp.concatenate(outs, 1)),
                                    np.asarray(full), atol=3e-4)
@@ -234,12 +244,8 @@ class TestHybridLayer:
         q, k, v = _qkv(jax.random.PRNGKey(6))
         cfg = dict(diag_block=16, lln_chunk=16)
         alpha = beta = jnp.full((4,), 1.3)
-        h = multi_head_attention(q, k, v,
-                                 AttnConfig(impl="lln_diag", causal=True,
-                                            **cfg), alpha=alpha, beta=beta)
-        l = multi_head_attention(q, k, v,
-                                 AttnConfig(impl="lln", causal=True, **cfg),
-                                 alpha=alpha, beta=beta)
+        h = _jnp_attention(q, k, v, "lln_diag", alpha, beta, **cfg)
+        l = _jnp_attention(q, k, v, "lln", alpha, beta, **cfg)
         d = block_diag_attn(q, k, v, block=16, causal=True)
         np.testing.assert_allclose(np.asarray(h),
                                    np.asarray(0.5 * (l + d)), atol=1e-5)

@@ -5,12 +5,10 @@
   first-class testable targets, not accidents of the CPU dispatch;
 * ``AttnSpec`` validation errors and ``resolve`` policy;
 * ``AttentionState`` lifecycle round-trip (init → prefill → decode →
-  evict) matching the legacy ``attn_prefill``/``attn_decode`` composition
-  bitwise;
-* MLA chunked multi-token decode through the engine.
+  evict) matching the KVCache / LLNDecodeState decode functions bitwise;
+* MLA chunked multi-token decode through the engine;
+* the dispatch table: which implementation each model front door runs.
 """
-import warnings
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -52,18 +50,6 @@ class TestSpecValidation:
     def test_invalid_specs_raise(self, kw):
         with pytest.raises(ValueError):
             AttnSpec(**kw)
-
-    def test_from_cfg_maps_serve_kernel_escape(self):
-        from repro.configs.base import ArchConfig
-        cfg = ArchConfig(name="t", family="dense", n_layers=1, d_model=32,
-                         n_heads=4, n_kv_heads=2, d_ff=64, vocab=64,
-                         head_dim=8, attn_impl="lln_diag",
-                         use_serve_kernel=False)
-        spec = AttnSpec.from_cfg(cfg)
-        assert spec.backend == "ref"      # the seed jnp serving path
-        assert spec.r == 2
-        spec2 = AttnSpec.from_cfg(cfg.replace(use_serve_kernel=True))
-        assert spec2.backend == "auto"
 
     def test_resolve_policy(self):
         assert resolve("auto", ragged=True) == Resolution("ref", False)
@@ -219,7 +205,8 @@ class TestEngineLifecycle:
                 lln=core_lln.LLNState(s=st.s, z=st.z, c_k=st.c_k),
                 tail_k=st.tail_k, tail_v=st.tail_v, pos=st.pos)
             ref2, lst = ca.decode_lln_chunk(legacy, qn, kn, vn, st.alpha,
-                                            st.beta, impl=impl)
+                                            st.beta, impl=impl,
+                                            backend="auto")
             np.testing.assert_array_equal(np.asarray(out2),
                                           np.asarray(ref2))
             np.testing.assert_array_equal(np.asarray(st2.s),
@@ -247,35 +234,6 @@ class TestEngineLifecycle:
                 np.asarray(after)[1], np.asarray(leaf)[1],
                 err_msg=f"evict leaked into live row: {jax.tree_util.keystr(kp)}")
 
-    def test_legacy_shims_delegate_bitwise(self):
-        """attn_prefill/attn_decode (deprecation shims) return exactly what
-        serve_prefill/serve_decode return."""
-        from repro.models import attention_block as ab
-        from repro.configs.base import ArchConfig
-        cfg = ArchConfig(name="t", family="dense", n_layers=1, d_model=32,
-                         n_heads=4, n_kv_heads=2, d_ff=64, vocab=64,
-                         head_dim=8, attn_impl="lln_diag", diag_block=8,
-                         lln_chunk=8, softmax_chunk=16, lln_fixed_ab=2.1,
-                         compute_dtype="float32", param_dtype="float32")
-        p = ab.attn_init(jax.random.PRNGKey(0), cfg)
-        x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 32))
-        positions = jnp.arange(16)
-        out_new, st_new = ab.serve_prefill(p, x, cfg, positions, max_len=20)
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            out_old, st_old = ab.attn_prefill(p, x, cfg, positions,
-                                              max_len=20)
-        np.testing.assert_array_equal(np.asarray(out_new),
-                                      np.asarray(out_old))
-        x1 = jax.random.normal(jax.random.PRNGKey(2), (2, 1, 32))
-        d_new, s2_new = ab.serve_decode(p, x1, st_new, cfg,
-                                        jnp.asarray(16, jnp.int32))
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            d_old, _ = ab.attn_decode(p, x1, st_old, cfg,
-                                      jnp.asarray(16, jnp.int32))
-        np.testing.assert_array_equal(np.asarray(d_new), np.asarray(d_old))
-
     def test_state_is_a_pytree_with_dict_paths(self):
         st = _engine("lln_diag", 2).init_state(2, 16)
         leaves = jax.tree_util.tree_leaves_with_path(st)
@@ -297,11 +255,11 @@ class TestPerRowCalibration:
         b, n, g, r, d = 3, 16, 2, 2, 8
         h = g * r
         q, k, _ = _qkv(90, b, n, h, g, d)
-        cfg = ca.AttnConfig(impl="lln", fixed_ab=0.0)
-        a_rows, b_rows = ca.batch_alpha_beta(q, k, cfg, per_row=True)
+        spec = AttnSpec(impl="lln", r=r)
+        a_rows, b_rows = ca.batch_alpha_beta(q, k, spec, per_row=True)
         assert a_rows.shape == (b, h) and b_rows.shape == (b, g)
         for i in range(b):
-            a1, b1 = ca.batch_alpha_beta(q[i:i + 1], k[i:i + 1], cfg)
+            a1, b1 = ca.batch_alpha_beta(q[i:i + 1], k[i:i + 1], spec)
             np.testing.assert_allclose(np.asarray(a_rows[i]),
                                        np.asarray(a1), rtol=1e-6)
             np.testing.assert_allclose(np.asarray(b_rows[i]),
@@ -310,8 +268,8 @@ class TestPerRowCalibration:
     @pytest.mark.parametrize("backend", ["pallas", "scan", "ref"])
     def test_per_row_calibration_works_on_every_backend(self, backend):
         """(B, H)/(B, G) calibration must flow through every backend's
-        full-sequence forward — including the jnp core path (which pools
-        per-q-head beta to groups and repeats it per row)."""
+        full-sequence forward — including the jnp core path under ``scan``
+        (which pools per-q-head beta to groups and repeats it per row)."""
         b, n, g, r, d = 2, 16, 2, 2, 8
         h = g * r
         eng = _engine("lln", r, backend, calibration="per_row")
@@ -415,3 +373,122 @@ class TestMLAChunkedDecode:
                                    cfg.nope_head_dim + cfg.rope_head_dim)
         assert st.pos.shape == (2,)
         assert st.alpha.shape == (2, 4)
+
+
+# ---------------------------------------------------------------------------
+# Dispatch table: which implementation each model front door runs, per
+# platform, ``use_kernel`` and ``attn_backend`` that a caller uses.
+# ---------------------------------------------------------------------------
+
+def _table_cfg(front, impl, use_kernel, backend):
+    from repro.configs import get_config
+    from repro.configs.base import ArchConfig
+    if front == "ssm_apply":
+        return get_config("mamba2-130m", smoke=True).replace(
+            use_kernel=use_kernel, compute_dtype="float32")
+    return ArchConfig(
+        name="table", family="dense", n_layers=1, d_model=32, n_heads=4,
+        n_kv_heads=2, head_dim=8, d_ff=64, vocab=64, attn_impl=impl,
+        diag_block=8, lln_chunk=8, softmax_chunk=16, use_kernel=use_kernel,
+        attn_backend=backend, compute_dtype="float32",
+        param_dtype="float32", remat="none")
+
+
+def _run_front_door(front, cfg):
+    """Trace one front door (``jax.eval_shape``: nothing is compiled, so a
+    kernel resolved for the chip traces here too)."""
+    from repro.models import attention_block as ab
+    from repro.models import ssm
+    key = jax.random.PRNGKey(0)
+    if front == "ssm_apply":
+        p = ssm.ssm_init(key, cfg)
+        x = jnp.zeros((1, 2 * cfg.ssm_chunk, cfg.d_model))
+        return jax.eval_shape(lambda p, x: ssm.ssm_apply(p, x, cfg), p, x)
+    p = ab.attn_init(key, cfg)
+    x = jnp.zeros((2, 16, cfg.d_model))
+    pos = jnp.arange(16)
+    if front == "attn_apply":
+        return jax.eval_shape(
+            lambda p, x: ab.attn_apply(p, x, cfg, pos, causal=False), p, x)
+    if front == "serve_prefill":
+        return jax.eval_shape(
+            lambda p, x: ab.serve_prefill(p, x, cfg, pos, max_len=24), p, x)
+    state = ab.serve_state_init(cfg, 2, 24)
+    return jax.eval_shape(
+        lambda p, x, s: ab.serve_decode(p, x, s, cfg,
+                                        jnp.full((2,), 16, jnp.int32)),
+        p, x[:, :1], state)
+
+
+#: (front door, impl, off the CPU, cfg.use_kernel, cfg.attn_backend) ->
+#: the implementations that run: ``pallas`` compiled kernels,
+#: ``pallas-interpret`` the kernels interpreted, ``scan`` the kernel-layout
+#: twins, ``ref`` the kernels' jnp references (kernels/ops.py), ``jnp`` the
+#: model-layout jnp math (core/lln.py, models/ssm.py:ssd_chunked),
+#: ``flash``/``naive`` the softmax forms.  Each row names a caller.
+DISPATCH_TABLE = [
+    # roberta-mlm512's training forward (bidirectional lln_diag).
+    ("attn_apply", "lln_diag", False, False, "auto", {"jnp"}),       # tests
+    ("attn_apply", "lln_diag", False, True, "auto",
+     {"pallas-interpret"}),                       # bench/tests/smoke.py
+    ("attn_apply", "lln_diag", False, True, "pallas",
+     {"pallas-interpret"}),                       # test_distributed.py
+    ("attn_apply", "lln_diag", True, False, "auto", {"pallas"}),   # the cell
+    ("attn_apply", "lln_diag", True, False, "ref", {"ref"}),   # chip_smoke
+    ("attn_apply", "softmax", False, False, "auto", {"flash"}),     # tests
+    # The decode cells' prefill and decode (causal lln_diag).
+    ("serve_prefill", "lln_diag", False, False, "auto", {"scan"}),  # tests
+    ("serve_prefill", "lln_diag", False, True, "pallas",
+     {"pallas-interpret"}),                       # test_distributed.py
+    ("serve_prefill", "lln_diag", True, False, "auto", {"pallas"}),  # cells
+    ("serve_prefill", "lln_diag", True, False, "ref", {"ref"}),  # chip_smoke
+    ("serve_prefill", "softmax", False, False, "ref", {"naive"}),  # serve CLI
+    ("serve_decode", "lln_diag", False, False, "auto", {"jnp"}),    # tests
+    ("serve_decode", "lln_diag", False, True, "pallas",
+     {"pallas-interpret"}),                       # test_distributed.py
+    ("serve_decode", "lln_diag", True, False, "auto", {"pallas"}),  # cells
+    ("serve_decode", "lln_diag", True, False, "ref", {"jnp"}),  # chip_smoke
+    # The Mamba2 SSD choice.
+    ("ssm_apply", "softmax", False, False, "auto", {"jnp"}),        # tests
+    ("ssm_apply", "softmax", False, True, "auto",
+     {"pallas-interpret"}),                       # test_distributed.py
+    ("ssm_apply", "softmax", True, False, "auto", {"pallas"}),  # chip_smoke
+]
+
+
+@pytest.mark.parametrize(
+    "front,impl,off_cpu,use_kernel,backend,want", DISPATCH_TABLE,
+    ids=["-".join(map(str, row[:5])) for row in DISPATCH_TABLE])
+def test_dispatch_table(monkeypatch, front, impl, off_cpu, use_kernel,
+                        backend, want):
+    from repro.core import engine as engine_mod
+    from repro.kernels import registry
+    from repro.models import ssm
+    seen = set()
+
+    def spy(mod, name, label):
+        real = getattr(mod, name)
+
+        def call(*a, **kw):
+            seen.add(label + ("-interpret" if kw.get("interpret") else ""))
+            return real(*a, **kw)
+        monkeypatch.setattr(mod, name, call)
+
+    for name in ("lln_bidir_pallas", "lln_causal_pallas",
+                 "lln_diag_fused_pallas", "block_diag_pallas",
+                 "lln_decode_pallas", "ssd_pallas"):
+        spy(kops, name, "pallas")
+    for name in ("_lln_ref", "_lln_prefill_scan", "_block_diag_twin"):
+        spy(kops, name, "scan")
+    for name in ("_lln_quad_ref", "_diag_ref", "_lln_prefill_ref"):
+        spy(kops, name, "ref")
+    spy(core_lln, "decode_chunk", "jnp")
+    spy(ssm, "ssd_chunked", "jnp")
+    spy(ca, "flash_softmax", "flash")
+    spy(ca, "naive_softmax", "naive")
+    spy(engine_mod, "lln_bidir", "jnp")
+    spy(engine_mod, "lln_causal", "jnp")
+    if off_cpu:
+        monkeypatch.setattr(registry, "on_cpu", lambda: False)
+    _run_front_door(front, _table_cfg(front, impl, use_kernel, backend))
+    assert seen == want

@@ -17,6 +17,7 @@ from repro.configs.base import ArchConfig, ShapeSpec
 from repro.core import attention as ca
 from repro.core import lln as core_lln
 from repro.kernels import ops as kops
+from repro.kernels import registry
 from repro.models import build_model, synthetic_batch
 
 
@@ -97,7 +98,7 @@ class TestPrefillState:
         alpha, beta = jnp.full((h,), 1.2), jnp.full((g,), 1.0)
         twin = kops.lln_prefill(q, k, v, alpha, beta, chunk=16)
         from repro.kernels.lln_attention import lln_causal_pallas
-        monkeypatch.setattr(kops, "_interpret", lambda flag: False)
+        monkeypatch.setattr(registry, "on_cpu", lambda: False)
         monkeypatch.setattr(
             kops, "lln_causal_pallas",
             lambda *a, **kw: lln_causal_pallas(*a, **{**kw,
@@ -152,7 +153,7 @@ class TestDecodeChunk:
         o_twin, st_twin = kops.lln_decode_chunk(st, qn, kn, vn, alpha,
                                                 beta_h)
         real = kops.lln_decode_pallas
-        monkeypatch.setattr(kops, "_interpret", lambda flag: False)
+        monkeypatch.setattr(registry, "on_cpu", lambda: False)
         monkeypatch.setattr(
             kops, "lln_decode_pallas",
             lambda *a, **kw: real(*a, **{**kw, "interpret": True}))
@@ -218,12 +219,12 @@ class TestDecodeChunk:
         qn, kn, vn = _qkv(13, b, t, h, g, d)
         for impl in ("lln", "lln_diag"):
             oc, stc = ca.decode_lln_chunk(st_g, qn, kn, vn, alpha, beta_h,
-                                          impl=impl)
+                                          impl=impl, backend="auto")
             sts, outs = st_h, []
             for i in range(t):
                 o, sts = ca.decode_lln_chunk(
                     sts, qn[:, i:i + 1], kn[:, i:i + 1], vn[:, i:i + 1],
-                    alpha, beta_h, impl=impl)
+                    alpha, beta_h, impl=impl, backend="auto")
                 outs.append(o)
             oseq = jnp.concatenate(outs, axis=1)
             np.testing.assert_allclose(np.asarray(oc), np.asarray(oseq),
@@ -285,7 +286,7 @@ class TestSingleTokenDiag:
                                tail_k=tail_k, tail_v=tail_v, pos=pos)
         beta_h = jnp.repeat(beta, r)
         mask = jnp.asarray(self.ROW_MASK)
-        kw = dict(row_mask=mask, defer_tail=defer_tail)
+        kw = dict(row_mask=mask, defer_tail=defer_tail, backend="auto")
         out, st_new = ca.decode_lln_chunk(st, q, kn, vn, alpha, beta_h, **kw)
         lln_out, st_lln = ca.decode_lln_chunk(st, q, kn, vn, alpha, beta_h,
                                               impl="lln", **kw)

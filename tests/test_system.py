@@ -112,5 +112,5 @@ def test_serve_cli_softmax_kv_decode():
     assert toks.shape == (2, 6)
     toks = serve_main(["--arch", "chatglm3-6b", "--smoke", "--attn-impl",
                        "softmax", "--batch", "2", "--prompt-len", "16",
-                       "--gen", "4", "--no-scan", "--no-serve-kernel"])
+                       "--gen", "4", "--no-scan", "--attn-backend", "ref"])
     assert toks.shape == (2, 4)
